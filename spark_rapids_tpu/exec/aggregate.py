@@ -28,15 +28,16 @@ import jax.numpy as jnp
 from .. import types as T
 from ..batch import ColumnarBatch, DeviceColumn, Field, Schema, bucket_capacity
 from ..expressions.aggregates import AggregateFunction
+from ..expressions.aggregates import _cumsum as prefix_sum
 from ..expressions.base import Alias, EvalContext, Expression
 from .base import Exec, UnaryExec
 from .basic import bind_all, output_name
-from .common import adjacent_equal, adjacent_equal_ops, compaction_indices, \
-    concat_batches, gather_column, sort_operands
+from .common import _batched_takes, adjacent_equal, adjacent_equal_ops, \
+    compaction_indices, concat_batches, gather_column, \
+    lex_sort_permutation, sort_operands
 
-# dtypes whose device payload is a flat 1-D array; such columns can ride a
-# key sort as extra payload operands (docs/perf_r3.md: payload carry is
-# ~free, versus 26–65 ms per post-hoc 4M-row gather)
+# dtypes whose device payload is a flat 1-D array: the fast kernel gathers
+# such columns through the key sort's permutation in batched row-gathers
 _FLAT_KINDS = frozenset({
     T.TypeKind.INT8, T.TypeKind.INT16, T.TypeKind.INT32, T.TypeKind.INT64,
     T.TypeKind.FLOAT32, T.TypeKind.FLOAT64, T.TypeKind.BOOLEAN,
@@ -161,8 +162,8 @@ class HashAggregateExec(UnaryExec):
                 f"{type(self.sort_sensitive[0]).__name__} supports "
                 f"COMPLETE mode only (not decomposable)")
 
-        # ---- round-3 fast path eligibility (docs/perf_r3.md) ----------
-        # values ride the key sort as payload; group-slot layout shrinks
+        # ---- fast path eligibility ----------------------------------
+        # values follow the key sort's permutation; group-slot layout shrinks
         # to `small_groups_bucket` when the observed group count allows
         self.small_groups_bucket = small_groups_bucket
         self._upd_value_exprs: List[Expression] = []
@@ -232,8 +233,7 @@ class HashAggregateExec(UnaryExec):
             nullable = [True] * len(all_cols)
         ops = sort_operands(all_cols, [False] * len(all_cols),
                             [True] * len(all_cols), live, nullable)
-        iota = jnp.arange(cap, dtype=jnp.int32)
-        perm = jax.lax.sort(ops + [iota], num_keys=len(ops) + 1)[-1]
+        perm = lex_sort_permutation(ops)
         sorted_keys = [gather_column(c, perm) for c in key_cols]
         sorted_live = jnp.arange(cap, dtype=jnp.int32) < n_live
         if key_cols:
@@ -243,7 +243,7 @@ class HashAggregateExec(UnaryExec):
             eq = jnp.concatenate([jnp.zeros(1, bool),
                                   jnp.ones(cap - 1, bool)])
         new_group = sorted_live & ~eq
-        group_id = jnp.cumsum(new_group.astype(jnp.int32)) - 1
+        group_id = prefix_sum(new_group.astype(jnp.int32)) - 1
         seg = jnp.where(sorted_live, group_id, cap)
         count = jnp.sum(new_group.astype(jnp.int32))
         return perm, seg, new_group, count, sorted_live, n_live
@@ -277,8 +277,8 @@ class HashAggregateExec(UnaryExec):
         return [gather_column(c, perm, slot_live) for c in sorted_keys]
 
     # ------------------------------------------------------------------
-    # Round-3 fast kernel (docs/perf_r3.md): ONE key sort carrying every
-    # aggregate input as payload; cumsum-diff reductions over the sorted
+    # Fast kernel: ONE key sort (a row permutation; every aggregate input
+    # is gathered through it); cumsum-diff reductions over the sorted
     # layout; dual small/large group-slot layout behind a lax.cond so the
     # common small-group-count case pays G-sized per-group gathers
     # instead of capacity-sized ones.
@@ -319,35 +319,35 @@ class HashAggregateExec(UnaryExec):
                                 in_live, nullable)
         nko = len(key_ops)
         iota = jnp.arange(cap, dtype=jnp.int32)
-        # provably non-null columns skip their validity payload lane; their
-        # sorted views share ONE validity object (sorted_live), which also
-        # dedups the per-aggregate non-null-count lanes downstream
-        payload: List[jax.Array] = [iota]
+        # the sort orders a row index only; key words and aggregate inputs
+        # are gathered through it (same-dtype lanes in one row-gather).
+        # Provably non-null columns skip their validity lane; their sorted
+        # views share ONE validity object (sorted_live), which also dedups
+        # the per-aggregate non-null-count lanes downstream
+        sperm = lex_sort_permutation(key_ops)
+        payload: List[jax.Array] = list(key_ops)
         for c, nl in zip(flat_vals, val_nullable):
-            payload.append(c.data.astype(jnp.uint8)
-                           if c.data.dtype == jnp.bool_ else c.data)
+            payload.append(c.data)
             if nl:
-                payload.append(c.validity.astype(jnp.uint8))
-        out = jax.lax.sort(key_ops + payload, num_keys=nko)
-        sorted_key_ops, sperm = out[:nko], out[nko]
+                payload.append(c.validity)
+        out = _batched_takes(payload, sperm)
+        sorted_key_ops = out[:nko]
         n_live = jnp.sum(in_live.astype(jnp.int32))
         sorted_live = iota < n_live
         svals: List[DeviceColumn] = []
-        j = nko + 1
+        j = nko
         for c, nl in zip(flat_vals, val_nullable):
             data = out[j]
             j += 1
-            if c.data.dtype == jnp.bool_:
-                data = data.astype(jnp.bool_)
             if nl:
-                validity = out[j].astype(jnp.bool_)
+                validity = out[j]
                 j += 1
             else:
                 validity = sorted_live
             svals.append(DeviceColumn(data, validity, None, c.dtype))
         eq = adjacent_equal_ops(sorted_key_ops[1:])  # skip the dead lane
         new_group = sorted_live & ~eq
-        gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
+        gid = prefix_sum(new_group.astype(jnp.int32)) - 1
         count = jnp.sum(new_group.astype(jnp.int32))
 
         from ..expressions.aggregates import (FastLanes, LaneResults,
@@ -401,8 +401,8 @@ class HashAggregateExec(UnaryExec):
         # reduction pipeline. Since the round-4 blocked scans shrank the
         # per-tier HLO, a THIRD mid tier (cap/4) is affordable and cuts the
         # group-starts row-gather 5x for mid-cardinality batches
-        # (tools/profile_round4.py: (4M,6) f64 gather 180 ms at L=4M vs
-        # 33 ms at L=1M; 1M-key hash_agg 568 ms -> 228 ms).
+        # (a round-4 chip profile: (4M,6) f64 gather 180 ms at L=4M vs
+        # 33 ms at L=1M; on this installation's chip: not measured).
         G = min(self.small_groups_bucket, cap)
         default = (G, cap >> 2, cap) if cap >> 2 > G else (G, cap)
         tiers = sorted({t for t in (self.layout_tiers or default)

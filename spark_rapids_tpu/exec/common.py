@@ -66,8 +66,9 @@ def _batched_takes(arrays: Sequence[jax.Array], idx: jax.Array
                    ) -> List[jax.Array]:
     """Gather many same-length arrays at ONE index set with as few device
     gathers as possible: same-dtype 1-D arrays stack into a [n, m] matrix
-    for a single row-gather (docs/perf_r3.md: a 4M-row gather costs
-    ~55-65 ms regardless of row width, and sibling gathers do NOT fuse)."""
+    for a single row-gather (a round-3 chip profile had a 4M-row gather
+    cost the same whatever the row width, and sibling gathers NOT fuse; on
+    this installation's chip: not measured)."""
     from collections import defaultdict
     byd = defaultdict(list)
     for i, a in enumerate(arrays):
@@ -126,14 +127,11 @@ def gather(batch: ColumnarBatch, indices: jax.Array, num_rows: jax.Array,
 def compaction_indices(keep: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """Map a keep-mask to (gather_indices, kept_count).
 
-    Stable: kept rows retain relative order. Implemented as a two-operand
-    key sort (drop flag, row index) — TPU scatters run ~40x slower than
-    sorts+gathers (~240ms vs ~6ms per 4M rows on v5e), so the sort
-    formulation beats the classic cumsum-scatter here.
+    Stable: kept rows retain relative order. A stable sort on the drop
+    flag, not the classic cumsum-scatter: sorts and gathers are the
+    primitives this engine leans on everywhere else.
     """
-    cap = keep.shape[0]
-    src = jnp.arange(cap, dtype=jnp.int32)
-    _, indices = jax.lax.sort([(~keep).astype(jnp.uint8), src], num_keys=2)
+    indices = lex_sort_permutation([(~keep).astype(jnp.uint8)])
     return indices, jnp.sum(keep.astype(jnp.int32))
 
 
@@ -289,14 +287,18 @@ def orderable_words(col: DeviceColumn) -> List[jax.Array]:
     if k in (TypeKind.FLOAT32,):
         return [_float_orderable(data, jnp.zeros((), jnp.uint32))]
     if k in (TypeKind.FLOAT64,):
-        # NO f64→u64 bitcast: TPU emulates f64 (f32 pairs) and XLA's x64
-        # rewriter cannot lower 64-bit bitcast_convert. Sort on a native
-        # float operand instead, with a leading nan-flag word so NaN ranks
-        # greatest (Spark total order). sort_operands negates float words
-        # for descending order (bitwise NOT is uint-only).
-        nan = jnp.isnan(data)
-        return [nan.astype(jnp.uint8),
-                jnp.where(nan, jnp.zeros((), data.dtype), data)]
+        # NO f64→u64 bitcast: the TPU compiler carries f64 as an f32 pair
+        # and its x64 rewrite has no 64-bit bitcast-convert (an f64 lane
+        # as a native sort key compiles, but ~7x slower than an i32 one).
+        # The IEEE bits come arithmetically (hashing._double_bits_words:
+        # NaN canonical, -0.0 == 0.0 — Spark's ordering treats both so);
+        # sign-flipped they order as the doubles do, NaN greatest.
+        from ..expressions.hashing import _double_bits_words
+        low, high = _double_bits_words(data)
+        neg = (high >> 31) != 0
+        sign = jnp.uint32(1) << 31
+        return [jnp.where(neg, ~high, high | sign),
+                jnp.where(neg, ~low, low)]
     # integral / date / timestamp / decimal: flip the sign bit
     u = data.astype({1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32,
                      8: jnp.uint64}[data.dtype.itemsize])
@@ -340,13 +342,60 @@ def sort_operands(cols: Sequence[DeviceColumn], descending: Sequence[bool],
                 # adjacent-EQUAL too, which the aggregate's word-level
                 # group-boundary detection relies on
                 w = jnp.where(col.validity, w, jnp.zeros((), w.dtype))
-            if not desc:
-                ops.append(w)
-            elif jnp.issubdtype(w.dtype, jnp.floating):
-                ops.append(-w)      # float words flip by negation
-            else:
-                ops.append(~w)
+            ops.append(~w if desc else w)
     return ops
+
+
+def _i32_lanes(ops: Sequence[jax.Array]) -> List[jax.Array]:
+    """Unsigned key words -> int32 lanes with the same lexicographic order
+    (most significant first): u64 words split in two, and neighbouring
+    narrow words (the u8 dead/null-rank lanes) pack into one lane while
+    they fit 32 bits. Signed i32, not u32: the TPU compiler builds the
+    unsigned comparator ~40% slower."""
+    words: List[Tuple[jax.Array, int]] = []      # (u32 value, bits used)
+    for w in ops:
+        if w.dtype == jnp.bool_:
+            w = w.astype(jnp.uint8)
+        assert jnp.issubdtype(w.dtype, jnp.unsignedinteger), w.dtype
+        if w.dtype.itemsize == 8:
+            words.append(((w >> jnp.uint64(32)).astype(jnp.uint32), 32))
+            words.append((w.astype(jnp.uint32), 32))
+            continue
+        bits = w.dtype.itemsize * 8
+        w = w.astype(jnp.uint32)
+        if words and words[-1][1] + bits <= 32:
+            prev, used = words.pop()
+            words.append(((prev << jnp.uint32(bits)) | w, used + bits))
+        else:
+            words.append((w, bits))
+    sign = jnp.uint32(1) << 31
+    return [jax.lax.bitcast_convert_type(w ^ sign, jnp.int32)
+            for w, _ in words]
+
+
+def lex_sort_permutation(ops: Sequence[jax.Array]) -> jax.Array:
+    """Stable permutation ordering rows by the unsigned key words ``ops``
+    (as ``sort_operands`` builds them), most significant first.
+
+    THE one place a key sort is built. Least-significant-lane-first passes
+    of ONE two-operand stable ``lax.sort`` (i32 lane, i32 row index) inside
+    a ``lax.scan``, so the program holds a single small sort whatever the
+    number and width of the keys; payload columns are gathered through the
+    permutation afterwards, never carried. The TPU compiler's time for a
+    sort follows the comparator and the operand count, not the row count:
+    for v5e at 1M rows (tools/aot_compile.py) a lone i32 key costs ~30 s,
+    three keys + index ~180 s, one f64 key ~210 s, an i32 key carrying
+    i64/f64/f64 payload ~125 s — while this loop costs ~40 s for any key
+    list and a 64-bit gather ~2 s."""
+    lanes = _i32_lanes(ops)
+    iota = jnp.arange(lanes[0].shape[0], dtype=jnp.int32)
+
+    def one_pass(perm, lane):
+        _, perm = jax.lax.sort((jnp.take(lane, perm), perm), num_keys=1,
+                               is_stable=True)
+        return perm, None
+
+    return jax.lax.scan(one_pass, iota, jnp.stack(lanes[::-1]))[0]
 
 
 def adjacent_equal_ops(ops: Sequence[jax.Array]) -> jax.Array:
@@ -368,12 +417,9 @@ def sort_permutation(batch: ColumnarBatch, key_cols: Sequence[DeviceColumn],
                      descending: Sequence[bool], nulls_first: Sequence[bool]
                      ) -> jax.Array:
     """Stable permutation ordering the batch by the given keys."""
-    cap = batch.capacity
     live = batch.row_mask()
-    ops = sort_operands(key_cols, descending, nulls_first, live)
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    out = jax.lax.sort(ops + [iota], num_keys=len(ops) + 1)  # iota key => stable
-    return out[-1]
+    return lex_sort_permutation(
+        sort_operands(key_cols, descending, nulls_first, live))
 
 
 # ---------------------------------------------------------------------------

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from .. import types as T
 from ..batch import ColumnarBatch, DeviceColumn, Field, Schema, bucket_capacity
+from ..expressions.aggregates import _cumsum as prefix_sum
 from ..expressions.base import EvalContext, Expression
 from ..expressions.hashing import murmur3_batch
 from ..types import TypeKind
@@ -253,7 +254,9 @@ class HashJoinExec(BinaryExec):
         # then LIVE null-keyed rows (outer tails still need them), then
         # dead padding — so live rows stay a prefix in sorted order
         rank = jnp.where(valid, 0, jnp.where(live, 1, 2)).astype(jnp.uint8)
-        sorted_h, _, perm = jax.lax.sort([h, rank, iota], num_keys=2)
+        from .common import lex_sort_permutation
+        perm = lex_sort_permutation([h, rank])
+        sorted_h = jnp.take(h, perm)
         n_valid = jnp.sum(valid.astype(jnp.int32)).astype(jnp.int32)
         from .common import gather_columns
         sorted_live = iota < build.num_rows
@@ -266,7 +269,7 @@ class HashJoinExec(BinaryExec):
         # that amortizes over every probe batch.
         prev_ne = jnp.concatenate(
             [jnp.ones(1, bool), sorted_h[1:] != sorted_h[:-1]])
-        gid = jnp.cumsum(prev_ne.astype(jnp.int32)) - 1
+        gid = prefix_sum(prev_ne.astype(jnp.int32)) - 1
         run_start = jax.ops.segment_min(
             iota, gid, num_segments=build.capacity, indices_are_sorted=True)
         nxt = jnp.concatenate(
@@ -316,13 +319,16 @@ class HashJoinExec(BinaryExec):
             return lo, counts
 
         def general_path():
-            # method="sort": one concat-sort instead of a serialized
-            # binary search (log-n dependent gather rounds) — measured
-            # 5.2x faster at 4M probes on v5e. The old side="right"
-            # second search is a build-side run-length gather now.
+            # method="scan": a binary search (log-n dependent gather
+            # rounds in one loop). method="sort" is one concat-sort, but
+            # the TPU compiler spends 54 s (i32) to 113 s (u64) on it at
+            # 1M rows for v5e against ~1 s for the loop
+            # (tools/aot_compile.py); which runs faster on this chip is
+            # not measured. The old side="right" second search is a
+            # build-side run-length gather now.
             lo = jnp.minimum(
                 jnp.searchsorted(sorted_words, h, side="left",
-                                 method="sort").astype(jnp.int32),
+                                 method="scan").astype(jnp.int32),
                 n_valid)
             word_at = jnp.take(sorted_words,
                                jnp.clip(lo, 0, runlen.shape[0] - 1))
@@ -332,7 +338,7 @@ class HashJoinExec(BinaryExec):
             return lo, counts
         lo, counts = jax.lax.cond(dense, dense_path, general_path) \
             if self._exact_probe else general_path()
-        offsets = jnp.cumsum(counts)
+        offsets = prefix_sum(counts)
         # int32 offsets keep the searches native-width; the 64-bit total
         # lets the host detect candidate counts that would wrap them
         total64 = jnp.sum(counts.astype(jnp.int64))
@@ -340,7 +346,7 @@ class HashJoinExec(BinaryExec):
 
     def _side_gather(self, batch, keys, idx, ok, need_keys: bool,
                      subst=None):
-        """ONE batched gather per side (docs/perf_r3.md — sibling gathers
+        """ONE batched gather per side (sibling gathers
         don't fuse; stacked row-gathers are width-flat). Key columns that
         are plain references reuse the already-gathered output column
         instead of adding a duplicate gather lane; on the exact-probe path
@@ -390,7 +396,7 @@ class HashJoinExec(BinaryExec):
         j = jnp.arange(out_cap, dtype=jnp.int32)
         total = offsets[-1]
         probe_row = jnp.searchsorted(offsets, j, side="right",
-                                     method="sort").astype(jnp.int32)
+                                     method="scan").astype(jnp.int32)
         probe_row = jnp.clip(probe_row, 0, stream.capacity - 1)
         start = jnp.take(offsets, probe_row) - jnp.take(counts, probe_row)
         ordinal = j - start

@@ -32,7 +32,7 @@ from ..expressions.base import EvalContext, Expression
 from .base import UnaryExec
 from .basic import bind_all
 from .common import (adjacent_equal, concat_batches, gather_column,
-                     slice_batch, sort_operands)
+                     lex_sort_permutation, slice_batch, sort_operands)
 
 
 class KeyBatchingExec(UnaryExec):
@@ -56,8 +56,7 @@ class KeyBatchingExec(UnaryExec):
             nullable = [not may_skip_null_lane(e) for e in self.keys]
             ops = sort_operands(key_cols, [False] * k, [True] * k, live,
                                 nullable)
-            iota = jnp.arange(batch.capacity, dtype=jnp.int32)
-            perm = jax.lax.sort(ops + [iota], num_keys=len(ops) + 1)[-1]
+            perm = lex_sort_permutation(ops)
             cols = tuple(gather_column(c, perm) for c in batch.columns)
             skeys = [gather_column(c, perm) for c in key_cols]
             sorted_live = jnp.arange(batch.capacity) < batch.num_rows

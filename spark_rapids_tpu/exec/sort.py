@@ -25,7 +25,7 @@ from ..expressions.base import EvalContext, Expression
 from .base import Exec, UnaryExec
 from .basic import bind_all
 from .common import concat_batches, gather, gather_column, slice_batch, \
-    sort_operands
+    sort_permutation
 
 
 @dataclass(frozen=True)
@@ -61,13 +61,11 @@ def desc(e: Expression) -> SortOrder:
 def sort_batch(batch: ColumnarBatch, orders: Sequence[SortOrder],
                ctx: EvalContext = EvalContext()) -> ColumnarBatch:
     """Stable in-core sort of one batch (jit-traceable)."""
-    cap = batch.capacity
     live = batch.row_mask()
     key_cols = [o.child.eval(batch, ctx) for o in orders]
-    ops = sort_operands(key_cols, [o.descending for o in orders],
-                        [o.effective_nulls_first for o in orders], live)
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    perm = jax.lax.sort(ops + [iota], num_keys=len(ops) + 1)[-1]
+    perm = sort_permutation(batch, key_cols,
+                            [o.descending for o in orders],
+                            [o.effective_nulls_first for o in orders])
     return gather(batch, perm, batch.num_rows, live)
 
 

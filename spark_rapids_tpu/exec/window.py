@@ -20,6 +20,7 @@ import jax.numpy as jnp
 
 from .. import types as T
 from ..batch import ColumnarBatch, DeviceColumn, Field, Schema, bucket_capacity
+from ..expressions.aggregates import _cumsum as prefix_sum
 from ..expressions.base import Alias, EvalContext, Expression
 from ..expressions.window import (LagLead, NTile, Rank, RowNumber,
                                   WindowAgg, WindowExpression, WindowFrame,
@@ -27,7 +28,7 @@ from ..expressions.window import (LagLead, NTile, Rank, RowNumber,
 from ..types import TypeKind
 from .base import Exec, UnaryExec
 from .common import adjacent_equal, concat_batches, gather_column, \
-    sort_operands
+    lex_sort_permutation, sort_operands
 
 
 def _unalias(e: Expression) -> Tuple[WindowExpression, str]:
@@ -94,7 +95,7 @@ class WindowExec(UnaryExec):
             [True] * len(pkeys) + [o.effective_nulls_first
                                    for o in spec.orders], live)
         iota = jnp.arange(cap, dtype=jnp.int32)
-        perm = jax.lax.sort(ops + [iota], num_keys=len(ops) + 1)[-1]
+        perm = lex_sort_permutation(ops)
 
         s_pkeys = [gather_column(c, perm) for c in pkeys]
         s_okeys = [gather_column(c, perm) for c in okeys]
@@ -196,7 +197,7 @@ class WindowExec(UnaryExec):
             ok = (iota - off >= 0) & (iota - off < cap)
             sv = gather_column(s, shifted_ix)
             # same partition check: partition id = cumsum(head)
-            pid = jnp.cumsum(head.astype(jnp.int32))
+            pid = prefix_sum(head.astype(jnp.int32))
             same = ok & (jnp.take(pid, shifted_ix) == pid) & live
             data = sv.data
             validity = sv.validity & same
@@ -312,7 +313,7 @@ class WindowExec(UnaryExec):
             # small literal ROWS windows: static shift fold beats the
             # scan/gather machinery (exact for every op, incl. floats)
             p, f = -frame.start, frame.end
-            pid = jnp.cumsum(head.astype(jnp.int32))
+            pid = prefix_sum(head.astype(jnp.int32))
             acc = jnp.full(x.shape, identity, x.dtype)
             for o in range(-p, f + 1):
                 ix = jnp.clip(iota + o, 0, cap - 1)
@@ -394,6 +395,16 @@ class WindowExec(UnaryExec):
         col = o.child.eval(batch, self.ctx)
         col = gather_column(col, self._range_perm)
         data = col.data
+
+        def one_word(d):
+            # f64 order values span two u32 words (hi, lo): fold them into
+            # one u64 so a bound and a data row compare on a single lane
+            ws = orderable_words(col.replace(data=d, validity=col.validity))
+            if len(ws) == 1:
+                return ws[0]
+            return (ws[0].astype(jnp.uint64) << jnp.uint64(32)) \
+                | ws[1].astype(jnp.uint64)
+
         if o.descending:
             # descending layouts sort by FLIPPED orderable words (~w,
             # bijective — value negation would merge INT_MIN with
@@ -401,21 +412,17 @@ class WindowExec(UnaryExec):
             # [v-end, v-start], so the bound value is v - delta and only
             # the word domain flips
             shifted = self._sat_add(data, -delta)
-            word = ~orderable_words(
-                col.replace(data=shifted, validity=col.validity))[0]
-            data_word = ~orderable_words(
-                col.replace(data=data, validity=col.validity))[0]
+            word = ~one_word(shifted)
+            data_word = ~one_word(data)
         else:
             shifted = self._sat_add(data, delta)
-            word = orderable_words(
-                col.replace(data=shifted, validity=col.validity))[0]
-            data_word = orderable_words(
-                col.replace(data=data, validity=col.validity))[0]
+            word = one_word(shifted)
+            data_word = one_word(data)
         nulls_first = o.effective_nulls_first
         n_rank = jnp.where(col.validity,
                            jnp.uint8(1),
                            jnp.uint8(0 if nulls_first else 2))
-        pid_raw = jnp.cumsum(head.astype(jnp.int32))
+        pid_raw = prefix_sum(head.astype(jnp.int32))
         pid = jnp.where(live, pid_raw, jnp.int32(2147483647))
         # tag: lo-side bounds sort BEFORE equal data (rank = count of
         # data strictly below); hi-side bounds sort AFTER equal data
@@ -426,14 +433,13 @@ class WindowExec(UnaryExec):
         # lane keeps them from interleaving among real-valued entries,
         # which preserves the bounds-sort-in-original-order identity the
         # count arithmetic relies on)
-        lanes = [
+        pid = pid.astype(jnp.uint32)            # non-negative by construction
+        perm2 = lex_sort_permutation([
             jnp.concatenate([pid, pid]),
             jnp.concatenate([n_rank, n_rank]),
             jnp.concatenate([data_word, word]),
             jnp.concatenate([tag_data, tag_bound]),
-            jnp.arange(2 * cap, dtype=jnp.int32),
-        ]
-        perm2 = jax.lax.sort(lanes, num_keys=4)[-1]
+        ])
         inv = jnp.zeros(2 * cap, jnp.int32).at[perm2].set(
             jnp.arange(2 * cap, dtype=jnp.int32))
         count_before = inv[cap:] - iota          # data rows sorting before

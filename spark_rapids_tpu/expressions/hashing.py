@@ -23,16 +23,17 @@ from dataclasses import dataclass
 from typing import Sequence, Tuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from .. import types as T
 from ..batch import ColumnarBatch, DeviceColumn
 from ..types import TypeKind
 from .base import EvalContext, Expression
 
-_C1 = jnp.uint32(0xCC9E2D51)
-_C2 = jnp.uint32(0x1B873593)
-_M = jnp.uint32(5)
-_N = jnp.uint32(0xE6546B64)
+_C1 = np.uint32(0xCC9E2D51)
+_C2 = np.uint32(0x1B873593)
+_M = np.uint32(5)
+_N = np.uint32(0xE6546B64)
 
 DEFAULT_SEED = 42
 
@@ -356,11 +357,11 @@ def partition_ids(cols: Sequence[DeviceColumn], num_partitions: int) -> jnp.ndar
 # stripes, then 8-byte words, one 4-byte word, then tail bytes.
 # ---------------------------------------------------------------------------
 
-_XP1 = jnp.uint64(0x9E3779B185EBCA87)
-_XP2 = jnp.uint64(0xC2B2AE3D27D4EB4F)
-_XP3 = jnp.uint64(0x165667B19E3779F9)
-_XP4 = jnp.uint64(0x85EBCA77C2B2AE63)
-_XP5 = jnp.uint64(0x27D4EB2F165667C5)
+_XP1 = np.uint64(0x9E3779B185EBCA87)
+_XP2 = np.uint64(0xC2B2AE3D27D4EB4F)
+_XP3 = np.uint64(0x165667B19E3779F9)
+_XP4 = np.uint64(0x85EBCA77C2B2AE63)
+_XP5 = np.uint64(0x27D4EB2F165667C5)
 
 
 def _rotl64(x, r):

@@ -1393,7 +1393,7 @@ class SubstringIndex(Expression):
         return _string_column(data, new_len, c.validity, ml)
 
 
-_HEX_DIGITS = jnp.asarray(bytearray(b"0123456789ABCDEF"), jnp.uint8)
+_HEX_DIGITS = np.frombuffer(b"0123456789ABCDEF", np.uint8)
 
 
 @dataclass(frozen=True, eq=False)

@@ -426,7 +426,7 @@ class ParquetSource(FileSource):
     # filterRowGroups + MultiFileCloudParquetPartitionReader). Whole-file
     # ds.to_table tasks oversubscribe the pool with their own internal
     # fan-out; one single-threaded task per ROW GROUP measured 64 ms →
-    # 47 ms on the 8×256K-row bench split (tools/profile_round4 notes).
+    # 47 ms on the 8×256K-row bench split (a round-4 profile).
     # ------------------------------------------------------------------
 
     def decode_tasks(self, files):

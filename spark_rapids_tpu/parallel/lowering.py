@@ -219,7 +219,7 @@ class MeshLowering:
         the first key always route together, so the cross-device order is
         total for ANY trailing keys (reference: GpuRangePartitioner's
         sampled bounds)."""
-        from ..exec.common import sort_operands
+        from ..exec.common import lex_sort_permutation, sort_operands
         from ..exec.sort import sort_batch
         if not node.global_sort or self.n_dev == 1:
             child = self._lower_node(node.child)
@@ -245,7 +245,8 @@ class MeshLowering:
             # dead devices contribute dead-flagged samples (sort last)
             gathered = [jax.lax.all_gather(s, axis).reshape(-1)
                         for s in samp]
-            slanes = jax.lax.sort(gathered, num_keys=len(gathered))
+            sperm = lex_sort_permutation(gathered)
+            slanes = [jnp.take(g, sperm) for g in gathered]
             # n_dev-1 splitters at even quantiles of the sample pool
             total = n_dev * S
             cut = [(d + 1) * total // n_dev for d in range(n_dev - 1)]
@@ -513,9 +514,15 @@ class MeshStageExec(LeafExec):
     def prepare(self):
         """Build (program, stacked_inputs) at the current join_expansion.
         Exposed so benchmarks can time steady-state program executions."""
+        program = self.build_program()
+        return program, [self._stack_input(e) for e in self.lowering.inputs]
+
+    def build_program(self):
+        """The stage's ONE jitted SPMD program over ``lowering.mesh``; takes
+        one stacked batch per ``lowering.inputs`` entry. Needs no data, so
+        tools/aot_compile.py compiles it for chips that are not attached."""
         low = self.lowering
         local_step = low.build_local_step(self.plan)
-        stacked = [self._stack_input(e) for e in low.inputs]
         spec = P(low.axis)
 
         def wrapped(*args):
@@ -524,10 +531,9 @@ class MeshStageExec(LeafExec):
             return (jax.tree.map(lambda x: x[None], out),
                     flags[None])
 
-        program = jax.jit(shard_map(
-            wrapped, mesh=low.mesh, in_specs=(spec,) * len(stacked),
+        return jax.jit(shard_map(
+            wrapped, mesh=low.mesh, in_specs=(spec,) * len(low.inputs),
             out_specs=(spec, spec), check_vma=False))
-        return program, stacked
 
     def _run(self) -> List[ColumnarBatch]:
         if self._results is not None:
@@ -555,11 +561,11 @@ class MeshStageExec(LeafExec):
 # Session hook
 # ---------------------------------------------------------------------------
 
-def try_lower_to_mesh(plan: Exec, mesh: Mesh,
-                      join_expansion: int = 1) -> Optional[MeshStageExec]:
-    """Return the fused mesh stage, or None when the plan shape (or any
-    node in it) is outside the fusable subset."""
-    try:
-        return MeshLowering(mesh, join_expansion=join_expansion).lower(plan)
-    except MeshUnsupported:
-        return None
+def lower_to_mesh(plan: Exec, mesh: Mesh,
+                  join_expansion: int = 1) -> MeshStageExec:
+    """The fused mesh stage for ``plan``. Raises ``MeshUnsupported``, with
+    the reason, when the plan shape (or any node in it) is outside the
+    fusable subset — the caller decides what runs instead and says so
+    (``Session._lower_to_mesh`` records the reason; no silent switch of
+    data plane)."""
+    return MeshLowering(mesh, join_expansion=join_expansion).lower(plan)

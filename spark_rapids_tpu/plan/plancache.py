@@ -435,7 +435,7 @@ class PlanDecisions:
     reasons: Tuple[Tuple[str, ...], ...]
     #: try_fuse_exec produced a fused stage for this shape
     fuse_eligible: bool = False
-    #: try_lower_to_mesh produced a mesh program for this shape
+    #: lower_to_mesh produced a mesh program for this shape
     mesh_eligible: bool = False
 
 

@@ -23,6 +23,9 @@ class Session:
     def __init__(self, conf: Optional[Dict] = None):
         self.conf = RapidsTpuConf(conf)
         self.last_plan = None          # captured physical plan (exec tree)
+        #: why the last plan's mesh lowering gave way to the host-mediated
+        #: exchange (None: it did not, or ICI shuffle mode was not asked)
+        self.last_mesh_giveway: Optional[str] = None
         #: shape fingerprint of the last prepared plan (None when the
         #: plan cache is off or the plan is uncacheable) — the key the
         #: observed-cost store records per-operator costs under
@@ -68,6 +71,7 @@ class Session:
         CPU-topped plan, or ("exec", plan) for a device plan."""
         from .. import trace as qtrace
         self.last_fingerprint = None
+        self.last_mesh_giveway = None
         if not self.conf.sql_enabled:
             self.last_plan = None
             return "interpret", None
@@ -143,8 +147,7 @@ class Session:
                 # ICI shuffle mode: fuse the planned query onto ONE SPMD
                 # mesh program (exchanges → XLA collectives); unsupported
                 # plan shapes keep the host-mediated exchanges
-                from ..parallel.lowering import try_lower_to_mesh
-                lowered = try_lower_to_mesh(plan, self._mesh())
+                lowered = self._lower_to_mesh(plan)
                 if lowered is not None:
                     plan = lowered
                     self.last_plan = plan
@@ -203,8 +206,7 @@ class Session:
         if decisions.mesh_eligible:
             from ..shuffle.manager import get_shuffle_manager
             if get_shuffle_manager(self.conf).wants_mesh_lowering:
-                from ..parallel.lowering import try_lower_to_mesh
-                lowered = try_lower_to_mesh(plan, self._mesh())
+                lowered = self._lower_to_mesh(plan)
                 if lowered is not None:
                     self.last_plan = lowered
                     return "exec", lowered
@@ -644,6 +646,18 @@ class Session:
         adaptive.note_query_wall(self.conf, self.last_fingerprint,
                                  path, wall_ns)
 
+    def _lower_to_mesh(self, plan):
+        """ICI shuffle mode asked for the mesh data plane: the fused mesh
+        stage, or None with the reason kept in ``last_mesh_giveway`` — the
+        host-mediated exchange then runs, and ``executed_exec_names()`` /
+        ``explain()`` say that it did and why."""
+        from ..parallel.lowering import MeshUnsupported, lower_to_mesh
+        try:
+            return lower_to_mesh(plan, self._mesh())
+        except MeshUnsupported as e:
+            self.last_mesh_giveway = str(e) or type(e).__name__
+            return None
+
     def _mesh(self):
         """1-axis data-parallel mesh over the visible devices."""
         import jax
@@ -709,7 +723,17 @@ class Session:
 
     def explain(self, df: DataFrame,
                 mode: ExplainMode = ExplainMode.ALL) -> str:
-        return Overrides(self.conf).explain(df.plan, mode)
+        text = Overrides(self.conf).explain(df.plan, mode)
+        from ..shuffle.manager import get_shuffle_manager
+        if get_shuffle_manager(self.conf).wants_mesh_lowering:
+            from .overrides import CpuFallbackExec
+            plan = Overrides(self.conf).plan(df.plan)
+            self.last_mesh_giveway = None
+            if not isinstance(plan, CpuFallbackExec) \
+                    and self._lower_to_mesh(plan) is None:
+                text += ("\nmesh lowering gave way to the host-mediated "
+                         f"exchange: {self.last_mesh_giveway}")
+        return text
 
     # ---- plan capture assertions (test support) ----
     def metrics(self) -> dict:
@@ -801,6 +825,8 @@ class Session:
 
         if self.last_plan is not None:
             walk(self.last_plan)
+        if self.last_mesh_giveway is not None:
+            names.append(f"MeshGiveWay[{self.last_mesh_giveway}]")
         return names
 
     def fell_back(self) -> List[str]:

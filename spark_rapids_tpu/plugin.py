@@ -31,7 +31,8 @@ log = logging.getLogger("spark_rapids_tpu")
 
 FATAL_EXIT_CODE = 20     # reference: executor exits 20 on fatal CUDA error
 
-_MIN_JAX = (0, 4, 30)
+#: the release line this tree is built and tested on (jax/jaxlib 0.9.0)
+_JAX_LINE = (0, 9)
 
 
 @dataclass
@@ -85,11 +86,11 @@ class ExecutorRuntime:
     def _version_handshake(self) -> None:
         """Reference: cudf/JNI version checks (Plugin.scala:300-324)."""
         import jax
-        ver = tuple(int(x) for x in jax.__version__.split(".")[:3])
-        if ver < _MIN_JAX:
+        ver = tuple(int(x) for x in jax.__version__.split(".")[:2])
+        if ver < _JAX_LINE:
             raise RuntimeError(
-                f"jax {jax.__version__} is older than the minimum supported "
-                f"{'.'.join(map(str, _MIN_JAX))}")
+                f"jax {jax.__version__} is older than the "
+                f"{'.'.join(map(str, _JAX_LINE))} line this tree runs on")
         if not jax.config.jax_enable_x64:
             raise RuntimeError(
                 "x64 mode is off — int64/float64 SQL semantics require it "
@@ -101,13 +102,11 @@ class ExecutorRuntime:
         import jax
         local = jax.local_devices()
         dev = local[0]
-        hbm = None
-        try:
-            stats = dev.memory_stats()
-            if stats:
-                hbm = stats.get("bytes_limit")
-        except Exception:
-            pass
+        hbm = (dev.memory_stats() or {}).get("bytes_limit")
+        if hbm is None and dev.platform == "tpu":
+            raise RuntimeError(
+                f"{dev} reports no memory_stats()['bytes_limit']: the HBM "
+                f"budget cannot be sized from an unknown device")
         return DeviceInfo(platform=dev.platform,
                           device_kind=getattr(dev, "device_kind", "?"),
                           num_local=len(local),
@@ -124,6 +123,8 @@ class ExecutorRuntime:
         from .memory.catalog import BufferCatalog
         frac = self.conf.get(HBM_POOL_FRACTION.key)
         reserve = self.conf.get(HBM_RESERVE.key)
+        # only a backend that reports no memory (the CPU test platform)
+        # gets a nominal size; on a TPU an unknown size already raised
         hbm = self.device.hbm_bytes or (16 << 30)
         limit = max(int(hbm * frac) - reserve, 1 << 30)
         return BufferCatalog(device_limit=limit,

@@ -691,6 +691,7 @@ class PlanServer:
                 "maxSessions": self._server.max_sessions,
                 "concurrentCollects": self._server.concurrent_collects,
                 "shuttingDown": self._server.shutting_down.is_set(),
+                "device": _device_info(),
             },
             "planCacheEntries": len(plancache.planning_cache()),
             "resultCache": plancache.result_cache().stats(),
@@ -745,6 +746,21 @@ class PlanServer:
             self._thread.join(timeout=10)
 
 
+def _device_info() -> dict:
+    """The device this process computes on, as JAX reports it — what a
+    client needs to tell a chip run from a CPU one. Asking initialises the
+    backend, so a server that cannot get its chip fails at start-up (the
+    readiness line is formatted from these stats), not at its first query."""
+    import jax
+    devs = jax.devices()
+    stats = devs[0].memory_stats() or {}
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs),
+            "peakBytesInUse": stats.get("peak_bytes_in_use"),
+            "bytesLimit": stats.get("bytes_limit"),
+            "compileCacheDir": jax.config.jax_compilation_cache_dir}
+
+
 def readiness_line(server: PlanServer) -> str:
     """The stdout readiness signal wrapping process managers (and the
     router's worker spawner) parse: ``listening on <host>:<port>`` with
@@ -759,13 +775,8 @@ def readiness_line(server: PlanServer) -> str:
 
 def main(argv=None) -> int:
     import argparse
-    import os
-    if os.environ.get("JAX_PLATFORMS") == "cpu":
-        # the deployment env force-registers the TPU platform regardless of
-        # JAX_PLATFORMS (tests/conftest.py documents this); honor an
-        # explicit CPU request so the server can run device-less
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+    from .. import compile_cache
+    compile_cache.enable()
     p = argparse.ArgumentParser(
         description="spark-rapids-tpu plan server")
     p.add_argument("--host", default="127.0.0.1")

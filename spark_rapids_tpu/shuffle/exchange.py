@@ -165,7 +165,8 @@ class ShuffleExchangeExec(UnaryExec):
     def _sample_range_bounds(self, batches: List[ColumnarBatch]) -> None:
         """Compute range bounds from the materialized input (reference:
         GpuRangePartitioner.sketch/determineBounds)."""
-        from ..exec.common import sort_operands, gather_column
+        from ..exec.common import (gather_column, lex_sort_permutation,
+                                   sort_operands)
         part: RangePartitioning = self.partitioning
         n = self.partitioning.num_partitions
         # concat all key columns, sort, take n-1 evenly spaced bound rows
@@ -182,8 +183,7 @@ class ShuffleExchangeExec(UnaryExec):
             live = kb.row_mask()
             ops = sort_operands(
                 list(kb.columns), part._descending, part._nulls_first, live)
-            iota = jnp.arange(kb.capacity, dtype=jnp.int32)
-            perm = jax.lax.sort(ops + [iota], num_keys=len(ops) + 1)[-1]
+            perm = lex_sort_permutation(ops)
             skeys = [gather_column(c, perm) for c in kb.columns]
             total = kb.num_rows
             # bound i sits at row (i+1)*total/n

@@ -19,6 +19,9 @@ import numpy as np
 
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
+#: why the library is not loaded (build or dlopen failure); the engine then
+#: runs its Python paths, and ``load_error()`` is how anyone finds out
+_ERROR: Optional[str] = None
 _LOCK = threading.Lock()
 
 _ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
@@ -50,7 +53,7 @@ def _needs_build() -> bool:
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _LIB, _TRIED
+    global _LIB, _TRIED, _ERROR
     with _LOCK:
         if _TRIED:
             return _LIB
@@ -88,13 +91,24 @@ def _load() -> Optional[ctypes.CDLL]:
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
                 ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p]
             _LIB = lib
-        except Exception:
+        except Exception as e:
             _LIB = None
+            stderr = getattr(e, "stderr", None)
+            _ERROR = f"{type(e).__name__}: {e}" + (
+                f"\n{stderr.decode(errors='replace')[-2000:]}"
+                if stderr else "")
         return _LIB
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def load_error() -> Optional[str]:
+    """None when the native library is loaded; otherwise what stopped its
+    build (native/build.sh) or load."""
+    _load()
+    return _ERROR
 
 
 # ---------------------------------------------------------------------------

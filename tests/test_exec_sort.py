@@ -106,3 +106,65 @@ def test_top_n():
     exp = oracle_sort(rows, [(0, False, True)])[:25]
     assert [r[0] for r in got] == [r[0] for r in exp]
     assert len(got) == 25
+
+
+# ---------------------------------------------------------------------------
+# exec/common.lex_sort_permutation — the one place a key sort is built
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtypes", [
+    ("uint8",),
+    ("uint8", "uint32", "uint32"),
+    ("uint8", "uint8", "uint64"),
+    ("uint32", "uint16", "uint8", "uint64", "uint8"),
+], ids=lambda d: "-".join(d))
+def test_lex_sort_permutation_matches_numpy_lexsort(dtypes):
+    """Any mix of unsigned key words, most significant first, stably —
+    however they are packed into i32 lanes and passes underneath."""
+    import numpy as np
+    import jax.numpy as jnp
+    from spark_rapids_tpu.exec.common import lex_sort_permutation
+    rng = np.random.default_rng(5)
+    n = 4096
+    cols = []
+    for dt in dtypes:
+        info = np.iinfo(dt)
+        # few distinct values (ties reach the later keys) incl. the extremes
+        vals = np.array([0, 1, info.max // 2, info.max // 2 + 1,
+                         info.max - 1, info.max], dtype=dt)
+        cols.append(vals[rng.integers(0, len(vals), n)])
+    got = np.asarray(lex_sort_permutation([jnp.asarray(c) for c in cols]))
+    want = np.lexsort(tuple(reversed(cols)))      # lexsort: LAST key primary
+    assert (got == want).all()
+
+
+def test_float64_orderable_words_order_like_spark():
+    """f64 keys sort through two u32 words built without a 64-bit bitcast:
+    -inf < negatives < -0.0 == 0.0 < positives < inf < NaN. (Subnormals
+    are left out: XLA flushes them to zero in the arithmetic that builds
+    the words, as the TPU does when they are transferred.)"""
+    import numpy as np
+    import jax.numpy as jnp
+    from spark_rapids_tpu import types as T
+    from spark_rapids_tpu.batch import DeviceColumn
+    from spark_rapids_tpu.exec.common import orderable_words
+    vals = np.array([float("nan"), float("inf"), 1.7976931348623157e308,
+                     1e300, 1.0000000000000002, 1.0, 2.2250738585072014e-308,
+                     0.0, -0.0, -2.2250738585072014e-308, -1.0,
+                     -1.0000000000000002, -1e300, float("-inf")])
+    rng = np.random.default_rng(1)
+    vals = np.concatenate([vals, rng.normal(0, 1e6, 200), rng.normal(0, 1e-6,
+                                                                     200)])
+    col = DeviceColumn(jnp.asarray(vals), jnp.ones(len(vals), bool), None,
+                       T.FLOAT64)
+    hi, lo = (np.asarray(w).astype(np.uint64) for w in orderable_words(col))
+    word = (hi << np.uint64(32)) | lo
+    order = np.argsort(word, kind="stable")
+    s = vals[order]
+    finite = s[~np.isnan(s)]
+    assert (np.diff(finite) >= 0).all()
+    assert np.isnan(s[-1]) and np.isinf(s[-2]) and s[-2] > 0
+    assert word[7] == word[8]                     # 0.0 and -0.0: one key
+    # distinct doubles get distinct words (np.unique folds -0.0 into 0.0
+    # too, and counts the NaN once)
+    assert len(np.unique(word)) == len(np.unique(vals))

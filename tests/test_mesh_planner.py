@@ -113,6 +113,11 @@ def test_mesh_join_overflow_retries():
 
 NO_BROADCAST = dict(ICI)
 NO_BROADCAST["spark.rapids.tpu.sql.autoBroadcastJoinThreshold"] = 0
+# ... and at run time: planning reads the join's partition count, which
+# materializes the 41-row build side and lets the adaptive switch turn the
+# join into a broadcast one, which has no mesh lowering (the session then
+# reports MeshGiveWay[broadcast join without broadcast exchange child])
+NO_BROADCAST["spark.rapids.tpu.sql.adaptive.broadcastJoin.enabled"] = False
 
 
 def _shuffled_vs_cpu(df_fn, ignore_order=True, require_exchanges=0):
@@ -169,3 +174,37 @@ def test_planned_global_sort_on_mesh():
         return table(FACT).order_by(desc(col("v")), asc(col("k")))
     ses = _shuffled_vs_cpu(q, ignore_order=False, require_exchanges=1)
     assert "MeshStageExec" in ses.executed_exec_names()
+
+
+def test_mesh_giveway_reason_is_visible():
+    """ICI shuffle mode asked for the mesh data plane; when lowering gives
+    way to the host-mediated exchange the session SAYS so, with the reason,
+    in executed_exec_names() and explain() — never a silent switch. Here
+    the adaptive runtime broadcast switch (left on) turns the 41-row build
+    side's join into a broadcast one, which has no mesh lowering."""
+    conf = dict(NO_BROADCAST)
+    conf["spark.rapids.tpu.sql.adaptive.broadcastJoin.enabled"] = True
+
+    def q():
+        # planning the group-by above the join reads the join's partition
+        # count — which is what materializes the build side and switches
+        return (table(FACT)
+                .join(table(DIM), ["k"], ["dk"], JoinType.INNER)
+                .group_by("g")
+                .agg(Sum(col("v")).alias("sv"), Count().alias("c")))
+    cpu = Session({"spark.rapids.tpu.sql.enabled": False})
+    tpu = Session(conf)
+    actual = tpu.collect(q())
+    names = tpu.executed_exec_names()
+    assert not any("MeshStage" in n for n in names), names
+    assert tpu.last_mesh_giveway == \
+        "broadcast join without broadcast exchange child"
+    assert f"MeshGiveWay[{tpu.last_mesh_giveway}]" in names
+    assert "mesh lowering gave way to the host-mediated exchange: " \
+        "broadcast join" in tpu.explain(q())
+    assert_tables_equal(actual, cpu.collect(q()), ignore_order=True)
+    # a plan that does lower reports no give-way
+    pinned = Session(NO_BROADCAST)
+    pinned.collect(q())
+    assert pinned.last_mesh_giveway is None
+    assert "MeshStageExec" in pinned.executed_exec_names()

@@ -25,6 +25,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
+from harness.threads import handler_threads
 from spark_rapids_tpu.batch import to_arrow
 from spark_rapids_tpu.exec import InMemoryScanExec
 from spark_rapids_tpu.expressions import col
@@ -153,10 +154,10 @@ def _wait_threads(baseline: int, timeout_s: float = 5.0) -> None:
     """Server handler threads must drain once their connections close."""
     deadline = time.monotonic() + timeout_s
     while time.monotonic() < deadline:
-        if threading.active_count() <= baseline:
+        if handler_threads() <= baseline:
             return
         time.sleep(0.02)
-    assert threading.active_count() <= baseline, \
+    assert handler_threads() <= baseline, \
         f"leaked threads: {[t.name for t in threading.enumerate()]}"
 
 
@@ -165,7 +166,7 @@ def _differential(t: pa.Table, mode: str, kind: str,
     cat = device_budget()
     clean = _wire_exchange(t)
     assert cat.total_pinned() == 0
-    baseline_threads = threading.active_count()
+    baseline_threads = handler_threads()
     m0 = transport_metrics().snapshot()
     with net_injection(mode, fault_kind=kind, delay_ms=5, **inj_kw):
         faulted = _wire_exchange(t)

@@ -873,6 +873,13 @@ def test_stats_wire_op_and_stable_schema():
         assert info["host"] == "127.0.0.1"
         assert info["port"] == server.port
         assert info["maxSessions"] >= 1 and not info["shuttingDown"]
+        # the device the server computes on, as JAX reports it — what a
+        # client (chip_smoke.py) tells a chip run from a CPU run by
+        import jax
+        dev = info["device"]
+        assert (dev["platform"], dev["kind"], dev["count"]) == (
+            jax.devices()[0].platform, jax.devices()[0].device_kind,
+            len(jax.devices()))
         assert st["counters"]["resultCacheHitCount"] >= 1
         assert set(st["admission"]) == {"concurrentCollects", "admitted",
                                         "inFlight", "waitTimeNs"}

@@ -42,8 +42,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(
     os.path.abspath(__file__)), ".."))
 
-# virtual CPU devices BEFORE jax imports (same dance as tests/conftest.py
-# — the soak exercises the recovery plane, not the chip)
+# On the CPU by design: the soak exercises the recovery plane over eight
+# virtual devices, never the chip (set before jax imports, as conftest does)
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
@@ -52,7 +52,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 
 import numpy as np           # noqa: E402

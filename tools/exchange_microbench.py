@@ -66,7 +66,7 @@ def _table(n):
 
 
 def _time(fn, reps=REPS):
-    """Min over reps (this class of host is noisy; docs/perf_r5.md uses
+    """Min over reps (this class of host is noisy; the round-5 notes used
     the same discipline)."""
     return _time_group([fn], reps)[0]
 

@@ -26,6 +26,11 @@ Results land in docs/profiling.md; the <2-min smoke-tier mini runs are
 ``pytest -m "serving and smoke"`` (tests/test_serving_concurrent.py and
 tests/test_serving_fleet.py), which drive this module with small
 parameters.
+
+The device is whatever JAX gives the serving processes — nothing here pins
+a platform. Every report names it: ``device`` (single server: this process
+IS the server) or ``worker_devices`` (fleet: this process only routes, stays
+off JAX and says so under ``router_process_backends``; one worker per chip).
 """
 
 from __future__ import annotations
@@ -39,8 +44,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def _tables(rows: int):
@@ -212,9 +215,17 @@ def run_load(clients: int, rounds: int, rows: int,
         "plan_cache_hits_client": sum(1 for s in samples
                                       if s[4] == "hit"),
         "server": stats,
+        "device": stats["server"]["device"],
         "leaked_sessions": leaked_sessions,
     }
     return out
+
+
+def _initialised_backends() -> list:
+    """JAX backends this (routing) process has initialised: [] on a chip
+    host, where holding the chip here would starve the workers."""
+    from jax._src import xla_bridge
+    return sorted(xla_bridge._backends)
 
 
 def run_fleet_load(clients: int, rounds: int, rows: int, fleet: int,
@@ -521,6 +532,10 @@ def run_fleet_load(clients: int, rounds: int, rows: int, fleet: int,
         "rolling_restart": restart_report or None,
         "rehydration_hits": rehydration,
         "leaked_sessions": leaked_sessions,
+        "worker_devices": {
+            wid: ((ws or {}).get("server") or {}).get("device")
+            for wid, ws in stats["workers"].items()},
+        "router_process_backends": _initialised_backends(),
     }
 
 
