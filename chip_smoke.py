@@ -53,6 +53,7 @@ sys.path.insert(0, HERE)
 
 REL_TOL = 1e-6      # tests/harness/asserts.py: assert_rows_equal's default
 ABS_TOL = 1e-9
+QUERY_TIMEOUT_S = 1000.0    # per reply; the whole smoke has 1200 s
 # f64 sums and averages run on the device only with this on (the planner
 # otherwise keeps them on the CPU: the chip carries f64 as an f32 pair,
 # docs/tpu_compat.md). The tolerance above is what the replies are held to.
@@ -281,7 +282,7 @@ def served_phase(args):
         emit(phase="server", port=server.port,
              ready_seconds=round(time.perf_counter() - t0, 3))
         client = PlanClient("127.0.0.1", server.port, conf=QUERY_CONF,
-                            timeout=args.query_timeout)
+                            timeout=QUERY_TIMEOUT_S)
         device = client.stats()["server"]["device"]
         emit(phase="server", device=device)
         if device["platform"] != "tpu" and not args.allow_cpu:
@@ -378,20 +379,16 @@ def mesh_phase(args):
     if not any(n.startswith("MeshStage") for n in names):
         raise SmokeFailure(f"no MeshStage executed: {names}")
 
+    # the executable MeshStageExec._run executed and the inputs it staged
     stage = ici.last_plan
-    program, stacked = stage.prepare()
-    shards = []
-    for b in stacked:
-        leaf = b.columns[0].data
-        rows = np.asarray(b.num_rows).reshape(-1)
-        shards.append({"devices": sorted(s.device.id
-                                         for s in leaf.addressable_shards),
-                       "rows_per_device": [int(r) for r in rows]})
+    shards = stage.staged
     emit(phase="mesh", inputs=shards, lowered=stage.lowered)
+    if not shards:
+        raise SmokeFailure("the mesh stage staged no input")
     for s in shards:
         if len(set(s["devices"])) != 4 or min(s["rows_per_device"]) <= 0:
             raise SmokeFailure(f"input not spread over four devices: {s}")
-    hlo = program.lower(*stacked).compile().as_text()
+    hlo = stage.executed.as_text()
     n_a2a = hlo.count("all-to-all")
     emit(phase="mesh", all_to_all_in_program=n_a2a)
     if n_a2a == 0:
@@ -425,7 +422,6 @@ def main(argv=None):
     p.add_argument("--allow-cpu", action="store_true",
                    help="rehearsal: run to the end on a CPU server, never "
                         "print ok")
-    p.add_argument("--query-timeout", type=float, default=1000.0)
     args = p.parse_args(argv)
     if args.rows is None:
         args.rows = 1 << 22 if args.chips == 4 else 1 << 24

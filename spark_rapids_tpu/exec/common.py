@@ -386,7 +386,10 @@ def lex_sort_permutation(ops: Sequence[jax.Array]) -> jax.Array:
     for v5e at 1M rows (tools/aot_compile.py) a lone i32 key costs ~30 s,
     three keys + index ~180 s, one f64 key ~210 s, an i32 key carrying
     i64/f64/f64 payload ~125 s — while this loop costs ~40 s for any key
-    list and a 64-bit gather ~2 s."""
+    list and a 64-bit gather ~2 s. At run time it is the gathers that cost:
+    on a v5 lite at 1M rows a pass is ~10 ms (the sort 2 ms of it), so three
+    words take 28.5 ms where the one 4-operand sort took 3.0 ms
+    (tools/chip_probe.py, PR 25; PERF.md weighs the trade)."""
     lanes = _i32_lanes(ops)
     iota = jnp.arange(lanes[0].shape[0], dtype=jnp.int32)
 

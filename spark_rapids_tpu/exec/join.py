@@ -241,7 +241,7 @@ class HashJoinExec(BinaryExec):
         """Sort the build side by probe word and MATERIALIZE it in that
         order. Expansion then gathers build columns directly at sorted
         positions — no perm indirection per probe batch (a 1M-row index
-        gather costs ~7 ms on this chip; the build-side gather here is
+        gather cost ~7 ms in the old plug-in's profile; the build-side gather here is
         paid once and amortizes over every probe batch)."""
         keys = [e.eval(build, self.ctx) for e in self.right_keys]
         live = build.row_mask()
@@ -323,9 +323,10 @@ class HashJoinExec(BinaryExec):
             # rounds in one loop). method="sort" is one concat-sort, but
             # the TPU compiler spends 54 s (i32) to 113 s (u64) on it at
             # 1M rows for v5e against ~1 s for the loop
-            # (tools/aot_compile.py); which runs faster on this chip is
-            # not measured. The old side="right" second search is a
-            # build-side run-length gather now.
+            # (tools/aot_compile.py). It pays at run time: 158.7 ms
+            # against 21.5 ms for 1M probes in 1M on a v5 lite
+            # (tools/chip_probe.py, PR 25). The old side="right" second
+            # search is a build-side run-length gather now.
             lo = jnp.minimum(
                 jnp.searchsorted(sorted_words, h, side="left",
                                  method="scan").astype(jnp.int32),

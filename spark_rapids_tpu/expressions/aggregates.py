@@ -36,12 +36,14 @@ from .base import EvalContext, Expression
 
 # NOTE on TPU cost model (docs/tpu_compat.md): jax.ops.segment_* lowers
 # to scatters; 64-bit operands are EMULATED on v5e, which makes their
-# scatters ~4.5x the 32-bit cost (measured 340ms vs 74ms per 4M rows).
+# scatters ~4.5x the 32-bit cost (340ms vs 74ms per 4M rows). The ms
+# figures in this file's comments are round-3/4 profiles taken through the
+# old plug-in; none is re-measured on this installation.
 # When the aggregate exec publishes the per-group (start, end) row bounds
 # it already computed (segment_bounds context), every segment reduction
 # instead runs as a SEGMENTED HILLIS-STEELE SUFFIX SCAN inside one
 # lax.fori_loop — log2(n) passes of roll+where+combine, all elementwise
-# (36ms vs 329ms for a 4M f64 sum), followed by one gather at the group
+# (36ms vs 329ms for a 4M f64 sum, round-3 profile), followed by one gather at the group
 # starts. Exact for integers; for floats the pairwise tree is MORE
 # accurate than sequential scatter accumulation. (lax.associative_scan
 # unrolls: for v5e at 1M i32 rows it compiles to 38 MB of code in ~127 s,
@@ -92,7 +94,8 @@ def _cumsum(x):
 # ---------------------------------------------------------------------------
 # Batched lane reductions
 #
-# A 4M-row gather costs ~55–65 ms on this chip NO MATTER the element type,
+# A 4M-row gather cost ~55–65 ms (round-4 profile, old plug-in) NO MATTER
+# the element type,
 # and sibling gathers do NOT fuse — but a [N, m] matrix ROW gather costs the
 # same as one scalar gather. So the fast aggregation path batches EVERY
 # per-group reduction into shared float64 lane stacks:
@@ -198,7 +201,8 @@ def _prefix_ladder_flat(m: jax.Array) -> jax.Array:
 def _prefix_ladder(m: jax.Array) -> jax.Array:
     """Inclusive prefix sum along axis 0 (native cumsum on emulated 64-bit
     lowers to a vmem-exhausting reduce-window; cumsum over (4M,6) f64 also
-    measures 160 ms where this blocked ladder is ~4 ms)."""
+    took 160 ms where this blocked ladder took ~4 ms — round-4 profile, old
+    plug-in)."""
     n = m.shape[0]
     C = _SCAN_BLOCK
     if n <= C or n % C != 0:
@@ -295,7 +299,8 @@ class LaneResults:
     Every reduction kind runs one blocked segmented suffix scan (group
     totals land on each group's first row) followed by ONE [L]-row-gather
     at group starts; the gather is the tier-dependent cost (a [4M,6] f64
-    row-gather at L=4M is ~180 ms, ~33 ms at L=1M — pick tiers well)."""
+    row-gather at L=4M was ~180 ms, ~33 ms at L=1M in the round-4 profile,
+    old plug-in — pick tiers well)."""
 
     def __init__(self, lanes: FastLanes, seg: jax.Array,
                  starts: jax.Array, live_slot: jax.Array):
@@ -305,7 +310,7 @@ class LaneResults:
         self._sum_at = None
         if lanes.sum_lanes:
             # one two-level segmented suffix scan (group-local rounding,
-            # ~4 ms per (4M,6) f64) + ONE [L]-row-gather at group starts —
+            # ~4 ms per (4M,6) f64, round-4 profile) + ONE [L]-row-gather at group starts —
             # the cheapest shape at every tier now that the scan is blocked
             # (the old prefix-difference needed TWO gathers and was only
             # exact for integer lanes anyway)
@@ -365,8 +370,9 @@ def _seg_sum(x, seg, cap):
     if _seg_bounds() is not None:
         # Segmented sum over key-sorted
         # rows = ONE cumsum + a window difference at the published group
-        # bounds. cumsum is 3–19 ms per 4M f64 rows where the emulated-
-        # 64-bit scatter was 285–320 ms. Integer cumsums wrap mod 2^w, so
+        # bounds. cumsum was 3–19 ms per 4M f64 rows where the emulated-
+        # 64-bit scatter was 285–320 ms (round-3 profile, old plug-in).
+        # Integer cumsums wrap mod 2^w, so
         # the difference is exact under Spark's non-ANSI wraparound; float
         # sums trade the scatter's sequential rounding for the prefix
         # tree's (both order-dependent, like Spark itself). Dead slots use

@@ -306,8 +306,8 @@ class Concat(Expression):
 
 
 #: Pallas substring kernel cutover: below this pattern length XLA's rolled
-#: compares win; above it the single-VMEM-pass kernel does (measured on
-#: v5e: k=16 XLA 19 ms vs kernel ~15 ms at 4M x 64B; gap grows with k)
+#: compares win; above it the single-VMEM-pass kernel does (measured
+#: through the old plug-in on v5e: k=16 XLA 19 ms vs kernel ~15 ms at 4M x 64B; gap grows with k)
 _PALLAS_SEARCH_MIN_K = 12
 
 

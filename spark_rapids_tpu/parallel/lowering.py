@@ -477,6 +477,11 @@ class MeshStageExec(LeafExec):
         self._schema = plan.output_schema
         self._results: Optional[List[ColumnarBatch]] = None
         self.lowered = list(lowering.lowered_names)
+        #: the executable that produced ``_results`` (``.as_text()`` is its
+        #: HLO) and, per input, the devices it was staged on with the rows
+        #: on each — what ran, not a program built like it
+        self.executed = None
+        self.staged: List[dict] = []
 
     @property
     def name(self) -> str:
@@ -541,8 +546,11 @@ class MeshStageExec(LeafExec):
         low = self.lowering
         for attempt in range(5):
             program, stacked = self.prepare()
-            out, flags = program(*stacked)
+            compiled = program.lower(*stacked).compile()
+            out, flags = compiled(*stacked)
             if not bool(np.any(np.asarray(jax.device_get(flags)))):
+                self.executed = compiled
+                self.staged = [_placement(b) for b in stacked]
                 self._results = unstack_batches(out)
                 return self._results
             # capacity flags don't say WHICH bucket lost; grow all —
@@ -555,6 +563,15 @@ class MeshStageExec(LeafExec):
 
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
         yield self._run()[p]
+
+
+def _placement(stacked: ColumnarBatch) -> dict:
+    """Where one stacked input sits: device ids of its shards, live rows on
+    each."""
+    leaf = jax.tree.leaves(stacked)[0]
+    rows = np.asarray(jax.device_get(stacked.num_rows)).reshape(-1)
+    return {"devices": sorted(s.device.id for s in leaf.addressable_shards),
+            "rows_per_device": [int(r) for r in rows]}
 
 
 # ---------------------------------------------------------------------------

@@ -724,15 +724,11 @@ class Session:
     def explain(self, df: DataFrame,
                 mode: ExplainMode = ExplainMode.ALL) -> str:
         text = Overrides(self.conf).explain(df.plan, mode)
-        from ..shuffle.manager import get_shuffle_manager
-        if get_shuffle_manager(self.conf).wants_mesh_lowering:
-            from .overrides import CpuFallbackExec
-            plan = Overrides(self.conf).plan(df.plan)
-            self.last_mesh_giveway = None
-            if not isinstance(plan, CpuFallbackExec) \
-                    and self._lower_to_mesh(plan) is None:
-                text += ("\nmesh lowering gave way to the host-mediated "
-                         f"exchange: {self.last_mesh_giveway}")
+        # explain() plans nothing (planning a join can materialize its build
+        # side); a give-way is a fact of the last collect, reported as such
+        if self.last_mesh_giveway is not None:
+            text += ("\nlast collect: mesh lowering gave way to the "
+                     f"host-mediated exchange: {self.last_mesh_giveway}")
         return text
 
     # ---- plan capture assertions (test support) ----

@@ -206,20 +206,6 @@ class WorkerHandle:
     def alive(self) -> bool:
         return self.proc is not None and self.proc.poll() is None
 
-    def alive_after_failure(self, settle_s: float = 0.5) -> bool:
-        """``alive()`` for the failure path: a killed process closes its
-        sockets a moment BEFORE it can be reaped, so a broken transaction
-        can be seen while ``poll()`` still says running (longer on a loaded
-        host). Give a dying process that moment; a healthy one costs the
-        full ``settle_s`` only here, on a transaction that already failed."""
-        if self.proc is None:
-            return False
-        try:
-            self.proc.wait(timeout=settle_s)
-        except subprocess.TimeoutExpired:
-            return True
-        return False
-
     def kill(self) -> None:
         if self.proc is None:
             return
@@ -1230,10 +1216,9 @@ class Router:
         observed dead is promoted DEAD immediately (no rehabilitation
         without replacement — the PR-11 rule that a corpse cannot beat
         itself back into the ring)."""
-        alive = w.alive_after_failure()     # may wait: outside the lock
         with self._lock:
             w.failures += 1
-            if not alive:
+            if not w.alive():
                 w.state = DEAD
             elif w.state == LIVE:
                 w.state = SUSPECT

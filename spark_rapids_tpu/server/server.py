@@ -756,9 +756,7 @@ def _device_info() -> dict:
     stats = devs[0].memory_stats() or {}
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs),
-            "peakBytesInUse": stats.get("peak_bytes_in_use"),
-            "bytesLimit": stats.get("bytes_limit"),
-            "compileCacheDir": jax.config.jax_compilation_cache_dir}
+            "peakBytesInUse": stats.get("peak_bytes_in_use")}
 
 
 def readiness_line(server: PlanServer) -> str:

@@ -194,14 +194,17 @@ def test_mesh_giveway_reason_is_visible():
                 .agg(Sum(col("v")).alias("sv"), Count().alias("c")))
     cpu = Session({"spark.rapids.tpu.sql.enabled": False})
     tpu = Session(conf)
+    # explain() plans nothing: the give-way is a fact of a collect
+    assert "gave way" not in tpu.explain(q())
+    assert tpu.last_mesh_giveway is None
     actual = tpu.collect(q())
     names = tpu.executed_exec_names()
     assert not any("MeshStage" in n for n in names), names
     assert tpu.last_mesh_giveway == \
         "broadcast join without broadcast exchange child"
     assert f"MeshGiveWay[{tpu.last_mesh_giveway}]" in names
-    assert "mesh lowering gave way to the host-mediated exchange: " \
-        "broadcast join" in tpu.explain(q())
+    assert "last collect: mesh lowering gave way to the host-mediated " \
+        "exchange: broadcast join" in tpu.explain(q())
     assert_tables_equal(actual, cpu.collect(q()), ignore_order=True)
     # a plan that does lower reports no give-way
     pinned = Session(NO_BROADCAST)

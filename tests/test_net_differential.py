@@ -25,7 +25,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
-from harness.threads import handler_threads
+from harness.threads import warm_reader_pool
 from spark_rapids_tpu.batch import to_arrow
 from spark_rapids_tpu.exec import InMemoryScanExec
 from spark_rapids_tpu.expressions import col
@@ -154,10 +154,10 @@ def _wait_threads(baseline: int, timeout_s: float = 5.0) -> None:
     """Server handler threads must drain once their connections close."""
     deadline = time.monotonic() + timeout_s
     while time.monotonic() < deadline:
-        if handler_threads() <= baseline:
+        if threading.active_count() <= baseline:
             return
         time.sleep(0.02)
-    assert handler_threads() <= baseline, \
+    assert threading.active_count() <= baseline, \
         f"leaked threads: {[t.name for t in threading.enumerate()]}"
 
 
@@ -166,7 +166,8 @@ def _differential(t: pa.Table, mode: str, kind: str,
     cat = device_budget()
     clean = _wire_exchange(t)
     assert cat.total_pinned() == 0
-    baseline_threads = handler_threads()
+    warm_reader_pool()
+    baseline_threads = threading.active_count()
     m0 = transport_metrics().snapshot()
     with net_injection(mode, fault_kind=kind, delay_ms=5, **inj_kw):
         faulted = _wire_exchange(t)

@@ -31,7 +31,7 @@ import numpy as np
 import pyarrow as pa
 import pytest
 
-from harness.threads import handler_threads
+from harness.threads import warm_reader_pool
 from spark_rapids_tpu.memory.catalog import device_budget
 from spark_rapids_tpu.memory.retry import oom_injection
 from spark_rapids_tpu.shuffle.lineage import (LineageMissError,
@@ -74,10 +74,10 @@ def baselines(soak, shapes):
 def _threads_settle(baseline, timeout_s=10.0):
     deadline = time.monotonic() + timeout_s
     while time.monotonic() < deadline:
-        if handler_threads() <= baseline:
+        if threading.active_count() <= baseline:
             return
         time.sleep(0.02)
-    assert handler_threads() <= baseline, \
+    assert threading.active_count() <= baseline, \
         f"leaked threads: {sorted(t.name for t in threading.enumerate())}"
 
 
@@ -97,7 +97,8 @@ def test_kill_peer_mid_query_recomputes_bit_for_bit(shape, soak, shapes,
     """replicas=0: the dead primary's blocks exist NOWHERE else — every
     one the reduce side still needs is recomputed from lineage."""
     cat = device_budget()
-    baseline_threads = handler_threads()
+    warm_reader_pool()
+    baseline_threads = threading.active_count()
     m0 = lineage_metrics().snapshot()
     parts = soak.run_query(shapes[shape], replicas=0, kill="mid_read")
     m1 = lineage_metrics().snapshot()
@@ -116,7 +117,8 @@ def test_kill_peer_mid_query_replica_serves(shape, soak, shapes,
     """replicas=1: every block was replicated at publish — the replica
     serves them all and recompute never fires."""
     cat = device_budget()
-    baseline_threads = handler_threads()
+    warm_reader_pool()
+    baseline_threads = threading.active_count()
     m0 = lineage_metrics().snapshot()
     parts = soak.run_query(shapes[shape], replicas=1, kill="mid_read")
     m1 = lineage_metrics().snapshot()
@@ -609,7 +611,7 @@ def test_plan_server_stop_cancels_active_recompute(monkeypatch):
 
     monkeypatch.setattr(Session, "collect", fake_collect)
     cat = device_budget()
-    baseline_threads = handler_threads()
+    baseline_threads = threading.active_count()
     server = PlanServer().start()
     t = pa.table({"x": np.arange(8, dtype=np.int64)})
     client_errors = []
