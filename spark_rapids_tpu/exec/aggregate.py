@@ -33,7 +33,7 @@ from ..expressions.base import Alias, EvalContext, Expression
 from .base import Exec, UnaryExec
 from .basic import bind_all, output_name
 from .common import _batched_takes, adjacent_equal, adjacent_equal_ops, \
-    compaction_indices, concat_batches, gather_column, \
+    compaction_indices, concat_batches, gather_column, jit_named, \
     lex_sort_permutation, sort_operands
 
 # dtypes whose device payload is a flat 1-D array: the fast kernel gathers
@@ -189,10 +189,14 @@ class HashAggregateExec(UnaryExec):
             and have_keys
             and all(_is_flat(f.dtype) for f in self.buffer_fields))
 
-        self._update_jit = jax.jit(self._update_kernel)
-        self._merge_jit = jax.jit(lambda b: self._merge_kernel(b, final=False))
-        self._final_jit = jax.jit(lambda b: self._merge_kernel(b, final=True))
-        self._eval_buffers_jit = jax.jit(self._eval_buffers_kernel)
+        me = type(self).__name__
+        self._update_jit = jit_named(f"{me}_update", self._update_kernel)
+        self._merge_jit = jit_named(
+            f"{me}_merge", lambda b: self._merge_kernel(b, final=False))
+        self._final_jit = jit_named(
+            f"{me}_final", lambda b: self._merge_kernel(b, final=True))
+        self._eval_buffers_jit = jit_named(
+            f"{me}_evalBuffers", self._eval_buffers_kernel)
 
     @staticmethod
     def _expr_key(e: Expression):
@@ -666,7 +670,7 @@ class HashAggregateExec(UnaryExec):
 
     def _slice_compact(self, batch: ColumnarBatch, cap: int) -> ColumnarBatch:
         from .common import slice_batch
-        return jax.jit(slice_batch, static_argnums=3)(
+        return jit_named("slice_batch", slice_batch, static_argnums=3)(
             batch, jnp.int32(0), jnp.int32(cap), cap)
 
     def _ooc_sorted_merge(self, entries, finalize, cat, buf_schema):
@@ -686,7 +690,7 @@ class HashAggregateExec(UnaryExec):
                          MIN_CAPACITY)
         sorter = OutOfCoreSorter(orders, buf_schema, cat,
                                  chunk_rows=chunk_rows)
-        slice_jit = jax.jit(slice_batch, static_argnums=3)
+        slice_jit = jit_named("slice_batch", slice_batch, static_argnums=3)
 
         def batches():
             from ..memory import acquire_with_retry

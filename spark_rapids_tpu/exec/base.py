@@ -110,35 +110,44 @@ class Exec:
         """Iterate one partition, maintaining the op's metrics: batch and
         row counts plus opTime (ns spent INSIDE this operator's iterator,
         including its children — the reference's NS_TIMING convention).
-        With query tracing active, the whole partition iteration is one
-        operator span (same name as the metric prefix; the NS_TIMING
-        caveat applies — children nest inside, and the span closes when
-        the consumer exhausts or abandons the iterator)."""
+        With query tracing active the partition's iteration is one
+        operator span (same name as the metric prefix) that closes when
+        the consumer exhausts or abandons the iterator, and each pull is
+        a *pull frame* of it (``trace.OperatorSpan``): what this thread
+        records inside a pull is this operator's, what it records between
+        pulls is the consumer's."""
         from .. import trace as qtrace
-        from ..utils import tracing
         it = self.do_execute_partition(p)
-        with qtrace.span(self.name, kind="operator", partition=p) as sp:
-            rows = 0
+        op = qtrace.open_operator(self.name, p)
+        op_time = self.metrics["opTime"]
+        try:
             while True:
                 t0 = time.perf_counter_ns()
+                if op is not None:
+                    op.enter()
                 try:
-                    # metric-linked profiler range: the slice name in
-                    # xprof is the same exec name collect_metrics()
-                    # reports (the reference wraps operators in NVTX
-                    # ranges the same way)
-                    with tracing.op_range(self.name):
-                        batch = next(it)
-                except StopIteration:
-                    self.metrics["opTime"].add(time.perf_counter_ns() - t0)
-                    if sp is not None:
-                        sp.attrs["rows"] = rows
-                    return
-                self.metrics["opTime"].add(time.perf_counter_ns() - t0)
+                    # (the batch yielded last stays referenced until this
+                    # returns the next: buffers are freed as they were
+                    # before there were pull frames)
+                    batch = next(it)
+                except BaseException as e:
+                    dt = time.perf_counter_ns() - t0
+                    if op is not None:
+                        op.exit(dt)
+                    if isinstance(e, StopIteration):
+                        op_time.add(dt)
+                        return
+                    raise
+                dt = time.perf_counter_ns() - t0
+                op_time.add(dt)
+                if op is not None:
+                    op.exit(dt, batch)
                 self.metrics["numOutputBatches"].add(1)
                 self.metrics["numOutputRows"].add_lazy(batch.num_rows)
-                if sp is not None:
-                    rows += int(batch.num_rows)
                 yield batch
+        finally:
+            if op is not None:
+                op.close()
 
     def collect_metrics(self, max_level: int = DEBUG) -> Dict[str, int]:
         """Aggregate this subtree's metrics up to a level (the
@@ -216,9 +225,15 @@ def iter_subplan_tables(plan: Exec):
     Stage re-planning and subplan result sharing materialize interior
     boundaries through this, so a captured subtree output is exactly
     what assemble_result() would have consumed."""
+    from .. import trace as qtrace
     schema = plan.output_schema
     for b in plan.execute():
-        yield to_arrow(b, schema)
+        # the query's one forced wait for the device
+        with qtrace.span("result.d2h", kind="transfer") as sp:
+            t = to_arrow(b, schema)
+            if sp is not None:
+                sp.attrs["bytes"] = t.nbytes
+        yield t
 
 
 def assemble_result(tables, schema) -> pa.Table:

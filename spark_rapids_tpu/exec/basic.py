@@ -24,7 +24,7 @@ from ..batch import (ColumnarBatch, DeviceColumn, Field, Schema,
 from ..expressions.base import Alias, EvalContext, Expression
 from ..types import TypeKind
 from .base import Exec, LeafExec, UnaryExec
-from .common import compact, slice_batch
+from .common import compact, jit_named, slice_batch
 
 
 def output_name(e: Expression, i: int) -> str:
@@ -89,11 +89,17 @@ class InMemoryScanExec(LeafExec):
 
     def _upload_batches(self):
         from ..memory.retry import maybe_inject, with_retry_no_split
+        from ..trace import span
 
         def h2d(chunk):
             maybe_inject("scan.h2d")
-            batch, _ = from_arrow(chunk, schema=self._schema,
-                                  dict_conf=self._dict_conf)
+            with span("scan.h2d", kind="transfer") as sp:
+                batch, _ = from_arrow(chunk, schema=self._schema,
+                                      dict_conf=self._dict_conf)
+                if sp is not None:
+                    sp.attrs["hostBytes"] = chunk.nbytes
+                    # padded to the capacity bucket
+                    sp.attrs["deviceBytes"] = batch.size_bytes()
             return batch
 
         for table in self._tables:
@@ -179,7 +185,7 @@ class ProjectExec(UnaryExec):
             cols = tuple(raw_eval(e, batch, ctx) for e in self.exprs)
             return ColumnarBatch(cols, batch.num_rows), _sum_errors(ctx)
 
-        self._kernel = jax.jit(kernel)
+        self._kernel = jit_named(f"{type(self).__name__}_project", kernel)
 
     @property
     def output_schema(self) -> Schema:
@@ -236,7 +242,7 @@ class FilterExec(UnaryExec):
             keep = c.data & c.validity
             return compact(batch, keep), _sum_errors(ctx)
 
-        self._kernel = jax.jit(kernel)
+        self._kernel = jit_named(f"{type(self).__name__}_filter", kernel)
 
     @property
     def output_schema(self) -> Schema:
@@ -255,7 +261,8 @@ class LocalLimitExec(UnaryExec):
     def __init__(self, limit: int, child: Exec):
         super().__init__(child)
         self.limit = limit
-        self._kernel = jax.jit(
+        self._kernel = jit_named(
+            f"{type(self).__name__}_limit",
             lambda b, remaining: slice_batch(b, jnp.int32(0), remaining))
 
     @property
@@ -357,7 +364,7 @@ class SampleExec(UnaryExec):
             u = jax.random.uniform(key, (batch.capacity,))
             return compact(batch, u < self.fraction)
 
-        self._kernel = jax.jit(kernel)
+        self._kernel = jit_named(f"{type(self).__name__}_sample", kernel)
 
     @property
     def output_schema(self) -> Schema:
@@ -390,7 +397,8 @@ class ExpandExec(UnaryExec):
             cols = tuple(e.eval(batch, self.ctx) for e in self.projections[pi])
             return ColumnarBatch(cols, batch.num_rows)
 
-        self._kernel = jax.jit(kernel, static_argnums=1)
+        self._kernel = jit_named(f"{type(self).__name__}_expand", kernel,
+                                 static_argnums=1)
 
     @property
     def output_schema(self) -> Schema:

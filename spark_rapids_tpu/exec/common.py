@@ -26,6 +26,26 @@ from ..batch import ColumnarBatch, DeviceColumn, Schema
 from ..types import SqlType, TypeKind
 
 
+def jit_named(name: str, fun, **jit_kwargs):
+    """``jax.jit(fun)`` as the program ``jit_<name>``: the one door through
+    which ``exec/``, ``io/``, ``shuffle/`` and ``memory/`` jit, so that a
+    device trace, a lowering's ``fun`` and the compile cache name a program
+    ``<Exec>_<role>`` and not ``_lambda_`` or ``kernel``. A function already
+    called ``name`` (a module-level one such as ``slice_batch``) is jitted
+    as it is and keeps JAX's trace cache across wrappers; any other goes
+    through a wrapper of that name, which like the lambda, closure or
+    bound method it stands for is a new function, traced anew, in every
+    exec instance. (No ``functools.wraps``: JAX names a program after what
+    ``inspect.unwrap`` finds.)"""
+    if getattr(fun, "__name__", None) == name:
+        return jax.jit(fun, **jit_kwargs)
+
+    def named(*args, **kwargs):
+        return fun(*args, **kwargs)
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named, **jit_kwargs)
+
+
 # ---------------------------------------------------------------------------
 # Gather / compact / concat
 # ---------------------------------------------------------------------------

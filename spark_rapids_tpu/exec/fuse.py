@@ -28,7 +28,7 @@ from ..expressions.base import EvalContext
 from .base import Exec, LeafExec
 from .basic import (FilterExec, InMemoryScanExec, LocalLimitExec,
                     ProjectExec, _raise_ansi)
-from .common import compact, slice_batch
+from .common import compact, jit_named, slice_batch
 from .join import HashJoinExec, JoinType
 from .sort import SortExec, TakeOrderedAndProjectExec, sort_batch
 
@@ -88,7 +88,7 @@ class FusedStage:
         planner.walk(plan)
         self.scans = planner.scans
         self.inputs = [next(iter(s._all_batches())) for s in self.scans]
-        self._program = jax.jit(self._trace)
+        self._program = jit_named("FusedStage_program", self._trace)
 
     # -- trace ---------------------------------------------------------
 

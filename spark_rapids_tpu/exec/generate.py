@@ -29,7 +29,7 @@ from ..batch import ColumnarBatch, DeviceColumn, Field, Schema
 from ..expressions.base import EvalContext, Expression
 from ..types import TypeKind
 from .base import UnaryExec
-from .common import compact
+from .common import compact, jit_named
 
 
 class GenerateExec(UnaryExec):
@@ -68,7 +68,7 @@ class GenerateExec(UnaryExec):
             kctx = EvalContext(self.ctx.ansi, {})
             return self._explode_kernel(batch, kctx), _sum_errors(kctx)
 
-        self._kernel = jax.jit(kernel)
+        self._kernel = jit_named(f"{type(self).__name__}_explode", kernel)
 
     @property
     def output_schema(self) -> Schema:

@@ -40,7 +40,8 @@ from ..expressions.hashing import murmur3_batch
 from ..types import TypeKind
 from .base import BinaryExec, Exec
 from .basic import bind_all
-from .common import compact, concat_batches, gather, gather_column
+from .common import compact, concat_batches, gather, gather_column, \
+    jit_named
 
 
 class JoinType(enum.Enum):
@@ -95,7 +96,7 @@ def _keys_equal(a: List[DeviceColumn], b: List[DeviceColumn]) -> jnp.ndarray:
 from functools import partial  # noqa: E402
 
 
-@partial(jax.jit, static_argnums=3)
+@partial(jit_named, "HashJoinExec_sliceTile", static_argnums=3)
 def _slice_tile(build, off, count, cap):
     from .common import slice_batch
     return slice_batch(build, off, count, cap)
@@ -202,10 +203,13 @@ class HashJoinExec(BinaryExec):
             len(self.right_keys) == 1
             and self.right_keys[0].dtype.kind in _EXACT_KINDS)
 
-        self._build_jit = jax.jit(self._build_kernel)
-        self._count_jit = jax.jit(self._count_kernel)
-        self._expand_jit = jax.jit(self._expand_kernel, static_argnums=(4,))
-        self._semi_jit = jax.jit(self._semi_kernel, static_argnums=(4,))
+        me = type(self).__name__
+        self._build_jit = jit_named(f"{me}_build", self._build_kernel)
+        self._count_jit = jit_named(f"{me}_count", self._count_kernel)
+        self._expand_jit = jit_named(f"{me}_expand", self._expand_kernel,
+                                     static_argnums=(4,))
+        self._semi_jit = jit_named(f"{me}_semi", self._semi_kernel,
+                                   static_argnums=(4,))
 
     def _probe_words(self, keys, valid, build_side: bool) -> jnp.ndarray:
         """The sorted/probed search key: exact orderable word (single
@@ -833,11 +837,13 @@ class HashJoinExec(BinaryExec):
         build_rows = sum(int(b.num_rows) for b in build_batches)
         n_buckets = -(-build_rows // self.max_build_rows)
 
-        split_build = jax.jit(
+        split_build = jit_named(
+            f"{type(self).__name__}_splitBuild",
             lambda b, s: compact(
                 b, self._bucket_pids(b, self.right_keys, n_buckets) == s),
             static_argnums=1)
-        split_stream = jax.jit(
+        split_stream = jit_named(
+            f"{type(self).__name__}_splitStream",
             lambda b, s: compact(
                 b, self._bucket_pids(b, self.left_keys, n_buckets) == s),
             static_argnums=1)
@@ -911,8 +917,10 @@ class BroadcastNestedLoopJoinExec(BinaryExec):
         # join type projects out (reference: AST closures in
         # GpuBroadcastNestedLoopJoinExec conditional variants)
         self.condition = condition.bind(pair_schema) if condition else None
-        self._cross_jit = jax.jit(self._cross_kernel)
-        self._count_jit = jax.jit(self._count_kernel)
+        self._cross_jit = jit_named(f"{type(self).__name__}_cross",
+                                    self._cross_kernel)
+        self._count_jit = jit_named(f"{type(self).__name__}_count",
+                                    self._count_kernel)
 
     @property
     def output_schema(self) -> Schema:

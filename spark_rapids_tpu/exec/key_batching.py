@@ -32,7 +32,8 @@ from ..expressions.base import EvalContext, Expression
 from .base import UnaryExec
 from .basic import bind_all
 from .common import (adjacent_equal, concat_batches, gather_column,
-                     lex_sort_permutation, slice_batch, sort_operands)
+                     jit_named, lex_sort_permutation, slice_batch,
+                     sort_operands)
 
 
 class KeyBatchingExec(UnaryExec):
@@ -63,8 +64,9 @@ class KeyBatchingExec(UnaryExec):
             new_group = sorted_live & ~adjacent_equal(skeys)
             return ColumnarBatch(cols, batch.num_rows), new_group
 
-        self._prep_jit = jax.jit(prep)
-        self._slice_jit = jax.jit(
+        self._prep_jit = jit_named(f"{type(self).__name__}_prep", prep)
+        self._slice_jit = jit_named(
+            f"{type(self).__name__}_slice",
             lambda b, start, count, cap: slice_batch(b, start, count, cap),
             static_argnums=3)
 

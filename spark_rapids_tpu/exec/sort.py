@@ -24,8 +24,8 @@ from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..expressions.base import EvalContext, Expression
 from .base import Exec, UnaryExec
 from .basic import bind_all
-from .common import concat_batches, gather, gather_column, slice_batch, \
-    sort_permutation
+from .common import concat_batches, gather, gather_column, jit_named, \
+    slice_batch, sort_permutation
 
 
 @dataclass(frozen=True)
@@ -85,7 +85,9 @@ class SortExec(UnaryExec):
         self.orders = [o.bind(child.output_schema) for o in orders]
         self.global_sort = global_sort
         self.max_rows = max_rows
-        self._sort_jit = jax.jit(lambda b: sort_batch(b, self.orders, self.ctx))
+        self._sort_jit = jit_named(
+            f"{type(self).__name__}_sort",
+            lambda b: sort_batch(b, self.orders, self.ctx))
 
     @property
     def output_schema(self) -> Schema:
@@ -186,13 +188,14 @@ class TakeOrderedAndProjectExec(UnaryExec):
             cut = bucket_capacity(min(self.limit, b.capacity))
             return slice_batch(s, jnp.int32(0), n, cut)
 
-        self._topn_jit = jax.jit(topn)
+        self._topn_jit = jit_named(f"{type(self).__name__}_topn", topn)
 
         def proj(b: ColumnarBatch) -> ColumnarBatch:
             cols = tuple(e.eval(b, self.ctx) for e in self.project)
             return ColumnarBatch(cols, b.num_rows)
 
-        self._proj_jit = jax.jit(proj) if self.project else None
+        self._proj_jit = jit_named(f"{type(self).__name__}_project", proj) \
+            if self.project else None
 
     @property
     def output_schema(self) -> Schema:

@@ -28,7 +28,7 @@ from ..expressions.window import (LagLead, NTile, Rank, RowNumber,
 from ..types import TypeKind
 from .base import Exec, UnaryExec
 from .common import adjacent_equal, concat_batches, gather_column, \
-    lex_sort_permutation, sort_operands
+    jit_named, lex_sort_permutation, sort_operands
 
 
 def _unalias(e: Expression) -> Tuple[WindowExpression, str]:
@@ -74,7 +74,8 @@ class WindowExec(UnaryExec):
         for w, n in zip(self.exprs, self.names):
             fields.append(Field(n, w.dtype, w.nullable))
         self._schema = Schema(fields)
-        self._kernel = jax.jit(self._window_kernel)
+        self._kernel = jit_named(f"{type(self).__name__}_window",
+                                 self._window_kernel)
 
     @property
     def output_schema(self) -> Schema:

@@ -115,6 +115,7 @@ class FileSourceScanExec(LeafExec):
                                     metrics=self.metrics)
         from ..memory.retry import (maybe_inject, split_host_table,
                                     with_retry)
+        from ..trace import span
         try:
             dict_conf = getattr(self.source, "_dict_conf", None)
 
@@ -124,8 +125,13 @@ class FileSourceScanExec(LeafExec):
                 # before. dict_conf carries the session's cardinality
                 # thresholds to the fallback decision.
                 maybe_inject("scan.h2d")
-                batch, _ = from_arrow(tbl, schema=self._schema,
-                                      dict_conf=dict_conf)
+                with span("scan.h2d", kind="transfer") as sp:
+                    batch, _ = from_arrow(tbl, schema=self._schema,
+                                          dict_conf=dict_conf)
+                    if sp is not None:
+                        sp.attrs["hostBytes"] = tbl.nbytes
+                        # padded to the capacity bucket
+                        sp.attrs["deviceBytes"] = batch.size_bytes()
                 return batch
 
             for host_table in it:

@@ -93,9 +93,11 @@ def reader_pool(num_threads: int = 8) -> cf.ThreadPoolExecutor:
     global _POOL, _POOL_SIZE
     with _POOL_LOCK:
         if _POOL is None or num_threads > _POOL_SIZE:
+            from ..trace import name_thread
             _POOL = cf.ThreadPoolExecutor(
                 max_workers=max(num_threads, _POOL_SIZE),
-                thread_name_prefix="multifile-read")
+                thread_name_prefix="multifile-read",
+                initializer=name_thread, initargs=("rtpu-read",))
             _POOL_SIZE = max(num_threads, _POOL_SIZE)
         return _POOL
 
@@ -374,7 +376,7 @@ class FileSource:
             else ReaderType.COALESCING
 
     def read_all(self) -> pa.Table:
-        tables = [self._decorate(self.read_file(f), f)
+        tables = [self._decorate(self._decode(f), f)
                   for f in self.files]
         return _concat_normalized(tables) if tables else None
 
@@ -411,15 +413,31 @@ class FileSource:
         # dedicated thread, NOT the shared reader pool: the producer holds
         # its worker for the whole scan, and the decode tasks it drives
         # submit into that same pool (pool-of-producers deadlock)
-        return prefetched(it, self.prefetch_depth(),
-                          metrics=metrics, name=f"{self.format_name}-scan")
+        return prefetched(it, self.prefetch_depth(), metrics=metrics,
+                          name=f"{self.format_name}-scan", stage="scan")
+
+    def _decode(self, path: str, thunk=None) -> pa.Table:
+        """One decode unit (a file, or ``thunk``'s row group of it) as
+        span ``scan.decode`` of whichever thread runs it."""
+        from ..trace import span
+        with span("scan.decode", kind="scan",
+                  file=os.path.basename(path)) as sp:
+            t = self.read_file(path) if thunk is None else thunk()
+            if sp is not None:
+                sp.attrs["rows"] = t.num_rows
+                sp.attrs["bytes"] = t.nbytes
+            return t
 
     def _decode_split(self, files: Sequence[str]) -> Iterator[pa.Table]:
-        """The undecorated decode stream (strategy dispatch)."""
+        """The undecorated decode stream (strategy dispatch). Pool
+        workers decode for the query whose thread drives this stream:
+        they attach to its trace under the scan's span."""
+        from ..trace import call_attached, capture
+        tok = capture()
         mode = self.effective_reader()
         if mode is ReaderType.PERFILE:
             for f in files:
-                yield self._decorate(self.read_file(f), f)
+                yield self._decorate(self._decode(f), f)
         elif mode is ReaderType.COALESCING:
             # decode the split's files through the shared pool (bounded by
             # coalescing.numFilesParallel), concat, re-chunk to batch_rows
@@ -430,8 +448,10 @@ class FileSource:
                       1)
             pool = reader_pool(self.num_threads)
             tabs = [self._decorate(t, f)
-                    for f, t in bounded_map(pool, files, self.read_file,
-                                            par)]
+                    for f, t in bounded_map(
+                        pool, files,
+                        lambda f: call_attached(tok, self._decode, f),
+                        par)]
             if not tabs:
                 return
             t = _concat_normalized(tabs)
@@ -443,8 +463,7 @@ class FileSource:
             pool = reader_pool(self.num_threads)
             tasks = self.decode_tasks(files)
             if tasks is None:
-                tasks = [(f, (lambda f=f: self.read_file(f)))
-                         for f in files]
+                tasks = [(f, None) for f in files]
             # windowed submission: maxTasksInFlight bounds queued decode
             # output so a many-file scan cannot hold the whole dataset in
             # host memory at once
@@ -452,7 +471,9 @@ class FileSource:
             win = max(self._mt_max_tasks or
                       int(_REGISTRY[MT_READER_MAX_TASKS.key].default), 1)
             for (f, _fn), raw in bounded_map(
-                    pool, tasks, lambda task: task[1](), win):
+                    pool, tasks,
+                    lambda task: call_attached(tok, self._decode, *task),
+                    win):
                 t = self._decorate(raw, f)
                 for off in range(0, max(t.num_rows, 1), self.batch_rows):
                     yield t.slice(off, self.batch_rows)

@@ -413,12 +413,12 @@ class SpillableInput:
             return None
         import jax.numpy as jnp
         from ..batch import bucket_capacity
-        from ..exec.common import slice_batch
-        import jax
+        from ..exec.common import jit_named, slice_batch
         mid = n // 2
         b = self.acquire()
         try:
-            slicer = jax.jit(slice_batch, static_argnums=3)
+            slicer = jit_named("slice_batch", slice_batch,
+                               static_argnums=3)
             left = slicer(b, jnp.int32(0), jnp.int32(mid),
                           bucket_capacity(mid))
             right = slicer(b, jnp.int32(mid), jnp.int32(n - mid),

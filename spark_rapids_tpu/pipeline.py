@@ -48,18 +48,21 @@ _JOIN_TIMEOUT_S = 30.0
 
 
 def prefetched(source: Iterable, depth: int, pool=None, metrics=None,
-               name: str = "prefetch", force_thread: bool = False):
+               name: str = "prefetch", force_thread: bool = False,
+               stage: str = "prefetch"):
     """Wrap ``source`` so it is produced ``depth`` items ahead of the
     consumer on a background thread. Returns the plain iterator (no
     thread, no queue) when depth<=0 or on single-core hosts —
     ``force_thread`` overrides the single-core policy for I/O-bound
-    producers (and tests)."""
+    producers (and tests). ``stage`` names the pipeline in a query
+    trace: the producer thread is ``rtpu-<stage>-<n>`` to the OS and a
+    consumer that blocks records ``<stage>.prefetchWait``."""
     if depth is None or depth <= 0:
         return iter(source)
     if not force_thread and (os.cpu_count() or 1) <= 1:
         return iter(source)
     return PrefetchIterator(source, depth, pool=pool, metrics=metrics,
-                            name=name)
+                            name=name, stage=stage)
 
 
 class PrefetchIterator:
@@ -73,8 +76,14 @@ class PrefetchIterator:
     shared reader pool to themselves."""
 
     def __init__(self, source: Iterable, depth: int, pool=None,
-                 metrics=None, name: str = "prefetch"):
+                 metrics=None, name: str = "prefetch",
+                 stage: str = "prefetch"):
+        from . import trace as qtrace
         self._source = source
+        self._stage = stage
+        # the producer works for the query that consumes: its spans (a
+        # scan's decode) go under the span that built this iterator
+        self._trace_token = qtrace.capture()
         self._q: queue.Queue = queue.Queue(maxsize=max(int(depth), 1))
         self._cancel = threading.Event()
         self._metrics = metrics if metrics is not None else {}
@@ -102,6 +111,13 @@ class PrefetchIterator:
         return False
 
     def _run(self) -> None:
+        from . import trace as qtrace
+        if self._thread is not None:
+            qtrace.name_thread(f"rtpu-{self._stage}")
+        with qtrace.attached(self._trace_token):
+            self._produce()
+
+    def _produce(self) -> None:
         it = iter(self._source)
         try:
             while not self._cancel.is_set():
@@ -161,7 +177,12 @@ class PrefetchIterator:
         if self._finished:
             raise StopIteration
         t0 = time.perf_counter_ns()
-        tag, val = self._q.get()
+        try:
+            tag, val = self._q.get_nowait()
+        except queue.Empty:
+            from . import trace as qtrace
+            with qtrace.span(f"{self._stage}.prefetchWait", kind="wait"):
+                tag, val = self._q.get()
         self._wait_ns += time.perf_counter_ns() - t0
         if tag == _ITEM:
             return val

@@ -946,6 +946,17 @@ class Overrides:
         from ..config import SHUFFLE_PARTITIONS
         return self.conf.get(SHUFFLE_PARTITIONS.key)
 
+    @staticmethod
+    def _partitioned(child: Exec) -> bool:
+        """Whether ``child`` has more than one partition. An adaptive
+        exchange or a co-partitioned join below it can say only once its
+        map output exists, so this question EXECUTES that subtree, during
+        planning: span ``plan.materialize`` holds it, the operators that
+        ran under it, and microseconds where nothing had to run."""
+        from .. import trace as qtrace
+        with qtrace.span("plan.materialize", kind="plan", exec=child.name):
+            return child.num_partitions > 1
+
     def _exchange(self, partitioning, child: Exec) -> Exec:
         from ..shuffle.manager import get_shuffle_manager
         return get_shuffle_manager(self.conf).create_exchange(
@@ -1015,7 +1026,7 @@ class Overrides:
         raw_aggs = [e.child if isinstance(e, _Alias) else e
                     for e in n.agg_exprs]
         if any(not getattr(a, "supports_partial", True) for a in raw_aggs):
-            if child.num_partitions > 1:
+            if self._partitioned(child):
                 if n.group_exprs:
                     child = self._exchange(
                         HashPartitioning(list(n.group_exprs),
@@ -1028,13 +1039,13 @@ class Overrides:
         partial = HashAggregateExec(n.group_exprs, n.agg_exprs, child,
                                     AggregateMode.PARTIAL,
                                     max_result_rows=agg_rows)
-        if n.group_exprs and child.num_partitions > 1:
+        if n.group_exprs and self._partitioned(child):
             from ..expressions.base import col
             key_cols = [col(f.name) for f in partial.key_fields]
             ex = self._exchange(
                 HashPartitioning(key_cols, self._shuffle_partitions()),
                 partial)
-        elif child.num_partitions > 1:
+        elif self._partitioned(child):
             ex = self._exchange(SinglePartitioning(), partial)
         else:
             ex = partial
@@ -1049,10 +1060,10 @@ class Overrides:
         first = n.window_exprs[0]
         w = first.child if isinstance(first, Alias) else first
         pkeys = list(w.spec.partition_keys)
-        if pkeys and child.num_partitions > 1:
+        if pkeys and self._partitioned(child):
             child = self._exchange(
                 HashPartitioning(pkeys, self._shuffle_partitions()), child)
-        elif child.num_partitions > 1:
+        elif self._partitioned(child):
             child = self._exchange(SinglePartitioning(), child)
         if pkeys:
             # bound the window kernel's per-batch working set by

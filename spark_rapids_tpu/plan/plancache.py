@@ -130,7 +130,9 @@ def metrics() -> ServingMetrics:
 #: immutable, so a digest is valid for the object's lifetime; the weakref
 #: callback retires the id before CPython can reuse it.
 _DIGESTS: Dict[int, Tuple[weakref.ref, str]] = {}
-_DIG_LOCK = threading.Lock()
+#: re-entrant: a collection can start while this thread holds the lock, and
+#: a dead table's ``_gone`` callback then takes it again on the same thread
+_DIG_LOCK = threading.RLock()
 
 
 def register_digest(table: pa.Table, digest: str) -> None:

@@ -86,8 +86,10 @@ class Session:
             if self.conf.get(SERVER_PLAN_CACHE_ENABLED.key):
                 from . import plancache
                 try:
-                    fp = plancache.shape_fingerprint(
-                        df.plan, self.conf, encoded=self._encoded_plan(df))
+                    with qtrace.span("plan.fingerprint", kind="plan"):
+                        fp = plancache.shape_fingerprint(
+                            df.plan, self.conf,
+                            encoded=self._encoded_plan(df))
                 except plancache.Uncacheable as e:
                     # never silent: the reason rides the cache-info surface
                     self.last_cache["plan"] = f"uncacheable: {e.reason}"
@@ -109,7 +111,8 @@ class Session:
                                 sp.attrs["planCache"] = "adaptive"
                             return self._plan_fresh(df, fp, advice=advice,
                                                     cache_put=False)
-                    decisions = plancache.planning_cache().get(fp)
+                    with qtrace.span("plan.cacheLookup", kind="plan"):
+                        decisions = plancache.planning_cache().get(fp)
                     if decisions is not None:
                         prepared = self._plan_from_decisions(df, decisions)
                         if prepared is not None:
@@ -130,8 +133,10 @@ class Session:
         process planning cache for the next same-shape query (cost-fed
         plans pass cache_put=False: adaptive decisions stay as fresh as
         the EWMAs that made them)."""
+        from .. import trace as qtrace
         ov = Overrides(self.conf, adaptive_advice=advice)
-        plan = ov.plan(df.plan)
+        with qtrace.span("plan.overrides", kind="plan"):
+            plan = ov.plan(df.plan)
         self.last_plan = plan
         from .overrides import CpuFallbackExec as _CFE
         kind = "exec"
@@ -188,18 +193,20 @@ class Session:
         re-validate, so a same-bucket input that no longer qualifies
         degrades to the iterator path instead of misexecuting. Returns
         None on a replay mismatch (fingerprint collision guard)."""
+        from .. import trace as qtrace
         from . import plancache
         from .overrides import CpuFallbackExec as _CFE
         from .overrides import PlanMeta, insert_coalesce_transitions
         ov = Overrides(self.conf)
-        meta = PlanMeta(df.plan, self.conf)
-        if not plancache.apply_reasons(meta, decisions.reasons):
-            return None
-        ov.last_meta = meta
-        from ..config import COALESCE_MAX_ROWS
-        plan = insert_coalesce_transitions(
-            ov._convert(meta), self.conf.batch_size_bytes,
-            max_rows=int(self.conf.get(COALESCE_MAX_ROWS.key)))
+        with qtrace.span("plan.overrides", kind="plan", replay=True):
+            meta = PlanMeta(df.plan, self.conf)
+            if not plancache.apply_reasons(meta, decisions.reasons):
+                return None
+            ov.last_meta = meta
+            from ..config import COALESCE_MAX_ROWS
+            plan = insert_coalesce_transitions(
+                ov._convert(meta), self.conf.batch_size_bytes,
+                max_rows=int(self.conf.get(COALESCE_MAX_ROWS.key)))
         self.last_plan = plan
         if isinstance(plan, _CFE):
             return "fallback", plan

@@ -318,6 +318,20 @@ class PlanClient:
         reply, _ = self._request({"msg": "stats"})
         return reply["stats"]
 
+    def profile(self, action: str, log_dir: Optional[str] = None) -> dict:
+        """Start (``action="start"``, with the server-side directory
+        ``log_dir``) or stop a ``jax.profiler`` device trace of the
+        server process; the engine's spans of the queries in between are
+        in it under their own names. Returns ``{"profiling", "dir"}``."""
+        if self._sock is None:
+            self._reconnect()
+        header = {"msg": "profile", "action": action}
+        if log_dir is not None:
+            header["dir"] = str(log_dir)
+        reply, _ = self._request(header)
+        return {"profiling": bool(reply.get("profiling")),
+                "dir": reply.get("dir")}
+
     def last_trace(self) -> Optional[dict]:
         """The last collect's stitched timeline: this client's own leg
         plus every profile the server (or router + the worker that
@@ -325,7 +339,9 @@ class PlanClient:
         ``{"queryId", "profiles": [...]}`` — feed it to
         tools/trace_viewer.py for Chrome/Perfetto trace-event JSON —
         or None before any collect. Remote profiles exist only when
-        the session ran with spark.rapids.tpu.trace.enabled."""
+        the session ran with spark.rapids.tpu.trace.enabled; their
+        spans carry ``selfUs``, each span's own microseconds beside its
+        duration (``trace.self_times``)."""
         if not self.last_query_id:
             return None
         if self._sock is None:

@@ -694,8 +694,9 @@ class _RouterSession:
             merged = router.merged_costs(header.get("fingerprint"))
             return {"msg": "trace_ack", "costs": merged}, b""
         qid = header.get("query_id") or None
-        profiles = router.recorder.profiles(
-            qid, last=int(header.get("last", 0) or 0))
+        profiles = [qtrace.with_self_times(p)     # as the worker's do
+                    for p in router.recorder.profiles(
+                        qid, last=int(header.get("last", 0) or 0))]
         wid = router.worker_for_query(qid) if qid else None
         if wid is not None:
             with router._lock:
@@ -767,12 +768,12 @@ class _RouterSession:
                 # busy, not broken: no suspect marking; its replacement
                 # backend replays the current table set on next use
                 continue
-            except (OSError, protocol.ProtocolError):
+            except (OSError, protocol.ProtocolError) as e:
                 # net-ok: the fault IS handled — the worker is marked
                 # suspect/dead and its backend dropped; fan-out acks
                 # only what succeeded (the replay converges the rest)
                 self.invalidate_backend(w.wid)
-                self.router.note_failure(w)
+                self.router.note_failure(w, e)
                 continue
             if reply.get("msg") == "error":
                 continue    # per-worker isolation; ack what succeeded
@@ -1009,7 +1010,7 @@ class _RouterSession:
                     # not router CPU (the finally keeps it out of the
                     # overhead metric)
                     self.invalidate_backend(w.wid)
-                    router.note_failure(w)
+                    router.note_failure(w, e)
                     router.note_failover()
                     last_unavailable = (
                         {"msg": "error", "unavailable": True,
@@ -1211,11 +1212,21 @@ class Router:
                     if w.state in (LIVE, SUSPECT, DRAINING)
                     and w.alive()]
 
-    def note_failure(self, w: WorkerHandle) -> None:
+    def note_failure(self, w: WorkerHandle,
+                     error: Optional[BaseException] = None) -> None:
         """One broken transaction marks a worker SUSPECT; a process
         observed dead is promoted DEAD immediately (no rehabilitation
         without replacement — the PR-11 rule that a corpse cannot beat
-        itself back into the ring)."""
+        itself back into the ring). A connection the peer closed or
+        reset (``error`` a ConnectionError) is how a killed process
+        looks a moment BEFORE the kernel lets it be reaped, so only then
+        is it given half a second to become reapable, outside the lock;
+        a timeout or any other fault reads the process as it is."""
+        if isinstance(error, ConnectionError) and w.proc is not None:
+            try:
+                w.proc.wait(timeout=0.5)
+            except subprocess.TimeoutExpired:
+                pass
         with self._lock:
             w.failures += 1
             if not w.alive():

@@ -125,6 +125,8 @@ class _ActiveQuery:
 
 class _Handler(socketserver.BaseRequestHandler):
     def handle(self):
+        from ..trace import name_thread
+        name_thread("rtpu-q")       # its own line in a device trace
         sock: socket.socket = self.request
         srv = self.server
         sock.settimeout(srv.idle_timeout)   # type: ignore[attr-defined]
@@ -247,6 +249,8 @@ class _Handler(socketserver.BaseRequestHandler):
         query = _ActiveQuery(None, cancel)
 
         def work():
+            from ..trace import name_thread
+            name_thread("rtpu-q")
             try:
                 box["reply"] = self._dispatch(header, body, tables, conf,
                                               cancelled)
@@ -380,11 +384,23 @@ class _Handler(socketserver.BaseRequestHandler):
                 costs = {fp: store.get(fp)} if fp else store.snapshot()
                 return {"msg": "trace_ack", "costs": costs}, b""
             rec = srv.trace_recorder
+            profiles = rec.profiles(header.get("query_id") or None,
+                                    last=int(header.get("last", 0) or 0))
+            # each span with its own microseconds (selfUs) beside its
+            # duration: where the query's time went, exec by exec
             return {"msg": "trace_ack",
-                    "profiles": rec.profiles(
-                        header.get("query_id") or None,
-                        last=int(header.get("last", 0) or 0)),
+                    "profiles": [qtrace.with_self_times(p)
+                                 for p in profiles],
                     "recorder": rec.stats()}, b""
+        if msg == "profile":
+            # a device trace of THIS process, the only one that can take
+            # it (it holds the chip): jax.profiler started and stopped on
+            # the operator's request. The engine's spans of the queries
+            # in between are in it under their own names (trace.py)
+            return {"msg": "profile_ack",
+                    **srv.plan_server.profile(
+                        str(header.get("action")),
+                        header.get("dir"))}, b""
         if msg == "costs_load":
             # fleet cost-sharing ingress: adopt a merged observed-cost
             # snapshot the router fanned out (Router.sync_costs), so
@@ -625,6 +641,8 @@ class PlanServer:
         srv.plan_server = self          # the stats/shutdown op target
         self._server = srv
         self._thread: Optional[threading.Thread] = None
+        self._profile_lock = threading.Lock()
+        self._profile_dir: Optional[str] = None
         # attach the fleet's shared persistent result tier when the conf
         # names one, BEFORE serving: a replacement worker must rehydrate
         # from its very first read-through. _server=True LOCKS the
@@ -716,6 +734,33 @@ class PlanServer:
 
     def serve_forever(self) -> None:
         self._server.serve_forever()
+
+    def profile(self, action: str, log_dir: Optional[str] = None) -> dict:
+        """``start`` a ``jax.profiler`` trace of this process into
+        ``log_dir``, or ``stop`` the one running. The Python tracer stays
+        off (it would record every call of the engine's host code and slow
+        what is measured); host events are taken at level 2, which holds
+        the engine's spans and XLA's own. Returns ``{"profiling", "dir"}``;
+        starting twice or stopping nothing changes nothing."""
+        import jax
+        with self._profile_lock:
+            if action == "start" and self._profile_dir is None:
+                if not log_dir:
+                    raise ValueError("profile start needs a directory")
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                opts.host_tracer_level = 2
+                jax.profiler.start_trace(str(log_dir),
+                                         profiler_options=opts)
+                self._profile_dir = str(log_dir)
+            elif action == "stop" and self._profile_dir is not None:
+                log_dir, self._profile_dir = self._profile_dir, None
+                jax.profiler.stop_trace()
+                return {"profiling": False, "dir": log_dir}
+            elif action not in ("start", "stop"):
+                raise ValueError(f"unknown profile action {action!r}")
+            return {"profiling": self._profile_dir is not None,
+                    "dir": self._profile_dir}
 
     def stop(self, grace_s: float = 10.0) -> None:
         """Stop accepting, CANCEL in-flight queries (cooperative cancel

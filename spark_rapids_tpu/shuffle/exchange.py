@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..exec.base import Exec, UnaryExec
-from ..exec.common import compact, concat_batches, slice_batch
+from ..exec.common import compact, concat_batches, jit_named, slice_batch
 from ..expressions.base import EvalContext
 from ..memory.catalog import BufferCatalog, SpillableBatch
 from .partitioning import Partitioning, RangePartitioning, SinglePartitioning
@@ -87,11 +87,15 @@ class ShuffleExchangeExec(UnaryExec):
         def slice_kernel(batch: ColumnarBatch, pids, p: int) -> ColumnarBatch:
             return compact(batch, pids == p)
 
-        self._slice_jit = jax.jit(slice_kernel, static_argnums=2)
-        self._shrink_jit = jax.jit(
+        me = type(self).__name__
+        self._slice_jit = jit_named(f"{me}_slice", slice_kernel,
+                                    static_argnums=2)
+        self._shrink_jit = jit_named(
+            f"{me}_shrink",
             lambda b, cap: slice_batch(b, 0, b.num_rows, cap),
             static_argnums=1)
-        self._pids_jit = jax.jit(
+        self._pids_jit = jit_named(
+            f"{me}_pids",
             lambda b: self.partitioning.partition_ids(b, self.ctx))
         from ..exec.base import DEBUG, MODERATE, Metric
         # wire-path visibility: serializeTime = framing/compression,
@@ -191,7 +195,8 @@ class ShuffleExchangeExec(UnaryExec):
             pos = jnp.clip(pos, 0, kb.capacity - 1).astype(jnp.int32)
             return [gather_column(c, pos) for c in skeys]
 
-        bound_cols = jax.jit(bounds_kernel)(allk)
+        bound_cols = jit_named(f"{type(self).__name__}_bounds",
+                               bounds_kernel)(allk)
         part.set_bounds(bound_cols, n - 1)
 
     def _register(self, staged, p: int, piece: ColumnarBatch) -> None:
@@ -231,9 +236,9 @@ class ShuffleExchangeExec(UnaryExec):
                       for b in self.child.execute_partition(cp))
         cat = self._cat()
         spill0 = cat.spilled_to_host + cat.spilled_to_disk
+        from .. import trace as qtrace
         from ..memory.retry import (SpillableInput, split_input_halves,
                                     with_retry)
-        from ..utils import tracing
         in_schema = self.child.output_schema
 
         def write_body(item: SpillableInput):
@@ -261,7 +266,7 @@ class ShuffleExchangeExec(UnaryExec):
 
         try:
             for batch in stream:
-                with tracing.op_range(f"{self.name}.write"):
+                with qtrace.span(f"{self.name}.write", kind="shuffle"):
                     # the input batch rides the catalog across retry
                     # boundaries (SpillableColumnarBatch discipline); a
                     # repeated OOM halves it — half-inputs slice to the
@@ -368,8 +373,8 @@ class ShuffleExchangeExec(UnaryExec):
         current one through the bounded pipeline (prefetch.depth; 0 =
         synchronous)."""
         import time as _time
+        from .. import trace as qtrace
         from ..pipeline import close_iterator, prefetched
-        from ..utils import tracing
         from .serializer import frame_packed, pack_batch
         specs = self._reader_specs()
         parts = self._materialize()
@@ -406,7 +411,7 @@ class ShuffleExchangeExec(UnaryExec):
             depth = int(_REGISTRY[PREFETCH_DEPTH.key].default) \
                 if _REGISTRY[PREFETCH_ENABLED.key].default else 0
         it = prefetched(staged(), depth, metrics=self.metrics,
-                        name="exchange-wire")
+                        name="exchange-wire", stage="wire")
         next_p, frames = 0, []
         try:
             for p, pt in it:
@@ -414,7 +419,8 @@ class ShuffleExchangeExec(UnaryExec):
                     yield next_p, frames
                     next_p, frames = next_p + 1, []
                 t0 = _time.perf_counter_ns()
-                with tracing.op_range(f"{self.name}.serialize"):
+                with qtrace.span(f"{self.name}.serialize",
+                                 kind="serializer"):
                     frames.append(frame_packed(pt, codec))
                 self.metrics["serializeTime"].add(
                     _time.perf_counter_ns() - t0)
@@ -622,9 +628,11 @@ class CachedShuffleExchangeExec(UnaryExec):
         self._conf = conf
         self._written = False
         self._write_lock = threading.Lock()
-        self._slice_jit = jax.jit(
+        self._slice_jit = jit_named(
+            f"{type(self).__name__}_slice",
             lambda b, pids, p: compact(b, pids == p), static_argnums=2)
-        self._pids_jit = jax.jit(
+        self._pids_jit = jit_named(
+            f"{type(self).__name__}_pids",
             lambda b: self.partitioning.partition_ids(b, self.ctx))
 
     def _get_cache(self):
@@ -656,8 +664,10 @@ class CachedShuffleExchangeExec(UnaryExec):
         cache = self._get_cache()
         schema = self.child.output_schema
         m = 0
-        shrink = jax.jit(lambda b, cap: slice_batch(b, 0, b.num_rows, cap),
-                         static_argnums=1)
+        shrink = jit_named(
+            f"{type(self).__name__}_shrink",
+            lambda b, cap: slice_batch(b, 0, b.num_rows, cap),
+            static_argnums=1)
         for cp in range(self.child.num_partitions):
             for batch in self.child.execute_partition(cp):
                 pids = self._pids_jit(batch)

@@ -21,7 +21,7 @@ import jax
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..exec.base import Exec, UnaryExec
-from ..exec.common import compact, concat_batches
+from ..exec.common import compact, concat_batches, jit_named
 from ..expressions.base import EvalContext
 from .partitioning import Partitioning, RangePartitioning
 from .serializer import deserialize_batch, serialize_batch
@@ -116,8 +116,10 @@ class MultithreadedShuffleExchangeExec(UnaryExec):
         def slice_kernel(batch, pids, p: int):
             return compact(batch, pids == p)
 
-        self._slice_jit = jax.jit(slice_kernel, static_argnums=2)
-        self._pids_jit = jax.jit(
+        self._slice_jit = jit_named(f"{type(self).__name__}_slice",
+                                    slice_kernel, static_argnums=2)
+        self._pids_jit = jit_named(
+            f"{type(self).__name__}_pids",
             lambda b: self.partitioning.partition_ids(b, self.ctx))
 
     @property
@@ -139,8 +141,11 @@ class MultithreadedShuffleExchangeExec(UnaryExec):
                 return
             n = self.num_partitions
             schema = self.output_schema
+            from ..trace import name_thread
             pool = cf.ThreadPoolExecutor(self.num_threads,
-                                         thread_name_prefix="shuffle-write")
+                                         thread_name_prefix="shuffle-write",
+                                         initializer=name_thread,
+                                         initargs=("rtpu-shufw",))
             futures = []
             # writer-pool tasks inherit this thread's trace context so
             # their serializer.pack / transport.replicate spans join the

@@ -258,7 +258,7 @@ def iter_framed(batches, codec: Optional[str] = None,
         depth = int(_REGISTRY[PREFETCH_DEPTH.key].default) \
             if _REGISTRY[PREFETCH_ENABLED.key].default else 0
     it = prefetched(staged(), depth, metrics=metrics,
-                    name="exchange-serialize")
+                    name="exchange-serialize", stage="wire")
     try:
         for item, packed in it:
             yield item, frame_packed(packed, codec)

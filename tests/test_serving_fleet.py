@@ -251,6 +251,52 @@ def test_kill_worker_mid_query_failover(tabs):
     _assert_no_worker_leak(router)
 
 
+class _DyingProc:
+    """A worker process whose sockets are already closed and which the
+    kernel lets be reaped only ``after`` seconds from now (never, for
+    None): what a killed process is to the peer that saw the reset."""
+
+    def __init__(self, after):
+        self.dead_at = None if after is None else time.monotonic() + after
+
+    def poll(self):
+        gone = self.dead_at is not None and time.monotonic() >= self.dead_at
+        return -9 if gone else None
+
+    def wait(self, timeout):
+        import subprocess
+        left = float("inf") if self.dead_at is None \
+            else self.dead_at - time.monotonic()
+        if left > timeout:
+            time.sleep(timeout)
+            raise subprocess.TimeoutExpired("worker", timeout)
+        time.sleep(max(left, 0.0))
+        return -9
+
+
+@pytest.mark.parametrize("error,reapable_after,state,at_least,under", [
+    # the reset outruns the reaping: the corpse must not stay in the ring
+    (ConnectionResetError(), 0.15, "dead", 0.1, 0.45),
+    # a live worker that dropped one connection: suspect, after the wait
+    (ConnectionResetError(), None, "suspect", 0.45, 2.0),
+    # alive but slow: read as it is, no wait on the failover path
+    (TimeoutError(), None, "suspect", 0.0, 0.1),
+    (None, 0.15, "suspect", 0.0, 0.1),
+])
+def test_note_failure_waits_for_the_reaping_only_after_a_reset(
+        tmp_path, error, reapable_after, state, at_least, under):
+    from spark_rapids_tpu.server.router import WorkerHandle
+    router = Router(workers=0, worker_conf={      # never started
+        "spark.rapids.tpu.server.fleet.resultStore.path": str(tmp_path)})
+    w = WorkerHandle("w0", {}, "127.0.0.1")
+    w.proc = _DyingProc(reapable_after)
+    t0 = time.monotonic()
+    router.note_failure(w, error)
+    took = time.monotonic() - t0
+    assert w.state == state and w.failures == 1
+    assert at_least <= took < under, took
+
+
 # ---------------------------------------------------------------------------
 # 3. rolling restart under load: zero dropped queries + rehydration
 # ---------------------------------------------------------------------------

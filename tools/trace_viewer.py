@@ -24,6 +24,7 @@ Mapping:
   outcomes, failover verdicts).
 
 Usage:
+    python tools/trace_viewer.py --table trace.jsonl   # where the time went
     python tools/trace_viewer.py trace.jsonl -o timeline.json
     python tools/trace_viewer.py --query-id 1234abcd trace.jsonl
     python tools/trace_viewer.py last_trace.json   # stitched dump
@@ -101,6 +102,8 @@ def to_trace_events(profiles: Iterable[dict],
         depths = _depths(spans)
         for s in spans:
             args = dict(s.get("attrs") or {})
+            if "selfUs" in s:
+                args["selfUs"] = s["selfUs"]
             args["queryId"] = prof.get("queryId")
             args["kind"] = s.get("kind", "span")
             events.append({
@@ -116,6 +119,53 @@ def to_trace_events(profiles: Iterable[dict],
     return events
 
 
+def self_time_table(profile: dict) -> List[dict]:
+    """One row per span name of one profile, largest own time first:
+    spans, their time inside (an operator's pulls, any other span's
+    duration), their OWN time (``trace.self_times``: less the children
+    on the same thread), pulls, and what JAX did under them (lowerings,
+    and the milliseconds of ``jit.*`` spans directly below)."""
+    import os
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), os.pardir))
+    from spark_rapids_tpu.trace import inside_us, self_times
+    spans = profile.get("spans", [])
+    own = self_times(spans)
+    name_of = {s["id"]: s["name"] for s in spans}
+    rows: Dict[str, dict] = {}
+    for s in spans:
+        attrs = s.get("attrs") or {}
+        r = rows.setdefault(s["name"], {
+            "name": s["name"], "spans": 0, "insideMs": 0.0, "selfMs": 0.0,
+            "pulls": 0, "lowerings": 0, "relowerMs": 0.0})
+        r["spans"] += 1
+        r["insideMs"] += inside_us(s) / 1000.0
+        r["selfMs"] += own[s["id"]] / 1000.0
+        r["pulls"] += int(attrs.get("pulls", 0))
+        r["lowerings"] += int(attrs.get("lowerings", 0))
+        parent = name_of.get(s.get("parent"))
+        if s["name"].startswith("jit.") and parent in rows \
+                and not parent.startswith("jit."):
+            rows[parent]["relowerMs"] += (s.get("durUs") or 0) / 1000.0
+    return sorted(rows.values(), key=lambda r: -r["selfMs"])
+
+
+def print_tables(profiles: Iterable[dict],
+                 query_id: Optional[str] = None) -> None:
+    for prof in profiles:
+        if query_id and prof.get("queryId") != query_id:
+            continue
+        print(f"# {prof.get('component', 'engine')} "
+              f"{prof.get('queryId', '?')}: "
+              f"{(prof.get('durUs') or 0) / 1000.0:.1f} ms")
+        print(f"{'span':<34}{'n':>4}{'inside ms':>12}{'self ms':>12}"
+              f"{'pulls':>7}{'lowerings':>10}{'relower ms':>12}")
+        for r in self_time_table(prof):
+            print(f"{r['name']:<34}{r['spans']:>4}{r['insideMs']:>12.1f}"
+                  f"{r['selfMs']:>12.1f}{r['pulls']:>7}"
+                  f"{r['lowerings']:>10}{r['relowerMs']:>12.1f}")
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         description="query-trace profiles -> Chrome trace-event JSON")
@@ -125,8 +175,14 @@ def main(argv=None) -> int:
                    help="output path (default: stdout)")
     p.add_argument("--query-id", default=None,
                    help="render only this query's profiles")
+    p.add_argument("--table", action="store_true",
+                   help="print each profile's self-time table (own ms, "
+                        "pulls, lowerings by span name) instead")
     args = p.parse_args(argv)
     profiles = load_profiles(args.input)
+    if args.table:
+        print_tables(profiles, query_id=args.query_id)
+        return 0
     events = to_trace_events(profiles, query_id=args.query_id)
     blob = json.dumps(events, indent=1)
     if args.out:
