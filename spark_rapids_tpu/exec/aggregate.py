@@ -33,8 +33,8 @@ from ..expressions.base import Alias, EvalContext, Expression
 from .base import Exec, UnaryExec
 from .basic import bind_all, output_name
 from .common import _batched_takes, adjacent_equal, adjacent_equal_ops, \
-    compaction_indices, concat_batches, gather_column, jit_named, \
-    lex_sort_permutation, sort_operands
+    KernelPrograms, compaction_indices, concat_batches, gather_column, \
+    jit_named, lex_sort_permutation, sort_operands
 
 # dtypes whose device payload is a flat 1-D array: the fast kernel gathers
 # such columns through the key sort's permutation in batched row-gathers
@@ -189,14 +189,21 @@ class HashAggregateExec(UnaryExec):
             and have_keys
             and all(_is_flat(f.dtype) for f in self.buffer_fields))
 
-        me = type(self).__name__
-        self._update_jit = jit_named(f"{me}_update", self._update_kernel)
-        self._merge_jit = jit_named(
-            f"{me}_merge", lambda b: self._merge_kernel(b, final=False))
-        self._final_jit = jit_named(
-            f"{me}_final", lambda b: self._merge_kernel(b, final=True))
-        self._eval_buffers_jit = jit_named(
-            f"{me}_evalBuffers", self._eval_buffers_kernel)
+        # everything the kernels below read of this exec: the programs'
+        # key, and all their stand-in has (common.KernelPrograms)
+        programs = KernelPrograms(self, (
+            "mode", "group_exprs", "aggs", "key_fields", "buffer_fields",
+            "sort_sensitive", "small_groups_bucket", "layout_tiers",
+            "_upd_value_exprs", "_upd_per_agg", "_fast_update",
+            "_fast_merge"))
+        cls = type(self)
+        self._update_jit = programs.jit("update", cls._update_kernel)
+        self._merge_jit = programs.jit(
+            "merge", lambda self, b: self._merge_kernel(b, final=False))
+        self._final_jit = programs.jit(
+            "final", lambda self, b: self._merge_kernel(b, final=True))
+        self._eval_buffers_jit = programs.jit(
+            "evalBuffers", cls._eval_buffers_kernel)
 
     @staticmethod
     def _expr_key(e: Expression):

@@ -54,6 +54,12 @@ class Exec:
     """A physical operator. Subclasses define `output_schema` and
     `do_execute() -> Iterator[ColumnarBatch]`."""
 
+    #: keyed programs this exec stated when it was built that the program
+    #: table already had / had to make (``common.KernelPrograms.jit``);
+    #: its first operator span carries them
+    program_hits = 0
+    program_misses = 0
+
     def __init__(self, children: Sequence["Exec"] = (),
                  ctx: EvalContext = EvalContext()):
         self.children: Tuple[Exec, ...] = tuple(children)
@@ -119,6 +125,10 @@ class Exec:
         from .. import trace as qtrace
         it = self.do_execute_partition(p)
         op = qtrace.open_operator(self.name, p)
+        if op is not None and (self.program_hits or self.program_misses):
+            # once an exec, so that a query's spans add up to its programs
+            op.note_programs(self.program_hits, self.program_misses)
+            self.program_hits = self.program_misses = 0
         op_time = self.metrics["opTime"]
         try:
             while True:

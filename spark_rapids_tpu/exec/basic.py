@@ -24,7 +24,7 @@ from ..batch import (ColumnarBatch, DeviceColumn, Field, Schema,
 from ..expressions.base import Alias, EvalContext, Expression
 from ..types import TypeKind
 from .base import Exec, LeafExec, UnaryExec
-from .common import compact, jit_named, slice_batch
+from .common import KernelPrograms, compact, slice_batch
 
 
 def output_name(e: Expression, i: int) -> str:
@@ -171,7 +171,7 @@ class ProjectExec(UnaryExec):
         self.exprs = bind_all(exprs, child.output_schema)
         self._schema = schema_of(self.exprs)
 
-        def kernel(batch: ColumnarBatch, bseed):
+        def kernel(self, batch: ColumnarBatch, bseed):
             # errors dict is always live: ANSI rows report conditionally,
             # CAPACITY_* budget overflows report unconditionally. bseed is
             # a traced per-(partition, batch) scalar for stateless PRNG
@@ -185,7 +185,7 @@ class ProjectExec(UnaryExec):
             cols = tuple(raw_eval(e, batch, ctx) for e in self.exprs)
             return ColumnarBatch(cols, batch.num_rows), _sum_errors(ctx)
 
-        self._kernel = jit_named(f"{type(self).__name__}_project", kernel)
+        self._kernel = KernelPrograms(self, ("exprs",)).jit("project", kernel)
 
     @property
     def output_schema(self) -> Schema:
@@ -236,13 +236,14 @@ class FilterExec(UnaryExec):
             raise TypeError(f"filter condition must be boolean, got "
                             f"{self.condition.dtype}")
 
-        def kernel(batch: ColumnarBatch):
+        def kernel(self, batch: ColumnarBatch):
             ctx = EvalContext(self.ctx.ansi, {})
             c = self.condition.eval(batch, ctx)
             keep = c.data & c.validity
             return compact(batch, keep), _sum_errors(ctx)
 
-        self._kernel = jit_named(f"{type(self).__name__}_filter", kernel)
+        self._kernel = KernelPrograms(self, ("condition",)).jit(
+            "filter", kernel)
 
     @property
     def output_schema(self) -> Schema:
@@ -261,9 +262,10 @@ class LocalLimitExec(UnaryExec):
     def __init__(self, limit: int, child: Exec):
         super().__init__(child)
         self.limit = limit
-        self._kernel = jit_named(
-            f"{type(self).__name__}_limit",
-            lambda b, remaining: slice_batch(b, jnp.int32(0), remaining))
+        self._kernel = KernelPrograms(self, ()).jit(
+            "limit",
+            lambda self, b, remaining: slice_batch(b, jnp.int32(0),
+                                                   remaining))
 
     @property
     def output_schema(self) -> Schema:
@@ -360,11 +362,12 @@ class SampleExec(UnaryExec):
         super().__init__(child)
         self.fraction, self.seed = fraction, seed
 
-        def kernel(batch: ColumnarBatch, key) -> ColumnarBatch:
+        def kernel(self, batch: ColumnarBatch, key) -> ColumnarBatch:
             u = jax.random.uniform(key, (batch.capacity,))
             return compact(batch, u < self.fraction)
 
-        self._kernel = jit_named(f"{type(self).__name__}_sample", kernel)
+        self._kernel = KernelPrograms(self, ("fraction",)).jit(
+            "sample", kernel)
 
     @property
     def output_schema(self) -> Schema:
@@ -393,12 +396,12 @@ class ExpandExec(UnaryExec):
             fields.append(Field(f.name, f.dtype, nullable))
         self._schema = Schema(fields)
 
-        def kernel(batch: ColumnarBatch, pi: int) -> ColumnarBatch:
+        def kernel(self, batch: ColumnarBatch, pi: int) -> ColumnarBatch:
             cols = tuple(e.eval(batch, self.ctx) for e in self.projections[pi])
             return ColumnarBatch(cols, batch.num_rows)
 
-        self._kernel = jit_named(f"{type(self).__name__}_expand", kernel,
-                                 static_argnums=1)
+        self._kernel = KernelPrograms(self, ("projections",)).jit(
+            "expand", kernel, static_argnums=1)
 
     @property
     def output_schema(self) -> Schema:

@@ -16,34 +16,155 @@ Key primitives:
 
 from __future__ import annotations
 
+import functools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from .. import types as T
-from ..batch import ColumnarBatch, DeviceColumn, Schema
+from ..batch import ColumnarBatch, DeviceColumn, Field, Schema
 from ..types import SqlType, TypeKind
 
 
-def jit_named(name: str, fun, **jit_kwargs):
+def jit_named(name: str, fun, key: Optional[str] = None, owner=None,
+              **jit_kwargs):
     """``jax.jit(fun)`` as the program ``jit_<name>``: the one door through
     which ``exec/``, ``io/``, ``shuffle/`` and ``memory/`` jit, so that a
     device trace, a lowering's ``fun`` and the compile cache name a program
-    ``<Exec>_<role>`` and not ``_lambda_`` or ``kernel``. A function already
-    called ``name`` (a module-level one such as ``slice_batch``) is jitted
-    as it is and keeps JAX's trace cache across wrappers; any other goes
-    through a wrapper of that name, which like the lambda, closure or
-    bound method it stands for is a new function, traced anew, in every
-    exec instance. (No ``functools.wraps``: JAX names a program after what
-    ``inspect.unwrap`` finds.)"""
-    if getattr(fun, "__name__", None) == name:
-        return jax.jit(fun, **jit_kwargs)
+    ``<Exec>_<role>`` and not ``_lambda_`` or ``kernel``. (No
+    ``functools.wraps``: JAX names a program after what ``inspect.unwrap``
+    finds.)
 
-    def named(*args, **kwargs):
-        return fun(*args, **kwargs)
-    named.__name__ = named.__qualname__ = name
-    return jax.jit(named, **jit_kwargs)
+    JAX keys its trace, its lowering and its loaded executable on the
+    function object, so WHICH object this returns decides what a rebuilt
+    exec pays:
+
+    - with a ``key`` it is the process's one entry for ``(name, key, jit
+      arguments)`` in ``compile_cache.program_table()``, made from ``fun``
+      by the first caller to state that key. Every later caller gets the
+      same callable and its calls take JAX's C++ fast path: no trace, no
+      lowering, no executable load, and ``fun`` itself is dropped, its
+      Python body never run. ``key`` therefore says everything ``fun``
+      reads when it is traced other than its arguments (``program_key``;
+      shapes, dtypes and pytree structure are JAX's own key), and ``fun``
+      closes over those compile-time values only (``KernelPrograms``): the
+      table keeps it for the life of the process. ``owner``, where given,
+      counts what the table said in its ``program_hits`` /
+      ``program_misses``, for its operator span.
+    - a function already called ``name`` (a module-level one such as
+      ``slice_batch``) is jitted as it is: JAX finds its trace and its
+      executables by the function, across wrappers.
+    - anything else with no key goes through a wrapper of that name, which
+      like the lambda, closure or bound method it stands for is a new
+      function, traced anew, in every exec instance (counted ``unkeyed``).
+    """
+    def build():
+        if getattr(fun, "__name__", None) == name:
+            return jax.jit(fun, **jit_kwargs)
+
+        def named(*args, **kwargs):
+            return fun(*args, **kwargs)
+        named.__name__ = named.__qualname__ = name
+        return jax.jit(named, **jit_kwargs)
+
+    from ..compile_cache import program_table
+    if key is not None:
+        fn, hit = program_table().get_or_build(
+            (name, key, repr(sorted(jit_kwargs.items()))), build)
+        if owner is not None:
+            tally = "program_hits" if hit else "program_misses"
+            setattr(owner, tally, getattr(owner, tally, 0) + 1)
+        return fn
+    if getattr(fun, "__name__", None) != name:
+        program_table().note_unkeyed()
+    return build()
+
+
+#: the ``key`` of a kernel that reads nothing but its arguments
+PURE = "pure"
+
+
+class _Unkeyable(Exception):
+    """A part of a program key that cannot be written down."""
+
+
+def _key_doc(v):
+    """``v`` as a JSON-able document that differs wherever a kernel reading
+    ``v`` could trace differently. The plan dialect's ``encode_value``
+    (expressions field by field with literal VALUES, refusing one whose
+    fields do not state it; ``SqlType``, ``Schema``, ``SortOrder``, the
+    enums execs hold) plus what only execs hold: a ``Field``, the
+    evaluation context's flags, and objects that state their own
+    ``program_key()``."""
+    from ..expressions.base import EvalContext
+    from ..server.plandoc import encode_value
+    if isinstance(v, EvalContext):
+        if v.errors is not None or v.batch_seed is not None:
+            raise _Unkeyable("an evaluation context with trace-time state")
+        return {"$ctx": [v.ansi]}
+    if isinstance(v, Field):
+        return {"$field": [v.name, encode_value(v.dtype), v.nullable]}
+    if isinstance(v, (list, tuple)):
+        return {"$l": [_key_doc(x) for x in v]}
+    own = getattr(v, "program_key", None)
+    if own is not None:
+        parts = own()
+        if parts is None:
+            raise _Unkeyable(type(v).__name__)
+        return {"$k": [type(v).__name__, _key_doc(parts)]}
+    return encode_value(v)
+
+
+def program_key(*parts) -> Optional[str]:
+    """The digest ``jit_named`` takes as ``key``: of everything a kernel
+    reads at trace time besides its arguments, stated by its exec. None
+    where a part has no encoding (an expression over a Python callable, a
+    partitioning that holds sampled bounds): that exec keeps a program of
+    its own. Two execs with equal keys MUST trace to equal jaxprs; a key
+    may say too much, never too little."""
+    from ..plan.plancache import _hash
+    from ..server.plandoc import PlanDecodeError
+    try:
+        return _hash([_key_doc(p) for p in parts])
+    except (_Unkeyable, PlanDecodeError):
+        return None
+
+
+class KernelPrograms:
+    """The programs of one exec (or sorter, writer, ...) ``owner`` whose
+    kernels read the fields ``reads`` of it, and ``ctx``.
+
+    ``jit(role, kernel)`` jits ``kernel(stand_in, *args)`` as
+    ``<Owner>_<role>``, where ``stand_in`` is an object of the owner's
+    class that has those fields and NOTHING else of the owner: no
+    children, no metrics, no runtime cache, so the table entry made from it
+    pins no query, and a kernel that reads a field its exec did not state
+    fails when it is traced instead of sharing a program under too small a
+    key. The key is the digest of the same fields plus ``also`` (what a
+    kernel closes over besides the owner) plus, for an exec, its
+    children's schemas and its own."""
+
+    def __init__(self, owner, reads: Sequence[str], also: Sequence = ()):
+        self._owner = owner
+        cls = type(owner)
+        self.stand_in = cls.__new__(cls)
+        fields = [("ctx", owner.ctx)] if hasattr(owner, "ctx") else []
+        fields += [(f, getattr(owner, f)) for f in reads]
+        for f, v in fields:
+            setattr(self.stand_in, f, v)
+        schemas = []
+        if hasattr(owner, "children"):
+            schemas = [c.output_schema for c in owner.children] \
+                + [owner.output_schema]
+        self.key = program_key(f"{cls.__module__}.{cls.__qualname__}",
+                               [[f, v] for f, v in fields], schemas,
+                               list(also))
+
+    def jit(self, role: str, kernel, **jit_kwargs):
+        return jit_named(f"{type(self._owner).__name__}_{role}",
+                         functools.partial(kernel, self.stand_in),
+                         key=self.key, owner=self._owner, **jit_kwargs)
 
 
 # ---------------------------------------------------------------------------

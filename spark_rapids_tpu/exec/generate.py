@@ -29,7 +29,7 @@ from ..batch import ColumnarBatch, DeviceColumn, Field, Schema
 from ..expressions.base import EvalContext, Expression
 from ..types import TypeKind
 from .base import UnaryExec
-from .common import compact, jit_named
+from .common import KernelPrograms, compact
 
 
 class GenerateExec(UnaryExec):
@@ -63,12 +63,14 @@ class GenerateExec(UnaryExec):
             fields.append(Field(elem_name, gt.children[0], outer))
         self._schema = Schema(fields)
 
-        def kernel(batch):
+        def kernel(self, batch):
             from .basic import _sum_errors
             kctx = EvalContext(self.ctx.ansi, {})
             return self._explode_kernel(batch, kctx), _sum_errors(kctx)
 
-        self._kernel = jit_named(f"{type(self).__name__}_explode", kernel)
+        self._kernel = KernelPrograms(
+            self, ("generator", "outer", "pos", "is_map")).jit(
+                "explode", kernel)
 
     @property
     def output_schema(self) -> Schema:

@@ -40,8 +40,8 @@ from ..expressions.hashing import murmur3_batch
 from ..types import TypeKind
 from .base import BinaryExec, Exec
 from .basic import bind_all
-from .common import compact, concat_batches, gather, gather_column, \
-    jit_named
+from .common import PURE, KernelPrograms, compact, concat_batches, gather, \
+    gather_column, jit_named
 
 
 class JoinType(enum.Enum):
@@ -96,7 +96,7 @@ def _keys_equal(a: List[DeviceColumn], b: List[DeviceColumn]) -> jnp.ndarray:
 from functools import partial  # noqa: E402
 
 
-@partial(jit_named, "HashJoinExec_sliceTile", static_argnums=3)
+@partial(jit_named, "HashJoinExec_sliceTile", key=PURE, static_argnums=3)
 def _slice_tile(build, off, count, cap):
     from .common import slice_batch
     return slice_batch(build, off, count, cap)
@@ -203,13 +203,16 @@ class HashJoinExec(BinaryExec):
             len(self.right_keys) == 1
             and self.right_keys[0].dtype.kind in _EXACT_KINDS)
 
-        me = type(self).__name__
-        self._build_jit = jit_named(f"{me}_build", self._build_kernel)
-        self._count_jit = jit_named(f"{me}_count", self._count_kernel)
-        self._expand_jit = jit_named(f"{me}_expand", self._expand_kernel,
-                                     static_argnums=(4,))
-        self._semi_jit = jit_named(f"{me}_semi", self._semi_kernel,
-                                   static_argnums=(4,))
+        programs = KernelPrograms(self, (
+            "join_type", "left_keys", "right_keys", "condition",
+            "_exact_probe"))
+        cls = type(self)
+        self._build_jit = programs.jit("build", cls._build_kernel)
+        self._count_jit = programs.jit("count", cls._count_kernel)
+        self._expand_jit = programs.jit("expand", cls._expand_kernel,
+                                        static_argnums=(4,))
+        self._semi_jit = programs.jit("semi", cls._semi_kernel,
+                                      static_argnums=(4,))
 
     def _probe_words(self, keys, valid, build_side: bool) -> jnp.ndarray:
         """The sorted/probed search key: exact orderable word (single
@@ -837,14 +840,16 @@ class HashJoinExec(BinaryExec):
         build_rows = sum(int(b.num_rows) for b in build_batches)
         n_buckets = -(-build_rows // self.max_build_rows)
 
-        split_build = jit_named(
-            f"{type(self).__name__}_splitBuild",
-            lambda b, s: compact(
+        programs = KernelPrograms(self, ("left_keys", "right_keys"),
+                                  also=[n_buckets])
+        split_build = programs.jit(
+            "splitBuild",
+            lambda self, b, s: compact(
                 b, self._bucket_pids(b, self.right_keys, n_buckets) == s),
             static_argnums=1)
-        split_stream = jit_named(
-            f"{type(self).__name__}_splitStream",
-            lambda b, s: compact(
+        split_stream = programs.jit(
+            "splitStream",
+            lambda self, b, s: compact(
                 b, self._bucket_pids(b, self.left_keys, n_buckets) == s),
             static_argnums=1)
 
@@ -917,10 +922,9 @@ class BroadcastNestedLoopJoinExec(BinaryExec):
         # join type projects out (reference: AST closures in
         # GpuBroadcastNestedLoopJoinExec conditional variants)
         self.condition = condition.bind(pair_schema) if condition else None
-        self._cross_jit = jit_named(f"{type(self).__name__}_cross",
-                                    self._cross_kernel)
-        self._count_jit = jit_named(f"{type(self).__name__}_count",
-                                    self._count_kernel)
+        programs = KernelPrograms(self, ("join_type", "condition"))
+        self._cross_jit = programs.jit("cross", type(self)._cross_kernel)
+        self._count_jit = programs.jit("count", type(self)._count_kernel)
 
     @property
     def output_schema(self) -> Schema:

@@ -31,8 +31,8 @@ from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..expressions.base import EvalContext, Expression
 from .base import UnaryExec
 from .basic import bind_all
-from .common import (adjacent_equal, concat_batches, gather_column,
-                     jit_named, lex_sort_permutation, slice_batch,
+from .common import (KernelPrograms, adjacent_equal, concat_batches,
+                     gather_column, lex_sort_permutation, slice_batch,
                      sort_operands)
 
 
@@ -49,7 +49,7 @@ class KeyBatchingExec(UnaryExec):
         self.keys = bind_all(keys, child.output_schema)
         self.target_rows = target_rows
 
-        def prep(batch: ColumnarBatch):
+        def prep(self, batch: ColumnarBatch):
             key_cols = [e.eval(batch, self.ctx) for e in self.keys]
             live = batch.row_mask()
             k = len(key_cols)
@@ -64,10 +64,12 @@ class KeyBatchingExec(UnaryExec):
             new_group = sorted_live & ~adjacent_equal(skeys)
             return ColumnarBatch(cols, batch.num_rows), new_group
 
-        self._prep_jit = jit_named(f"{type(self).__name__}_prep", prep)
-        self._slice_jit = jit_named(
-            f"{type(self).__name__}_slice",
-            lambda b, start, count, cap: slice_batch(b, start, count, cap),
+        programs = KernelPrograms(self, ("keys",))
+        self._prep_jit = programs.jit("prep", prep)
+        self._slice_jit = programs.jit(
+            "slice",
+            lambda self, b, start, count, cap: slice_batch(b, start, count,
+                                                           cap),
             static_argnums=3)
 
     @property

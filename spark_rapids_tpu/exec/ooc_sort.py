@@ -21,8 +21,8 @@ import jax.numpy as jnp
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..memory import (BufferCatalog, SpillableBatch, acquire_with_retry,
                       register_with_retry)
-from .common import compact, concat_batches, jit_named, slice_batch, \
-    sort_operands
+from .common import KernelPrograms, compact, concat_batches, jit_named, \
+    slice_batch, sort_operands
 from .sort import SortOrder, sort_batch
 
 
@@ -55,11 +55,11 @@ class OutOfCoreSorter:
         self.schema = schema
         self.catalog = catalog
         self.chunk_rows = chunk_rows
-        self._sort_jit = jit_named("OutOfCoreSorter_sort",
-                                   lambda b: sort_batch(b, self.orders))
-        self._split_jit = jit_named("OutOfCoreSorter_split",
-                                    self._split_kernel,
-                                    static_argnums=(2,))
+        programs = KernelPrograms(self, ("orders",))
+        self._sort_jit = programs.jit(
+            "sort", lambda self, b: sort_batch(b, self.orders))
+        self._split_jit = programs.jit("split", type(self)._split_kernel,
+                                       static_argnums=(2,))
         self._slice_jit = jit_named("slice_batch", slice_batch,
                                     static_argnums=(3,))
 

@@ -24,7 +24,7 @@ from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..expressions.base import EvalContext, Expression
 from .base import Exec, UnaryExec
 from .basic import bind_all
-from .common import concat_batches, gather, gather_column, jit_named, \
+from .common import KernelPrograms, concat_batches, gather, gather_column, \
     slice_batch, sort_permutation
 
 
@@ -85,9 +85,8 @@ class SortExec(UnaryExec):
         self.orders = [o.bind(child.output_schema) for o in orders]
         self.global_sort = global_sort
         self.max_rows = max_rows
-        self._sort_jit = jit_named(
-            f"{type(self).__name__}_sort",
-            lambda b: sort_batch(b, self.orders, self.ctx))
+        self._sort_jit = KernelPrograms(self, ("orders",)).jit(
+            "sort", lambda self, b: sort_batch(b, self.orders, self.ctx))
 
     @property
     def output_schema(self) -> Schema:
@@ -182,19 +181,20 @@ class TakeOrderedAndProjectExec(UnaryExec):
         self._schema = schema_of(self.project) if self.project \
             else child.output_schema
 
-        def topn(b: ColumnarBatch) -> ColumnarBatch:
+        def topn(self, b: ColumnarBatch) -> ColumnarBatch:
             s = sort_batch(b, self.orders, self.ctx)
             n = jnp.minimum(s.num_rows, jnp.int32(self.limit))
             cut = bucket_capacity(min(self.limit, b.capacity))
             return slice_batch(s, jnp.int32(0), n, cut)
 
-        self._topn_jit = jit_named(f"{type(self).__name__}_topn", topn)
+        programs = KernelPrograms(self, ("limit", "orders", "project"))
+        self._topn_jit = programs.jit("topn", topn)
 
-        def proj(b: ColumnarBatch) -> ColumnarBatch:
+        def proj(self, b: ColumnarBatch) -> ColumnarBatch:
             cols = tuple(e.eval(b, self.ctx) for e in self.project)
             return ColumnarBatch(cols, b.num_rows)
 
-        self._proj_jit = jit_named(f"{type(self).__name__}_project", proj) \
+        self._proj_jit = programs.jit("project", proj) \
             if self.project else None
 
     @property

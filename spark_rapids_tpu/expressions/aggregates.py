@@ -972,6 +972,7 @@ class Percentile(AggregateFunction):
                             T.FLOAT64)
 
 
+@dataclass(frozen=True, eq=False)
 class ApproxPercentile(Percentile):
     """approx_percentile(col, q[, accuracy]): answered EXACTLY.
 
@@ -981,11 +982,7 @@ class ApproxPercentile(Percentile):
     is one gather — and an exact answer satisfies any accuracy contract.
     The accuracy argument is accepted and ignored."""
 
-    def __init__(self, child=None, percentage: float = 0.5,
-                 accuracy: int = 10000):
-        object.__setattr__(self, "child", child)
-        object.__setattr__(self, "percentage", percentage)
-        object.__setattr__(self, "accuracy", accuracy)
+    accuracy: int = 10000
 
     def with_children(self, c):
         return ApproxPercentile(c[0] if c else None, self.percentage,

@@ -109,14 +109,13 @@ class ColumnarWriteTask:
         self.rows = 0
         self._bucket_ids = None
         if bucket_spec is not None:
-            from ..exec.common import jit_named
+            from ..exec.common import KernelPrograms
             from ..expressions.base import col
             from ..shuffle.partitioning import HashPartitioning
             cols, n = bucket_spec
             part = HashPartitioning([col(c) for c in cols], n).bind(schema)
-            self._bucket_ids = jit_named(
-                "ColumnarWriteTask_bucketIds",
-                lambda b: part.partition_ids(b))
+            self._bucket_ids = KernelPrograms(self, (), also=[part]).jit(
+                "bucketIds", lambda self, b: part.partition_ids(b))
 
     def _target(self, part_key: Tuple, bucket: Optional[int]) -> str:
         name = f"part-{self.task_id:05d}-{self._uuid}"

@@ -43,6 +43,7 @@ import pyarrow as pa
 
 from .. import types as T
 from ..batch import Field as SField, Schema
+from ..exec.aggregate import AggregateMode
 from ..exec.join import JoinType
 from ..exec.sort import SortOrder
 from ..expressions.base import Expression
@@ -78,7 +79,8 @@ _PLAN_NODES: Dict[str, type] = {
                 L.LogicalGenerate)
 }
 
-_ENUMS: Dict[str, type] = {"JoinType": JoinType, "ReaderType": ReaderType}
+_ENUMS: Dict[str, type] = {"JoinType": JoinType, "ReaderType": ReaderType,
+                           "AggregateMode": AggregateMode}
 
 
 _PLAIN_DATACLASSES: Dict[str, type] = {}
@@ -109,6 +111,18 @@ def _file_sources() -> Dict[str, type]:
 # value codec
 # ---------------------------------------------------------------------------
 
+def _refuse_unstated(cls: type) -> None:
+    """An expression is written down as its dataclass fields, so they must
+    be all its constructor takes: a class with a hand-written ``__init__``
+    (``udf/compiler``'s loop nodes) holds state that ``astuple()`` does
+    not list, and would read as equal to every other instance of its
+    class."""
+    made_by = next(c for c in cls.__mro__ if "__init__" in vars(c))
+    if "__dataclass_fields__" not in vars(made_by):
+        raise PlanDecodeError(
+            f"cannot serialize {cls.__name__}: its fields do not state it")
+
+
 def encode_value(v: Any) -> Any:
     if v is None or isinstance(v, (bool, int, str)):
         return v
@@ -120,6 +134,7 @@ def encode_value(v: Any) -> Any:
     if isinstance(v, np.generic):
         return encode_value(v.item())
     if isinstance(v, Expression):
+        _refuse_unstated(type(v))
         return {"$e": [type(v).__name__]
                 + [encode_value(x) for x in v.astuple()]}
     if isinstance(v, SortOrder):

@@ -676,6 +676,7 @@ class PlanServer:
         stable (``schemaVersion`` guards it): the router aggregates
         these fleet-wide and ``readiness_line`` formats from the
         ``server`` block, so every field here is load-bearing."""
+        from .. import compile_cache
         from ..plan import adaptive, plancache, sharing
         from ..shuffle.lineage import metrics as lineage_metrics
         from ..trace import observed_costs
@@ -688,7 +689,10 @@ class PlanServer:
             # splits / broadcast switches)
             # v4: adds the `sharing` block (in-flight dedup, subplan
             # cache, scan-share registry, admission affinity batching)
-            "schemaVersion": 4,
+            # v5: adds the `programs` block (the process's program
+            # table: entries, hits, misses, unkeyed, evictions)
+            "schemaVersion": 5,
+            "programs": compile_cache.program_table().stats(),
             "adaptive": adaptive.metrics().snapshot(),
             "sharing": dict(
                 sharing.metrics().snapshot(),

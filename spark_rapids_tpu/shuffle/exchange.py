@@ -23,7 +23,8 @@ import jax.numpy as jnp
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..exec.base import Exec, UnaryExec
-from ..exec.common import compact, concat_batches, jit_named, slice_batch
+from ..exec.common import KernelPrograms, compact, concat_batches, \
+    slice_batch
 from ..expressions.base import EvalContext
 from ..memory.catalog import BufferCatalog, SpillableBatch
 from .partitioning import Partitioning, RangePartitioning, SinglePartitioning
@@ -84,19 +85,22 @@ class ShuffleExchangeExec(UnaryExec):
         self._use_left: Optional[Dict[Tuple[int, int], int]] = None
         self._catalog = catalog
 
-        def slice_kernel(batch: ColumnarBatch, pids, p: int) -> ColumnarBatch:
+        def slice_kernel(self, batch: ColumnarBatch, pids,
+                         p: int) -> ColumnarBatch:
             return compact(batch, pids == p)
 
-        me = type(self).__name__
-        self._slice_jit = jit_named(f"{me}_slice", slice_kernel,
-                                    static_argnums=2)
-        self._shrink_jit = jit_named(
-            f"{me}_shrink",
-            lambda b, cap: slice_batch(b, 0, b.num_rows, cap),
+        # (the partition index is a static argument: one table entry, a
+        # program a partition inside it, built once a process)
+        programs = KernelPrograms(self, ())
+        self._slice_jit = programs.jit("slice", slice_kernel,
+                                       static_argnums=2)
+        self._shrink_jit = programs.jit(
+            "shrink",
+            lambda self, b, cap: slice_batch(b, 0, b.num_rows, cap),
             static_argnums=1)
-        self._pids_jit = jit_named(
-            f"{me}_pids",
-            lambda b: self.partitioning.partition_ids(b, self.ctx))
+        self._pids_jit = KernelPrograms(self, ("partitioning",)).jit(
+            "pids",
+            lambda self, b: self.partitioning.partition_ids(b, self.ctx))
         from ..exec.base import DEBUG, MODERATE, Metric
         # wire-path visibility: serializeTime = framing/compression,
         # overlapTime = D2H staging hidden behind it (pipeline.py)
@@ -183,10 +187,14 @@ class ShuffleExchangeExec(UnaryExec):
         cap = bucket_capacity(sum(kb.capacity for kb in key_batches))
         allk = concat_batches(key_batches, cap)
 
-        def bounds_kernel(kb: ColumnarBatch):
+        # (locals, not ``part``: the program table keeps the kernel, and
+        # the partitioning holds the sampled bounds on the device)
+        descending, nulls_first = part._descending, part._nulls_first
+
+        def bounds_kernel(self, kb: ColumnarBatch):
             live = kb.row_mask()
             ops = sort_operands(
-                list(kb.columns), part._descending, part._nulls_first, live)
+                list(kb.columns), descending, nulls_first, live)
             perm = lex_sort_permutation(ops)
             skeys = [gather_column(c, perm) for c in kb.columns]
             total = kb.num_rows
@@ -195,8 +203,9 @@ class ShuffleExchangeExec(UnaryExec):
             pos = jnp.clip(pos, 0, kb.capacity - 1).astype(jnp.int32)
             return [gather_column(c, pos) for c in skeys]
 
-        bound_cols = jit_named(f"{type(self).__name__}_bounds",
-                               bounds_kernel)(allk)
+        bound_cols = KernelPrograms(
+            self, (), also=[descending, nulls_first, n]).jit(
+                "bounds", bounds_kernel)(allk)
         part.set_bounds(bound_cols, n - 1)
 
     def _register(self, staged, p: int, piece: ColumnarBatch) -> None:
@@ -628,12 +637,17 @@ class CachedShuffleExchangeExec(UnaryExec):
         self._conf = conf
         self._written = False
         self._write_lock = threading.Lock()
-        self._slice_jit = jit_named(
-            f"{type(self).__name__}_slice",
-            lambda b, pids, p: compact(b, pids == p), static_argnums=2)
-        self._pids_jit = jit_named(
-            f"{type(self).__name__}_pids",
-            lambda b: self.partitioning.partition_ids(b, self.ctx))
+        programs = KernelPrograms(self, ())
+        self._slice_jit = programs.jit(
+            "slice", lambda self, b, pids, p: compact(b, pids == p),
+            static_argnums=2)
+        self._shrink_jit = programs.jit(
+            "shrink",
+            lambda self, b, cap: slice_batch(b, 0, b.num_rows, cap),
+            static_argnums=1)
+        self._pids_jit = KernelPrograms(self, ("partitioning",)).jit(
+            "pids",
+            lambda self, b: self.partitioning.partition_ids(b, self.ctx))
 
     def _get_cache(self):
         if self._cache is None:
@@ -664,10 +678,6 @@ class CachedShuffleExchangeExec(UnaryExec):
         cache = self._get_cache()
         schema = self.child.output_schema
         m = 0
-        shrink = jit_named(
-            f"{type(self).__name__}_shrink",
-            lambda b, cap: slice_batch(b, 0, b.num_rows, cap),
-            static_argnums=1)
         for cp in range(self.child.num_partitions):
             for batch in self.child.execute_partition(cp):
                 pids = self._pids_jit(batch)
@@ -680,7 +690,7 @@ class CachedShuffleExchangeExec(UnaryExec):
                     if cap < piece.capacity:
                         # full-capacity slices would multiply residency by
                         # the partition count (same policy as _register)
-                        piece = shrink(piece, cap)
+                        piece = self._shrink_jit(piece, cap)
                     cache.add_batch(self._shuffle_id, m, r, piece, schema)
                 m += 1
         self._n_maps = m

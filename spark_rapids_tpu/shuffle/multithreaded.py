@@ -21,7 +21,7 @@ import jax
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..exec.base import Exec, UnaryExec
-from ..exec.common import compact, concat_batches, jit_named
+from ..exec.common import KernelPrograms, compact, concat_batches
 from ..expressions.base import EvalContext
 from .partitioning import Partitioning, RangePartitioning
 from .serializer import deserialize_batch, serialize_batch
@@ -113,14 +113,14 @@ class MultithreadedShuffleExchangeExec(UnaryExec):
         else:
             self._lineage = None
 
-        def slice_kernel(batch, pids, p: int):
+        def slice_kernel(self, batch, pids, p: int):
             return compact(batch, pids == p)
 
-        self._slice_jit = jit_named(f"{type(self).__name__}_slice",
-                                    slice_kernel, static_argnums=2)
-        self._pids_jit = jit_named(
-            f"{type(self).__name__}_pids",
-            lambda b: self.partitioning.partition_ids(b, self.ctx))
+        self._slice_jit = KernelPrograms(self, ()).jit(
+            "slice", slice_kernel, static_argnums=2)
+        self._pids_jit = KernelPrograms(self, ("partitioning",)).jit(
+            "pids",
+            lambda self, b: self.partitioning.partition_ids(b, self.ctx))
 
     @property
     def output_schema(self) -> Schema:

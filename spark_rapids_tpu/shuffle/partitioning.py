@@ -35,6 +35,13 @@ class Partitioning:
         """int32[cap] target partition per row (live rows only meaningful)."""
         raise NotImplementedError
 
+    def program_key(self):
+        """Everything ``partition_ids`` reads when it is traced, as values
+        ``exec.common.program_key`` can encode, for the program table; None
+        where that includes run-time state (sampled bounds), so that the
+        exchange keeps a program of its own."""
+        return None
+
 
 @dataclass
 class HashPartitioning(Partitioning):
@@ -55,6 +62,9 @@ class HashPartitioning(Partitioning):
         m = h % jnp.int32(self.num_partitions)
         return jnp.where(m < 0, m + self.num_partitions, m).astype(jnp.int32)
 
+    def program_key(self):
+        return [list(self.exprs), self.num_partitions]
+
 
 @dataclass
 class RoundRobinPartitioning(Partitioning):
@@ -66,6 +76,9 @@ class RoundRobinPartitioning(Partitioning):
         return ((jnp.arange(cap, dtype=jnp.int32) + self.start)
                 % self.num_partitions)
 
+    def program_key(self):
+        return [self.num_partitions, self.start]
+
 
 @dataclass
 class SinglePartitioning(Partitioning):
@@ -73,6 +86,9 @@ class SinglePartitioning(Partitioning):
 
     def partition_ids(self, batch, ctx=EvalContext()):
         return jnp.zeros(batch.capacity, jnp.int32)
+
+    def program_key(self):
+        return []
 
 
 @dataclass

@@ -448,6 +448,15 @@ class OperatorSpan:
             a["batches"] = self._batches
             self._span.lazy_rows.append(batch.num_rows)
 
+    def note_programs(self, hits: int, misses: int) -> None:
+        """Of the keyed programs this operator's exec stated when it was
+        built, how many the program table had (``programHits``: their
+        calls re-trace and re-lower nothing) and how many it had to make
+        (``programMisses``: each is traced and lowered once per input
+        shape, under ``traces``/``lowerings``/``compiles``)."""
+        self._span.attrs["programHits"] = hits
+        self._span.attrs["programMisses"] = misses
+
     def close(self) -> None:
         self._trace.close_span(self._span)
 

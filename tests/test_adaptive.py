@@ -564,7 +564,7 @@ class TestAdaptiveFleet:
                            for r in direct.last_adaptive), \
                     direct.last_adaptive
                 st = direct.stats()
-                assert st["schemaVersion"] == 4
+                assert st["schemaVersion"] == 5
                 assert st["adaptive"]["costFedPlanCount"] >= 1
 
             rst = router.serving_stats()

@@ -848,8 +848,11 @@ def test_stats_wire_op_and_stable_schema():
         # count, dropped spans, cost-store size) joined the schema;
         # v3: the adaptive block (cost-fed plans + runtime re-plan
         # counters) joined it; v4: the sharing block (in-flight dedup,
-        # subplan cache, scan-share registry, affinity batching)
-        assert st["schemaVersion"] == 4
+        # subplan cache, scan-share registry, affinity batching); v5: the
+        # programs block (the process's program table)
+        assert st["schemaVersion"] == 5
+        assert set(st["programs"]) == {"entries", "hits", "misses",
+                                       "unkeyed", "evictions"}
         assert set(st["adaptive"]) == {
             "costFedPlanCount", "explorationRunCount", "replanCount",
             "coalescedPartitionCount", "skewSplitCount",

@@ -82,6 +82,15 @@ def _profile_of(ses):
     return qtrace.flight_recorder().profiles(ses.last_query_id)[0]
 
 
+@pytest.fixture
+def no_programs_yet():
+    """For a test that counts an exec's FIRST lowering: an earlier test of
+    this process may have stated the same program, and then nothing of it
+    is lowered again (``compile_cache.ProgramTable``)."""
+    from spark_rapids_tpu.compile_cache import program_table
+    program_table().clear()
+
+
 def test_a_lowering_between_pulls_is_the_parents_not_the_open_childs():
     jnp.arange(5) * 3 + 1           # the eager ops of the operand: not ours
     plan = _JitsBetweenPulls(_TwoBatches())
@@ -107,7 +116,7 @@ def test_a_lowering_between_pulls_is_the_parents_not_the_open_childs():
         <= parent["durUs"]
 
 
-def test_self_times_add_up_to_execute(tmp_path):
+def test_self_times_add_up_to_execute(tmp_path, no_programs_yet):
     ses = Session(dict(TRACE_ON))
     out = ses.collect(_aggregate(_scan_frame(tmp_path, 1)))
     assert out.num_rows == 7
@@ -400,3 +409,45 @@ def test_a_named_program_keeps_its_name_and_a_named_function_its_cache():
     # of it re-traces nothing (JAX caches the trace by the function)
     assert jit_named("slice_batch", slice_batch,
                      static_argnums=3).__wrapped__ is slice_batch
+    # with no key, a wrapper (and so a trace and a lowering) an instance
+    assert jit_named("SomeExec_role", lambda v: v + 1) is not f
+
+
+@pytest.mark.parametrize("other,same", [
+    (dict(), True),
+    (dict(key="k2"), False),
+    (dict(name="SomeExec_other"), False),
+    (dict(static_argnums=(1,)), False),
+    (dict(static_argnums=1), False),
+    (dict(key=None), False),
+], ids=["equal", "key", "name", "jit_arguments", "jit_argument_form",
+        "no_key"])
+def test_a_keyed_program_is_one_object_a_name_key_and_jit_arguments(
+        other, same):
+    from spark_rapids_tpu.compile_cache import program_table
+    from spark_rapids_tpu.exec.common import jit_named
+    ran = []
+
+    def kernel(tag):
+        def fun(v, n=2):
+            ran.append(tag)
+            return v * n
+        return fun
+    one = dict(name="SomeExec_keyed", key="k1", static_argnums=())
+    before = program_table().stats()
+    f = jit_named(fun=kernel("first"), **one)
+    g = jit_named(fun=kernel("second"), **dict(one, **other))
+    assert (g is f) == same
+    x = jnp.arange(3)
+    if "static_argnums" in other:
+        assert g(x, 3).tolist() == [0, 3, 6]
+    assert f(x).tolist() == g(x).tolist() == [0, 2, 4]
+    # the first to state a key made its program: a hit's own function is
+    # dropped, its Python body never runs
+    assert ("second" in ran) == (not same)
+    after = program_table().stats()
+    keyed = 1 if other.get("key", "k1") is None else 2
+    assert after["hits"] + after["misses"] \
+        == before["hits"] + before["misses"] + keyed
+    assert after["unkeyed"] == before["unkeyed"] + 2 - keyed
+    assert "jit_SomeExec_keyed" in f.lower(x).as_text()[:200]

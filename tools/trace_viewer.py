@@ -123,8 +123,9 @@ def self_time_table(profile: dict) -> List[dict]:
     """One row per span name of one profile, largest own time first:
     spans, their time inside (an operator's pulls, any other span's
     duration), their OWN time (``trace.self_times``: less the children
-    on the same thread), pulls, and what JAX did under them (lowerings,
-    and the milliseconds of ``jit.*`` spans directly below)."""
+    on the same thread), pulls, what JAX did under them (lowerings, and
+    the milliseconds of ``jit.*`` spans directly below), and how many of
+    their execs' keyed programs the program table already had."""
     import os
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), os.pardir))
@@ -137,12 +138,15 @@ def self_time_table(profile: dict) -> List[dict]:
         attrs = s.get("attrs") or {}
         r = rows.setdefault(s["name"], {
             "name": s["name"], "spans": 0, "insideMs": 0.0, "selfMs": 0.0,
-            "pulls": 0, "lowerings": 0, "relowerMs": 0.0})
+            "pulls": 0, "lowerings": 0, "relowerMs": 0.0,
+            "programHits": 0, "programMisses": 0})
         r["spans"] += 1
         r["insideMs"] += inside_us(s) / 1000.0
         r["selfMs"] += own[s["id"]] / 1000.0
         r["pulls"] += int(attrs.get("pulls", 0))
         r["lowerings"] += int(attrs.get("lowerings", 0))
+        r["programHits"] += int(attrs.get("programHits", 0))
+        r["programMisses"] += int(attrs.get("programMisses", 0))
         parent = name_of.get(s.get("parent"))
         if s["name"].startswith("jit.") and parent in rows \
                 and not parent.startswith("jit."):
@@ -159,11 +163,13 @@ def print_tables(profiles: Iterable[dict],
               f"{prof.get('queryId', '?')}: "
               f"{(prof.get('durUs') or 0) / 1000.0:.1f} ms")
         print(f"{'span':<34}{'n':>4}{'inside ms':>12}{'self ms':>12}"
-              f"{'pulls':>7}{'lowerings':>10}{'relower ms':>12}")
+              f"{'pulls':>7}{'lowerings':>10}{'relower ms':>12}"
+              f"{'hit/miss':>10}")
         for r in self_time_table(prof):
             print(f"{r['name']:<34}{r['spans']:>4}{r['insideMs']:>12.1f}"
                   f"{r['selfMs']:>12.1f}{r['pulls']:>7}"
-                  f"{r['lowerings']:>10}{r['relowerMs']:>12.1f}")
+                  f"{r['lowerings']:>10}{r['relowerMs']:>12.1f}"
+                  f"{str(r['programHits']) + '/' + str(r['programMisses']):>10}")
 
 
 def main(argv=None) -> int:
