@@ -5,10 +5,13 @@ come out as not correct.
 
     python3 benchmarks/control.py --workload <cell> --seeds 1,2,3 [--scale 1.0]
 
-``lower_precision``  the reference's arithmetic in the nearest precision
-                     below the configuration's (float32 for float64; for the
-                     exact-decimal configuration float32 sums rounded to
-                     cents, which groups of a few rows do not show)
+``lower_precision``  the reference's arithmetic in the precision the
+                     configuration states as its control
+                     (``guarantees.control_precision``, a numpy float type's
+                     name: float32 below float64; below exact decimals,
+                     float32 sums rounded to cents). Not run where the
+                     configuration states none (null, with
+                     ``control_precision_why``)
 ``lost_batch``       the largest table's last file is never read: the
                      guarantee that every row of every scanned file counts
 Host code only (numpy, pyarrow); prints one JSON line a seed and control.
@@ -28,8 +31,21 @@ sys.path.insert(0, HERE)
 from rtbench import compare, data, loader      # noqa: E402
 
 
-def lower_precision(query, read, params):
-    return query.reference(read, params, money=np.float32)
+def control_precision(config):
+    """The numpy float type the configuration names, or None."""
+    name = config["guarantees"]["control_precision"]
+    if name is None:
+        return None
+    kind = getattr(np, name, None)
+    if not (isinstance(kind, type) and issubclass(kind, np.floating)):
+        raise loader.BenchmarkError(
+            f"guarantees.control_precision {name!r} of configuration "
+            f"{config['name']!r} is no numpy float type's name (or null)")
+    return kind
+
+
+def lower_precision(query, read, params, precision):
+    return query.reference(read, params, money=precision)
 
 
 def lost_batch(query, read, params, written):
@@ -50,6 +66,7 @@ def lost_batch(query, read, params, written):
 def readings(config, traffic, scale, seed, work, rehearsal=False):
     family = config["family"]
     limit = config["guarantees"]["double_rel_err"]
+    precision = control_precision(config)
     out_dir = os.path.join(work, f"control-{config['name']}-{seed}")
     out = []
     try:
@@ -60,9 +77,11 @@ def readings(config, traffic, scale, seed, work, rehearsal=False):
                                         sorted(query.TABLES), out_dir)
             read = data.reader(written)
             want = query.reference(read, params)
-            for name, answer in (
-                    ("lower_precision", lower_precision(query, read, params)),
-                    ("lost_batch", lost_batch(query, read, params, written))):
+            answers = {"lost_batch": lost_batch(query, read, params, written)}
+            if precision is not None:
+                answers["lower_precision"] = lower_precision(
+                    query, read, params, precision)
+            for name, answer in answers.items():
                 r = compare.compare(answer, want, query.ORDERED)
                 _, correct = compare.verdict([r], 0, limit)
                 out.append({"control": name, "query": entry["query"],

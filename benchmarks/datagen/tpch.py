@@ -5,10 +5,14 @@ under ``assumed`` in ``configs/tpch_sf1.json``.
 ``generate(config, scale, seed, tables)`` returns ``{table: pyarrow.Table}``
 for the tables asked for. ``scale`` multiplies the configuration's row
 counts (1.0 on the chip, a small fraction in the CPU rehearsal). Money and
-quantity are ``double`` (the configuration's ``reduced``), keys ``int64``,
+quantity come in the type the configuration's ``money_type`` states:
+``double`` (the ``--floats`` form), or ``decimal(15,2)`` (the published
+type) as ``decimal128(15, 2)`` holding the same cents. Keys are ``int64``,
 dates ``date32``, ``char``/``varchar`` Arrow strings. numpy and pyarrow
 only: the client process never touches JAX.
 """
+
+import re
 
 import numpy as np
 import pyarrow as pa
@@ -35,6 +39,27 @@ WORDS = ("furiously sly carefully blithely quickly fluffily slyly quietly "
          "engage hinder print above the according to").split()
 
 _STREAMS = {"customer": 1, "orders": 2, "lineitem": 3}
+_DECIMAL = re.compile(r"^decimal\((\d+), *2\)$")
+
+
+def money(config, cents, dollars=None):
+    """A money or quantity column from integer cents, in the configuration's
+    ``money_type``. ``dollars`` is the double form where the caller already
+    holds it; a decimal's 128-bit unscaled value is the sign-extended
+    int64."""
+    kind = config["money_type"]
+    if kind == "double":
+        return cents / 100.0 if dollars is None else dollars
+    m = _DECIMAL.match(kind)
+    if m is None or int(m.group(1)) > 18:
+        raise ValueError(f"money_type {kind!r}: this generator makes "
+                         f"'double' or 'decimal(p,2)' with p <= 18")
+    lo = np.ascontiguousarray(cents, dtype=np.int64)
+    words = np.empty((len(lo), 2), dtype=np.int64)
+    words[:, 0] = lo
+    words[:, 1] = lo >> 63
+    return pa.Array.from_buffers(pa.decimal128(int(m.group(1)), 2), len(lo),
+                                 [None, pa.py_buffer(words)])
 
 
 def _rng(seed, table):
@@ -91,7 +116,7 @@ def customer(config, scale, seed, shared):
         "c_address": _text(rng, n, 10, 40),
         "c_nationkey": nation,
         "c_phone": pa.array(phone, pa.string()),
-        "c_acctbal": rng.integers(-99999, 999999 + 1, size=n) / 100.0,
+        "c_acctbal": money(config, rng.integers(-99999, 999999 + 1, size=n)),
         "c_mktsegment": _pool_strings(rng, n, SEGMENTS),
         "c_comment": _text(rng, n, 29, 116),
     })
@@ -141,15 +166,21 @@ def _lineitem_columns(config, scale, seed):
     returned = rng.integers(0, 2, size=total, dtype=np.int8)
     flag = np.where(receiptdate <= CURRENT_DATE, returned, 2).astype(np.int32)
     status = (shipdate > CURRENT_DATE).astype(np.int32)
+    discount = rng.integers(0, 11, size=total)
+    tax = rng.integers(0, 9, size=total)
     return {
         "rng": rng, "core": core, "order_idx": order_idx,
         "l_orderkey": _order_keys(n)[order_idx],
         "l_partkey": partkey, "l_suppkey": suppkey,
         "l_linenumber": linenumber,
+        # the double forms, which orders' totals are computed from, and the
+        # integer cents each holds
         "l_quantity": quantity.astype(np.float64),
         "l_extendedprice": (quantity * retail_cents) / 100.0,
-        "l_discount": rng.integers(0, 11, size=total) / 100.0,
-        "l_tax": rng.integers(0, 9, size=total) / 100.0,
+        "l_discount": discount / 100.0, "l_tax": tax / 100.0,
+        "cents": {"l_quantity": quantity * 100,
+                  "l_extendedprice": quantity * retail_cents,
+                  "l_discount": discount, "l_tax": tax},
         "flag": flag, "status": status,
         "l_shipdate": shipdate, "l_commitdate": commitdate,
         "l_receiptdate": receiptdate,
@@ -168,9 +199,8 @@ def lineitem(config, scale, seed, shared):
     return pa.table({
         "l_orderkey": c["l_orderkey"], "l_partkey": c["l_partkey"],
         "l_suppkey": c["l_suppkey"], "l_linenumber": c["l_linenumber"],
-        "l_quantity": c["l_quantity"],
-        "l_extendedprice": c["l_extendedprice"],
-        "l_discount": c["l_discount"], "l_tax": c["l_tax"],
+        **{name: money(config, cents, c[name])
+           for name, cents in c["cents"].items()},
         "l_returnflag": _codes(c["flag"], ["R", "A", "N"]),
         "l_linestatus": _codes(c["status"], ["F", "O"]),
         "l_shipdate": pa.array(c["l_shipdate"], pa.date32()),
@@ -201,7 +231,8 @@ def orders(config, scale, seed, shared):
         "o_orderkey": _order_keys(n),
         "o_custkey": cust,
         "o_orderstatus": _codes(status, ["F", "O", "P"]),
-        "o_totalprice": total,
+        "o_totalprice": money(config, np.rint(total * 100).astype(np.int64),
+                              total),
         "o_orderdate": pa.array(date, pa.date32()),
         "o_orderpriority": _pool_strings(rng, n, PRIORITIES),
         "o_clerk": _pool_strings(rng, n, clerks),

@@ -38,7 +38,8 @@ def load(path):
     """``{"devices": [{"ops": [...], "modules": [...]}], "host": {thread:
     [...]}, "extent_ns": n}``, every event ``(name, start_ns, duration_ns)``
     on the trace's one clock, and the trace's own length, profiler start to
-    stop (None where the trace does not say)."""
+    stop (None where the trace does not say). Every line of the host's
+    plane is kept (``host_lines``)."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     devices, host, extent = [], {}, None
@@ -52,14 +53,26 @@ def load(path):
                     dev["modules"] += _events(line)
             devices.append(dev)
         elif plane.name == HOST_PLANE:
-            for line in plane.lines:
-                host[line.name] = _events(line)
+            host = host_lines((line.name, _events(line))
+                              for line in plane.lines)
         elif plane.name == SESSION_PLANE:
             stats = dict(plane.stats)
             if {"profile_start_time", "profile_stop_time"} <= set(stats):
                 extent = float(stats["profile_stop_time"]
                                - stats["profile_start_time"])
     return {"devices": devices, "host": host, "extent_ns": extent}
+
+
+def host_lines(lines):
+    """``{key: events}`` of ``(name, events)`` pairs, none lost: a line
+    keeps its name as its key, and where two threads share a name
+    (``python``, or none at all) the later ones get ``name#2``,
+    ``name#3``."""
+    out, seen = {}, {}
+    for name, events in lines:
+        seen[name] = seen.get(name, 0) + 1
+        out[name if seen[name] == 1 else f"{name}#{seen[name]}"] = events
+    return out
 
 
 def union(intervals):
@@ -123,18 +136,14 @@ def _named_ops(device):
 
 
 def _covering(host, start, end):
-    """The host event that covers most of ``[start, end]``, by name; the
-    innermost such event where several nest."""
-    best, best_cover, best_dur = "unattributed", 0.0, 0.0
+    """What the host was doing in ``[start, end]``, by name: of the events
+    that each cover over half of it, the innermost, which is the shortest
+    (a pull holds its leaf; the leaf names the gap even where the gap began
+    a little before it)."""
+    best, best_dur = "unattributed", float("inf")
     for events in host.values():
         for name, s, dur in events:
             cover = min(end, s + dur) - max(start, s)
-            if cover <= 0:
-                continue
-            # more cover wins; at equal cover the shorter (inner) event
-            if cover > best_cover * 1.001 or (
-                    cover >= best_cover * 0.999 and dur < best_dur):
-                best, best_cover, best_dur = name, cover, dur
-    if best_cover < 0.5 * (end - start):
-        return "unattributed"
+            if cover > 0.5 * (end - start) and dur < best_dur:
+                best, best_dur = name, dur
     return best

@@ -13,6 +13,25 @@ if BENCH not in sys.path:
 import json          # noqa: E402
 import subprocess    # noqa: E402
 
+import pytest        # noqa: E402
+
+DOMAINS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "domains")
+
+
+def domains(generator):
+    """The module of checks of one generator, ``domains/<generator>.py``,
+    found by the generator's name as ``rtbench/loader.py`` finds the
+    generator itself. One that is missing fails the test that asks, with
+    the file to add: nothing is skipped."""
+    from rtbench import loader
+    path = os.path.join(DOMAINS_DIR, generator + ".py")
+    if not os.path.exists(path):
+        pytest.fail(f"generator {generator!r} has no checks: add {path} "
+                    f"exporting DOMAINS = {{table: check(t, tables, cfg)}} "
+                    f"and ROWS = {{table: rule(cfg, scale, tables)}}")
+    return loader._module(path, f"domains_{generator}")
+
 
 def run_cli(script, args, cwd=REPO, timeout=600):
     """Run one of the benchmark's commands as the driver would; returns

@@ -33,13 +33,29 @@ def test_a_control_comes_out_not_correct(cell, tmp_path):
     by_name = {r["control"]: r for r in got}
     assert by_name["lost_batch"]["correct"] is False
     assert by_name["lost_batch"]["exact_mismatches"] > 0
-    if config["guarantees"]["double_rel_err"] is not None:
-        # the configuration states float64: float32 has to fail its limit
+    guarantees = config["guarantees"]
+    if guarantees["control_precision"] is None:
+        # no precision control, and the configuration says why
+        assert "lower_precision" not in by_name
+        assert guarantees["control_precision_why"]
+    else:
         low = by_name["lower_precision"]
         assert low["correct"] is False
-        assert low["double_rel_err"] \
-            > 3 * config["guarantees"]["double_rel_err"]
+        if guarantees["double_rel_err"] is not None:
+            # doubles: the lower precision reads over three times the limit
+            assert low["double_rel_err"] > 3 * guarantees["double_rel_err"]
+        else:
+            # no double: lower precision has to get an exact value wrong
+            assert low["exact_mismatches"] > 0
     assert not os.listdir(tmp_path)
+
+
+def test_a_control_precision_that_is_no_float_type_is_refused():
+    config = dict(_cell(CELLS[0])[0])
+    config["guarantees"] = dict(config["guarantees"],
+                                control_precision="none that a query sees")
+    with pytest.raises(loader.BenchmarkError, match="control_precision"):
+        control.control_precision(config)
 
 
 DRIVER = textwrap.dedent('''

@@ -5,11 +5,12 @@ rehearsal."""
 
 import json
 import os
+import textwrap
 import types
 
 import pytest
 
-from benchlib import REPO, run_cli
+from benchlib import BENCH, REPO, run_cli
 from rtbench import loader, spantree
 
 MS = 1000       # microseconds
@@ -118,20 +119,58 @@ def test_self_time_leaves_out_children_on_other_threads():
         == (500 - 300 - 10 - 30 - 60) * MS
 
 
+FIRST_EXECUTION = textwrap.dedent('''
+    """run.py as it is; the server's profile of the harness's FIRST
+    submission, a shape's first execution on a fresh server, is read with
+    the benchmark's own readers and printed: no switch of the harness."""
+    import sys
+    import types
+    sys.path.insert(0, {bench!r})
+    import run
+    from spark_rapids_tpu.server.client import PlanClient
+
+    served, seen = PlanClient.collect, []
+
+    def collect(self, df, *a, **k):
+        table = served(self, df, *a, **k)
+        if not seen:
+            seen.append(types.SimpleNamespace(error=None, query=0,
+                                              trace=self.last_trace()))
+            for name in ("lowerings_per_query", "relower_ms"):
+                value = run.loader.metric(name).read({{"done": seen}})
+                print(f"first execution {{name}}: {{value!r}}",
+                      file=sys.stderr)
+        return table
+    PlanClient.collect = collect
+    sys.exit(run.main({args!r}))
+''')
+
+
 def test_a_traced_rehearsal_prints_all_eight(tmp_path):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
     assert [m["name"] for m in bench["per_layer"]][-8:] == NEW
-    rc, last, out, err = run_cli(
-        os.path.join(REPO, "benchmarks", "run.py"),
-        ["--workload", "tpcds_sf1.q3", "--seed", 2 ** 31 + 28,
-         "--seconds", 1, "--trace", 1, "--rehearsal", "--work-dir",
-         tmp_path])
+    driver = tmp_path / "driver.py"
+    driver.write_text(FIRST_EXECUTION.format(bench=BENCH, args=[
+        "--workload", "tpcds_sf1.q3", "--seed", str(2 ** 31 + 28),
+        "--seconds", "1", "--trace", "1", "--rehearsal", "--work-dir",
+        str(tmp_path / "work")]))
+    rc, last, out, err = run_cli(str(driver), [])
     assert rc == 0, err[-3000:]
     assert last["correct"] is True
     m = {k: v["value"] for k, v in last["metrics"].items()}
     assert set(NEW) <= set(m)
-    assert m["lowerings_per_query"] >= 1 and m["relower_ms"] > 0
+    # a warm query lowers nothing since the program table (PR 29): both are
+    # reported, and 0 is a reading
+    assert m["lowerings_per_query"] >= 0 and m["relower_ms"] >= 0
+    # the cost is still there in a shape's first execution on a fresh
+    # server (this rehearsal's cache directory is its own, and empty)
+    first = {ln.split(": ")[0].split()[-1]: float(ln.split(": ")[1])
+             for ln in err.splitlines()
+             if ln.startswith("first execution ")}
+    assert first["lowerings_per_query"] >= 1 and first["relower_ms"] > 0
+    assert first["lowerings_per_query"] > m["lowerings_per_query"]
+    assert first["relower_ms"] > m["relower_ms"]
     assert m["scan_decode_ms"] > 0 and m["scan_h2d_ms"] > 0
     assert m["execute_ms"] > m["relower_ms"]
     assert m["gc_pause_ms"] >= 0 and m["plan_materialize_ms"] >= 0
@@ -163,18 +202,25 @@ def test_recorded_chip_trace_names_idle_gaps_by_engine_spans(tmp_path):
     trace = xplane.load(str(path))
     assert [d["name"] for d in trace["devices"]] == want["devices"]
     assert trace["extent_ns"] == want["extent_ns"]
-    assert sorted(trace["host"]) == sorted(want["host_lines"])
-    assert "python" not in trace["host"]
-    held = {name for name, _, _ in trace["host"]["rtpu-q-0"]}
+    # every line of the host's plane, the one with no name too
+    host = trace["host"]
+    assert sorted(host) == sorted(want["host_lines"]) and "" in host
+    assert "python" not in host
+    held = {name for name, _, _ in host["rtpu-q-0"]}
     assert ENGINE_SPANS - {"scan.decode"} <= held
     assert {name for line in ("rtpu-read-0", "rtpu-read-1")
-            for name, _, _ in trace["host"][line]} == {"scan.decode"}
+            for name, _, _ in host[line]} == {"scan.decode"}
     r = xplane.reduce(trace)
     assert r["program_executions"] == want["program_executions"]
     assert r["busy_s"] == pytest.approx(want["busy_s"], rel=1e-9)
     assert [n for n, _ in r["device_ops"]] == want["top_ops"]
+    # four gaps of a millisecond read np.asarray(jax.Array) where they read
+    # result.d2h when recorded: the innermost event over half the gap names
+    # it now (tpu_v5e_spans.json, idle_gaps_renamed)
     gaps = [n for n, _ in r["idle_gaps"]]
     assert gaps == [n for n, _ in want["idle_gaps"]]
+    assert [g for _, g in r["idle_gaps"]] == pytest.approx(
+        [g for _, g in want["idle_gaps"]], rel=1e-9)
     assert set(gaps) & ENGINE_SPANS
     assert "unattributed" not in gaps
     assert not any("_lambda_" in n or "jit_kernel" in n

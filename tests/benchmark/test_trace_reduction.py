@@ -43,8 +43,9 @@ def test_known_intervals_give_known_numbers():
     assert r["device_ops"] == [["jit_a/sort.2", pytest.approx(0.015)],
                                ["jit_a/fusion.1", pytest.approx(0.010)],
                                ["jit_b/fusion.1", pytest.approx(0.010)]]
-    # one gap, 20..50 ms, and the host event that covers most of it
-    assert r["idle_gaps"] == [["PjRtCompile", pytest.approx(0.030)]]
+    # one gap, 20..50 ms: PjRtCompile covers 28 ms of it and inner.load,
+    # inside it, 20 ms; of those over half the gap, the innermost names it
+    assert r["idle_gaps"] == [["inner.load", pytest.approx(0.030)]]
 
 
 def test_metric_readers_on_the_known_trace():
@@ -63,6 +64,34 @@ def test_a_gap_nothing_covers_is_unattributed():
     t = _trace()
     t["host"] = {"worker": [("brief", 21 * MS, 2 * MS)]}
     assert xplane.reduce(t)["idle_gaps"][0][0] == "unattributed"
+
+
+def test_a_gap_that_begins_before_its_leaf_is_still_the_leafs():
+    """The pull that holds the leaf covers all of the gap and the leaf
+    nine tenths: the leaf names it (PERF.md section 7, PR 28's reading of
+    ``tpcds_sf1.q3``: gaps inside ``scan.h2d`` read ``HashJoinExec``)."""
+    t = _trace()
+    t["host"] = {"rtpu-q-0": [("query", 0 * MS, 90 * MS),
+                              ("HashJoinExec", 10 * MS, 70 * MS),
+                              ("scan.h2d", 23 * MS, 30 * MS),
+                              ("brief", 30 * MS, 10 * MS)]}
+    assert xplane.reduce(t)["idle_gaps"] == [["scan.h2d",
+                                              pytest.approx(0.030)]]
+
+
+def test_two_host_lines_of_one_name_are_both_read():
+    """Two threads called ``python`` (and two with no name): the event that
+    names the gap sits on the first, which the second used to overwrite."""
+    t = _trace()
+    t["host"] = xplane.host_lines([
+        ("python", [("from_arrow", 19 * MS, 32 * MS)]),
+        ("", [("noise", 0 * MS, 1 * MS)]),
+        ("python", [("tick", 90 * MS, 1 * MS)]),
+        ("", [("noise", 95 * MS, 1 * MS)]),
+        ("python", [])])
+    assert list(t["host"]) == ["python", "", "python#2", "#2", "python#3"]
+    assert t["host"]["python"] == [("from_arrow", 19 * MS, 32 * MS)]
+    assert xplane.reduce(t)["idle_gaps"][0][0] == "from_arrow"
 
 
 def test_a_trace_that_does_not_say_its_length_is_refused():
@@ -91,6 +120,7 @@ def test_recorded_tpu_trace_reads_as_it_did_when_recorded():
     with open(os.path.join(BENCH, "recorded", "tpu_v5e_small.json")) as f:
         want = json.load(f)
     trace = xplane.load(RECORDED)
+    assert list(trace["host"]) == want["host_lines"]
     assert [d["name"] for d in trace["devices"]] == want["devices"]
     # the slice's length is the trace's own, profiler start to stop
     assert trace["extent_ns"] == want["extent_ns"]
