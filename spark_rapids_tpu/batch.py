@@ -310,18 +310,10 @@ def _scalar_storage(arr: pa.Array, dtype: SqlType,
     array/map ELEMENT buffers so nested data gets identical encoding."""
     n = len(arr)
     if dtype.kind is TypeKind.DECIMAL:
-        import decimal as pydec
-        # the default decimal context (28 digits) ROUNDS scaleb on wide
-        # values — widen it for the exact unscaled-int conversion
-        with pydec.localcontext() as lctx:
-            lctx.prec = 60
-            ints = [int(v.scaleb(dtype.scale)) if v is not None else 0
-                    for v in arr.to_pylist()]
-        if dtype.precision > 18:
-            # DECIMAL128: 4×32-bit limbs in int64 lanes (decimal128.py)
-            from .expressions.decimal128 import to_limbs_np
-            return to_limbs_np(ints)
-        return np.array(ints, dtype=np.int64)
+        # a numpy view of Arrow's 16-byte values: the low word up to 18
+        # digits, the four 32-bit limbs above (decimal128.py)
+        from .expressions.decimal128 import arrow_decimal_storage
+        return arrow_decimal_storage(arr, dtype.precision, validity)
     if dtype.kind is TypeKind.TIMESTAMP:
         np_vals = np.zeros(n, dtype=np.int64)
         tmp = arr.cast(pa.timestamp("us")).to_numpy(zero_copy_only=False)
@@ -498,9 +490,29 @@ def from_arrow(table: pa.Table, capacity: Optional[int] = None,
         schema = Schema(tight)
     n = table.num_rows
     cap = capacity or bucket_capacity(n)
-    cols = [column_from_arrow(table.column(i), f.dtype, cap, truncate_strings,
-                              name=f.name, dict_conf=dict_conf)
-            for i, f in enumerate(schema)]
+
+    def convert(i):
+        f = schema.fields[i]
+        return column_from_arrow(table.column(i), f.dtype, cap,
+                                 truncate_strings, name=f.name,
+                                 dict_conf=dict_conf)
+
+    cols: List[Optional[DeviceColumn]] = [None] * len(schema.fields)
+    decimals = [i for i, f in enumerate(schema)
+                if f.dtype.kind is TypeKind.DECIMAL]
+    if decimals:
+        # the decimal columns together, under a span of their own: Arrow's
+        # 16-byte values to padded device columns (a child of the scan's
+        # ``scan.h2d`` where a scan asked)
+        from .trace import span
+        with span("scan.h2d.decimal", kind="transfer", values=n,
+                  columns=len(decimals),
+                  bytes=sum(table.column(i).nbytes for i in decimals)):
+            for i in decimals:
+                cols[i] = convert(i)
+    for i in range(len(cols)):
+        if cols[i] is None:
+            cols[i] = convert(i)
     return ColumnarBatch(tuple(cols), jnp.asarray(n, jnp.int32)), schema
 
 
@@ -528,10 +540,10 @@ def empty_batch(schema: Schema, capacity: int = MIN_CAPACITY) -> ColumnarBatch:
 
 def _storage_to_arrow(flat: np.ndarray, dtype: SqlType) -> pa.Array:
     """Inverse of _scalar_storage for non-null element buffers."""
-    import decimal as pydec
     if dtype.kind is TypeKind.DECIMAL:
-        return pa.array([pydec.Decimal(int(v)).scaleb(-dtype.scale)
-                         for v in flat], type=T.to_arrow(dtype))
+        from .expressions.decimal128 import storage_to_arrow_decimal
+        return storage_to_arrow_decimal(
+            flat, T.to_arrow(dtype), np.ones(flat.shape[0], bool))
     if dtype.kind is TypeKind.TIMESTAMP:
         return pa.array(flat.astype("datetime64[us]"),
                         type=T.to_arrow(dtype))
@@ -653,20 +665,8 @@ def _col_to_arrow(col: DeviceColumn, dtype: SqlType, name: str,
         return ma
     data = np.asarray(col.data[:n])
     if dtype.kind is TypeKind.DECIMAL:
-        import decimal as pydec
-        with pydec.localcontext() as lctx:
-            lctx.prec = 60       # exact: default context rounds at 28
-            if dtype.precision > 18:
-                from .expressions.decimal128 import from_limbs_np
-                ints = from_limbs_np(data)
-                vals = [pydec.Decimal(v).scaleb(-dtype.scale)
-                        if ok else None
-                        for v, ok in zip(ints, validity)]
-            else:
-                vals = [pydec.Decimal(int(v)).scaleb(-dtype.scale)
-                        if ok else None
-                        for v, ok in zip(data, validity)]
-        return pa.array(vals, type=T.to_arrow(dtype))
+        from .expressions.decimal128 import storage_to_arrow_decimal
+        return storage_to_arrow_decimal(data, T.to_arrow(dtype), validity)
     if dtype.kind is TypeKind.TIMESTAMP:
         return pa.array(data.astype("datetime64[us]"),
                         type=T.to_arrow(dtype), mask=~validity)

@@ -36,8 +36,10 @@ from .common import _batched_takes, adjacent_equal, adjacent_equal_ops, \
     KernelPrograms, compaction_indices, concat_batches, gather_column, \
     jit_named, lex_sort_permutation, sort_operands
 
-# dtypes whose device payload is a flat 1-D array: the fast kernel gathers
-# such columns through the key sort's permutation in batched row-gathers
+# dtypes whose device payload is a flat 1-D array (or, for a decimal past
+# 18 digits, a limb matrix, gathered as rows the same way): the fast
+# kernel gathers such columns through the key sort's permutation in
+# batched row-gathers
 _FLAT_KINDS = frozenset({
     T.TypeKind.INT8, T.TypeKind.INT16, T.TypeKind.INT32, T.TypeKind.INT64,
     T.TypeKind.FLOAT32, T.TypeKind.FLOAT64, T.TypeKind.BOOLEAN,
@@ -46,8 +48,7 @@ _FLAT_KINDS = frozenset({
 
 
 def _is_flat(t: T.SqlType) -> bool:
-    return t.kind in _FLAT_KINDS or (t.kind is T.TypeKind.DECIMAL
-                                     and t.precision <= 18)
+    return t.kind in _FLAT_KINDS or t.kind is T.TypeKind.DECIMAL
 
 
 def _pad_column(c: DeviceColumn, cap: int) -> DeviceColumn:
@@ -189,6 +190,18 @@ class HashAggregateExec(UnaryExec):
             and have_keys
             and all(_is_flat(f.dtype) for f in self.buffer_fields))
 
+        # Limb buffers (a decimal sum past 18 digits: 32 bytes a row, six
+        # f64 chunk lanes in the merge's stack) weigh several times what a
+        # double buffer does: a merge of four 2^20-row partials no longer
+        # fits the chip (22.7 GB of temporaries for TPC-H Q1's seven limb
+        # sums, tools/aot_compile.py's method). So such an exec, told by
+        # its buffer types alone, cuts each partial to its group count's
+        # bucket as soon as it is made (one host read of the count a
+        # batch) and merges a quarter of the rows at a time.
+        from ..expressions.decimal128 import is_dec128
+        self._wide_buffers = any(is_dec128(f.dtype)
+                                 for f in self.buffer_fields)
+
         # everything the kernels below read of this exec: the programs'
         # key, and all their stand-in has (common.KernelPrograms)
         programs = KernelPrograms(self, (
@@ -197,13 +210,15 @@ class HashAggregateExec(UnaryExec):
             "_upd_value_exprs", "_upd_per_agg", "_fast_update",
             "_fast_merge"))
         cls = type(self)
-        self._update_jit = programs.jit("update", cls._update_kernel)
+        def role(r):
+            return r + "Dec128" if self._wide_buffers else r
+        self._update_jit = programs.jit(role("update"), cls._update_kernel)
         self._merge_jit = programs.jit(
-            "merge", lambda self, b: self._merge_kernel(b, final=False))
+            role("merge"), lambda self, b: self._merge_kernel(b, final=False))
         self._final_jit = programs.jit(
-            "final", lambda self, b: self._merge_kernel(b, final=True))
+            role("final"), lambda self, b: self._merge_kernel(b, final=True))
         self._eval_buffers_jit = programs.jit(
-            "evalBuffers", cls._eval_buffers_kernel)
+            role("evalBuffers"), cls._eval_buffers_kernel)
 
     @staticmethod
     def _expr_key(e: Expression):
@@ -565,6 +580,10 @@ class HashAggregateExec(UnaryExec):
                 part = self._update_jit(batch)
             else:
                 part = batch
+            if self._wide_buffers:
+                out_cap = bucket_capacity(max(int(part.num_rows), 1))
+                if out_cap < part.capacity:
+                    part = self._slice_compact(part, out_cap)
             # registered handles start unpinned (spillable)
             spillables.append((register_with_retry(part, buf_schema,
                                                    catalog=cat,
@@ -618,6 +637,8 @@ class HashAggregateExec(UnaryExec):
             return got
 
         window = self.max_result_rows
+        if self._wide_buffers:
+            window = max(window >> 2, 1)
         while True:
             total = sum(c for _, c in entries)
             if len(entries) == 1 or total <= window:

@@ -24,7 +24,7 @@ from ..batch import (ColumnarBatch, DeviceColumn, Field, Schema,
 from ..expressions.base import Alias, EvalContext, Expression
 from ..types import TypeKind
 from .base import Exec, LeafExec, UnaryExec
-from .common import KernelPrograms, compact, slice_batch
+from .common import KernelPrograms, compact, dec128_role, slice_batch
 
 
 def output_name(e: Expression, i: int) -> str:
@@ -185,7 +185,8 @@ class ProjectExec(UnaryExec):
             cols = tuple(raw_eval(e, batch, ctx) for e in self.exprs)
             return ColumnarBatch(cols, batch.num_rows), _sum_errors(ctx)
 
-        self._kernel = KernelPrograms(self, ("exprs",)).jit("project", kernel)
+        self._kernel = KernelPrograms(self, ("exprs",)).jit(
+            dec128_role("project", [f.dtype for f in self._schema]), kernel)
 
     @property
     def output_schema(self) -> Schema:

@@ -85,6 +85,15 @@ def jit_named(name: str, fun, key: Optional[str] = None, owner=None,
 PURE = "pure"
 
 
+def dec128_role(role: str, types) -> str:
+    """``role`` + "Dec128" where one of ``types`` is a decimal past 18
+    digits: a program that computes on limb matrices says so in its name
+    (``jit_ProjectExec_projectDec128``), and a device trace tells it from
+    its int64 sibling."""
+    from ..expressions.decimal128 import is_dec128
+    return role + "Dec128" if any(is_dec128(t) for t in types) else role
+
+
 class _Unkeyable(Exception):
     """A part of a program key that cannot be written down."""
 

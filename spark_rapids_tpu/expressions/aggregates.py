@@ -140,6 +140,7 @@ class FastLanes:
         self.min_lanes: List[jax.Array] = []
         self.max_lanes: List[jax.Array] = []
         self._count_cache: List[Tuple[Optional[jax.Array], int]] = []
+        self._wide_cache: list = []
 
     def sum_f64(self, x) -> int:
         self.sum_lanes.append(x.astype(jnp.float64))
@@ -156,6 +157,24 @@ class FastLanes:
         for lane in _enc_i64_lanes(x):
             self._sum_exact_lane(lane)
         return (i, i + 1, i + 2)
+
+    def sum_wide(self, data: jax.Array, validity: jax.Array):
+        """A decimal's exact sum of up to 128 bits (``data`` an int64
+        unscaled vector or a limb tensor) as 22-bit chunk lanes
+        (decimal128.chunk_lanes): three a 64-bit word. Returns (what
+        ``LaneResults.sum_wide`` takes, the count lane of the rows
+        summed). Two aggregates over one column (sum and avg) share the
+        lanes: the cache holds references, as ``count``'s does."""
+        for d, v, hit in self._wide_cache:
+            if d is data and v is validity:
+                return hit
+        from .decimal128 import chunk_lanes
+        ok = self.live if validity is self.live else (validity & self.live)
+        lanes, offsets, bias_bit = chunk_lanes(data, ok)
+        refs = [self._sum_exact_lane(x) for x in lanes]
+        hit = ((refs, offsets, bias_bit), self.count(ok))
+        self._wide_cache.append((data, validity, hit))
+        return hit
 
     def count(self, ok: Optional[jax.Array]) -> int:
         """Count of true rows; ok=None counts live rows. The cache holds a
@@ -342,6 +361,14 @@ class LaneResults:
                          self._sum_at[:, ref].astype(jnp.int64),
                          jnp.int64(0))
 
+    def sum_wide(self, wide, n_ok: jax.Array):
+        """(limbs [L, 4], left 128 bits) of ``FastLanes.sum_wide``'s
+        lanes, given the per-group count of the rows summed."""
+        from .decimal128 import from_chunk_sums
+        refs, offsets, bias_bit = wide
+        return from_chunk_sums([self.count(r) for r in refs], offsets,
+                               bias_bit, n_ok)
+
     def min_f64(self, ref: int) -> jax.Array:
         return self._min_at[:, ref]
 
@@ -490,9 +517,9 @@ class Sum(AggregateFunction):
         if k in (TypeKind.FLOAT32, TypeKind.FLOAT64):
             return T.FLOAT64
         if k is TypeKind.DECIMAL:
-            # Spark widens to min(p+10, 38); results wider than DECIMAL64
-            # are planner-gated to CPU (overrides._check_dtype_tree), so the
-            # int64 storage never sees them — but the TYPE must be Spark's.
+            # Spark widens to min(p+10, 38); past 18 digits the buffer is
+            # a limb sum (decimal128.py), on the fused lanes where the
+            # exec takes them (fast_update / fast_merge below)
             d = self.child.dtype
             return T.decimal(min(d.precision + 10, 38), d.scale)
         return T.INT64
@@ -578,17 +605,44 @@ class Sum(AggregateFunction):
                                  T.INT64)]
         return finish
 
+    def _wide_finish(self, wide, nref, total=None, flagged=None):
+        """Finisher of a limb sum on the fused lanes: the chunk lanes'
+        sums back into limbs, Spark's precision cap on top of the 128-bit
+        range, and the (sum, count, overflow) buffers of ``update``.
+        ``total``/``flagged`` are a merge's count lanes (rows behind the
+        partial sums; partials that had already overflowed)."""
+        from .decimal128 import exceeds_digits
+
+        def finish(res: "LaneResults"):
+            n_ok = res.count(nref)
+            limbs, ovf = res.sum_wide(wide, n_ok)
+            ovf = ovf | exceeds_digits(limbs, self.dtype.precision)
+            n = n_ok if total is None else res.sum_int(total)
+            if flagged is not None:
+                ovf = ovf | (res.count(flagged) > 0)
+            one = jnp.ones(n.shape[0], bool)
+            return [DeviceColumn(limbs, n > 0, None, self.dtype),
+                    DeviceColumn(n, one, None, T.INT64),
+                    DeviceColumn(ovf, one, None, T.BOOLEAN)]
+        return finish
+
     def fast_update(self, inputs, live, B):
-        if self._is_dec128:
-            return None
         col = inputs[0]
+        if self._is_dec128:
+            wide, nref = B.sum_wide(col.data, col.validity)
+            return self._wide_finish(wide, nref)
         ok = live if col.validity is live else (col.validity & live)
         return self._lane_finish(self._lane_refs(col.data, ok, B),
                                  B.count(ok))
 
     def fast_merge(self, buffers, live, B):
         if self._is_dec128:
-            return None
+            wide, nref = B.sum_wide(buffers[0].data, buffers[0].validity)
+            return self._wide_finish(
+                wide, nref,
+                total=B.sum_int(jnp.where(live, buffers[1].data,
+                                          jnp.int64(0))),
+                flagged=B.count(live & buffers[2].data))
         ok = buffers[0].validity & live
         kr = self._lane_refs(buffers[0].data, ok, B)
         ncnt = B.sum_int(jnp.where(live, buffers[1].data, jnp.int64(0)))
@@ -773,9 +827,15 @@ class Max(_MinMax):
 
 
 class Average(AggregateFunction):
-    """avg(x) → double (or decimal widening); buffer = (sum: double, count).
-    Decimal averages return Spark's decimal(p+4, s+4) type and are
-    planner-gated to CPU (the device buffer is double)."""
+    """avg(x) → double; buffer = (sum: double, count).
+
+    avg over decimal(p, s) is Spark's: the buffer is ``sum``'s (a
+    decimal(p+10, s) sum, in limbs past 18 digits, the non-null count and
+    the limb sum's overflow flag), so it updates and merges as ``Sum``
+    does, fused lanes included; the result is sum × 10^(s'−s) / count
+    rounded HALF_UP once to decimal(min(p+4, 38), s' = min(s+4, 38))
+    (decimal128.div_half_up), null where the sum overflowed or the
+    quotient passes the precision."""
 
     @property
     def dtype(self):
@@ -784,10 +844,20 @@ class Average(AggregateFunction):
             return T.decimal(min(d.precision + 4, 38), min(d.scale + 4, 38))
         return T.FLOAT64
 
+    @property
+    def _decimal_sum(self) -> Optional[Sum]:
+        if self.child.dtype.kind is TypeKind.DECIMAL:
+            return Sum(self.child)
+        return None
+
     def buffer_types(self):
-        return [T.FLOAT64, T.INT64]
+        ds = self._decimal_sum
+        return ds.buffer_types() if ds else [T.FLOAT64, T.INT64]
 
     def update(self, inputs, seg, live, cap):
+        ds = self._decimal_sum
+        if ds:
+            return ds.update(inputs, seg, live, cap)
         col = inputs[0]
         x, ok = _masked(col, live, jnp.zeros((), col.data.dtype))
         s = _seg_sum(x.astype(jnp.float64), seg, cap)
@@ -796,6 +866,9 @@ class Average(AggregateFunction):
                 DeviceColumn(n, jnp.ones(cap, bool), None, T.INT64)]
 
     def merge(self, buffers, seg, live, cap):
+        ds = self._decimal_sum
+        if ds:
+            return ds.merge(buffers, seg, live, cap)
         s = jnp.where(live & buffers[0].validity, buffers[0].data, 0.0)
         n = jnp.where(live, buffers[1].data, 0)
         ms = _seg_sum(s, seg, cap)
@@ -803,7 +876,27 @@ class Average(AggregateFunction):
         return [DeviceColumn(ms, mn > 0, None, T.FLOAT64),
                 DeviceColumn(mn, jnp.ones(cap, bool), None, T.INT64)]
 
+    def _evaluate_decimal(self, ds: Sum, buffers, group_live):
+        from .decimal128 import div_half_up, lift64, to_int64
+        out = self.dtype
+        total, n = buffers[0].data, buffers[1].data
+        valid = buffers[0].validity & group_live & (n > 0)
+        if len(buffers) > 2:
+            valid = valid & ~buffers[2].data
+        limbs = total if total.ndim > 1 else lift64(total)
+        q, ovf = div_half_up(limbs, out.scale - ds.dtype.scale, n,
+                             out.precision)
+        valid = valid & ~ovf
+        if out.precision > 18:
+            return DeviceColumn(jnp.where(valid[:, None], q, 0), valid,
+                                None, out)
+        return DeviceColumn(jnp.where(valid, to_int64(q), 0), valid, None,
+                            out)
+
     def evaluate(self, buffers, group_live):
+        ds = self._decimal_sum
+        if ds:
+            return self._evaluate_decimal(ds, buffers, group_live)
         n = buffers[1].data
         valid = (n > 0) & group_live
         avg = buffers[0].data / jnp.where(n > 0, n, 1).astype(jnp.float64)
@@ -811,6 +904,9 @@ class Average(AggregateFunction):
 
     # ---- batched lanes -------------------------------------------------
     def fast_update(self, inputs, live, B):
+        ds = self._decimal_sum
+        if ds:
+            return ds.fast_update(inputs, live, B)
         col = inputs[0]
         ok = live if col.validity is live else (col.validity & live)
         sref = B.sum_f64(jnp.where(ok, col.data, 0).astype(jnp.float64))
@@ -824,6 +920,9 @@ class Average(AggregateFunction):
         return finish
 
     def fast_merge(self, buffers, live, B):
+        ds = self._decimal_sum
+        if ds:
+            return ds.fast_merge(buffers, live, B)
         sref = B.sum_f64(jnp.where(live & buffers[0].validity,
                                    buffers[0].data, 0.0))
         nref = B.sum_int(jnp.where(live, buffers[1].data, jnp.int64(0)))

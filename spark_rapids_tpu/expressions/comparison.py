@@ -33,7 +33,7 @@ def _compare_data(lc: DeviceColumn, rc: DeviceColumn, op: str):
         ld = lc.data if lc.data.ndim > 1 else lift64(lc.data)
         rd = rc.data if rc.data.ndim > 1 else lift64(rc.data)
         # align scales before comparing unscaled values; the planner gates
-        # scale gaps > 9 (decimal_cmp_unsupported_reason)
+        # a rescale past 38 digits (decimal_cmp_unsupported_reason)
         ls, rs = lc.dtype.scale, rc.dtype.scale
         if ls < rs:
             ld = rescale_up(ld, 10 ** (rs - ls))
@@ -76,9 +76,6 @@ def decimal_cmp_unsupported_reason(lt, rt):
             return (f"comparing {small} to {big} rescales past the int64 "
                     f"unscaled range")
         return None
-    if diff > 9:
-        return (f"comparing {small} to {big}: scale gap {diff} exceeds the "
-                f"limb rescale budget (10^9)")
     if small.precision + diff > 38:
         return f"comparing {small} to {big} rescales past 38 digits"
     return None

@@ -283,6 +283,16 @@ class WindowAgg(WindowFunction):
     def bind(self, schema):
         return WindowAgg(self.agg.bind(schema))
 
+    def device_unsupported_reason(self):
+        # the frame reductions (exec/window.py) accumulate in int64 or
+        # double: no limb sums, no decimal division
+        name, t = type(self.agg).__name__, self.agg.dtype
+        if t.kind is TypeKind.DECIMAL and (
+                name == "Average" or (name == "Sum" and t.precision > 18)):
+            return (f"{name.lower()} over a window returning {t}: the "
+                    f"window frames have no decimal128 kernel")
+        return None
+
     @property
     def dtype(self):
         return self.agg.dtype

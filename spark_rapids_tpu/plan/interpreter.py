@@ -91,12 +91,41 @@ class RowEvaluator:
     def _num2(self, e, row):
         return self.eval(e.children[0], row), self.eval(e.children[1], row)
 
+    def _to_decimal_type(self, v, d):
+        """``v`` as ``d`` (HALF_UP at its scale); past its precision null,
+        or an error in ANSI mode: Spark's CheckOverflow / decimal cast."""
+        import decimal as _d
+        with _d.localcontext() as cx:
+            cx.prec = 100        # exact: the default context rounds at 28
+            q = _d.Decimal(v).quantize(_d.Decimal(1).scaleb(-d.scale),
+                                       rounding=_d.ROUND_HALF_UP)
+            if abs(q.scaleb(d.scale)) >= 10 ** d.precision:
+                if self.ansi:
+                    raise AnsiError(
+                        f"[ARITHMETIC_OVERFLOW] {v} cannot be represented "
+                        f"as {d} (ANSI mode)")
+                return None
+            return q
+
     def _arith(self, e, row, fn):
         l, r = self._num2(e, row)
         if l is None or r is None:
             return None
-        v = fn(l, r)
         d = e.dtype
+        if d.kind is TypeKind.DECIMAL:
+            import decimal as _d
+            if type(e).__name__ in ("Add", "Subtract"):
+                # Spark 3.3 casts both operands to the result type first
+                l, r = (self._to_decimal_type(x, d) for x in (l, r))
+                if l is None or r is None:
+                    return None
+            with _d.localcontext() as cx:
+                cx.prec = 100
+                v = fn(_d.Decimal(l), _d.Decimal(r))
+            return self._to_decimal_type(v, d)
+        if d.is_fractional:
+            l, r = float(l), float(r)     # a decimal operand enters as double
+        v = fn(l, r)
         if v is not None and d.kind in _INT_BITS:
             bits = _INT_BITS[d.kind]
             if self.ansi and not -(1 << (bits - 1)) <= int(v) \
@@ -160,6 +189,8 @@ class RowEvaluator:
         d = e.dtype
         if d.kind in _INT_BITS:
             return _wrap(-v, _INT_BITS[d.kind])
+        if d.kind is TypeKind.DECIMAL:
+            return v.copy_negate()    # ``-v`` rounds to the context's 28
         return -v
 
     def _eval_Abs(self, e, row):
@@ -169,6 +200,8 @@ class RowEvaluator:
         d = e.dtype
         if d.kind in _INT_BITS:
             return _wrap(abs(v), _INT_BITS[d.kind])
+        if d.kind is TypeKind.DECIMAL:
+            return v.copy_abs()
         return abs(v)
 
     def _eval_BitwiseOp(self, e, row):
@@ -1575,11 +1608,21 @@ class Interpreter:
                 return None
             if a.dtype.kind is TypeKind.DECIMAL:
                 import decimal as _d
-                q = _d.Decimal(1).scaleb(-a.dtype.scale)
+                # the exact sum (null past Spark's decimal(p+10, s)
+                # buffer), divided and rounded HALF_UP once
+                d, ct = a.dtype, a.children[0].dtype
                 with _d.localcontext() as cx:
-                    cx.prec = 38
-                    return (_d.Decimal(sum(nn)) / len(nn)).quantize(
-                        q, rounding=_d.ROUND_HALF_UP)
+                    cx.prec = 120
+                    total = sum(nn)
+                    if abs(total.scaleb(ct.scale)) >= \
+                            10 ** min(ct.precision + 10, 38):
+                        return None
+                    avg = (total / len(nn)).quantize(
+                        _d.Decimal(1).scaleb(-d.scale),
+                        rounding=_d.ROUND_HALF_UP)
+                    if abs(avg.scaleb(d.scale)) >= 10 ** d.precision:
+                        return None
+                    return avg
             return float(sum(nn)) / len(nn)
         if name == "First":
             return xs[0] if xs else None

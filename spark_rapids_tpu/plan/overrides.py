@@ -75,8 +75,11 @@ def _expr_rules() -> Dict[str, ExprRule]:
     # passthroughs admit every type that has a device layout
     for n in ("BoundReference", "UnresolvedColumn", "Literal", "Alias"):
         r(n, TS.ALL_BASIC + TS.DECIMAL_128 + TS.ARRAY + TS.MAP + TS.STRUCT)
+    # decimal operands of up to 38 digits: limb kernels
+    # (expressions/decimal128.py); a result whose type cuts the scale
+    # states its own reason (device_unsupported_reason)
     for n in ("Add", "Subtract", "Multiply", "UnaryMinus", "Abs"):
-        r(n, TS.NUMERIC)
+        r(n, TS.NUMERIC + TS.DECIMAL_128)
     for n in ("Divide", "IntegralDivide", "Remainder", "Pmod"):
         r(n, TS.NUMERIC)
     for n in ("BitwiseOp", "BitwiseNot"):
@@ -186,8 +189,9 @@ def _expr_rules() -> Dict[str, ExprRule]:
       note="answered exactly; sorted segments make exact as cheap as the sketch")
     for n in ("CollectList", "CollectSet"):
         r(n, TS.NUMERIC + TS.DATETIME + TS.BOOLEAN + TS.STRING)
-    r("Average", TS.NUMERIC,
-      note="float sums reassociate; parity kept by f64 accumulation")
+    r("Average", TS.NUMERIC + TS.DECIMAL_128,
+      note="float sums reassociate; parity kept by f64 accumulation; "
+           "decimals are exact (limb sum, HALF_UP division)")
     for n in ("StddevSamp", "StddevPop", "VarianceSamp", "VariancePop"):
         r(n, TS.FP)
     # collections + HOFs (reference: collectionOperations.scala,
@@ -611,14 +615,6 @@ class PlanMeta:
                 # mistyped trees (e.g. element_at over a scalar) raise in
                 # dtype; the per-param gate above already recorded why
                 kind = None
-            # sum over decimal widens to min(p+10, 38); DECIMAL128 limb
-            # storage (expressions/decimal128.py) covers the whole range
-            if name == "Average" and kind is TypeKind.DECIMAL:
-                p, s = child.dtype.precision, child.dtype.scale
-                self.will_not_work(
-                    f"avg over decimal({p},{s}) must return Spark's "
-                    f"decimal({min(p + 4, 38)},{min(s + 4, 38)}) with "
-                    f"HALF_UP rounding; the device buffer is double")
             if name in self._F64_HAZARD_AGGS and \
                     kind is TypeKind.FLOAT64 and \
                     not self.conf.incompatible_ops:

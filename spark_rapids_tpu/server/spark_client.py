@@ -1117,6 +1117,7 @@ UNSUPPORTED: Dict[str, str] = {
     # -- internal / structural (never arrive from Catalyst) --
     "AggregateFunction": "abstract base, never instantiated",
     "BinaryArithmetic": "abstract base, never instantiated",
+    "_AddSub": "abstract base (Add/Subtract are the concrete classes)",
     "BinaryComparison": "abstract base, never instantiated",
     "BinaryLogic": "abstract base, never instantiated",
     "WindowFunction": "abstract marker base, never instantiated",

@@ -447,6 +447,14 @@ class OperatorSpan:
             self._batches += 1
             a["batches"] = self._batches
             self._span.lazy_rows.append(batch.num_rows)
+            # decimal128 limb matrices this operator emitted and their
+            # device bytes (32 a value): shapes, known here without a sync
+            for c in batch.columns:
+                if getattr(c.data, "ndim", 0) == 2 \
+                        and c.dtype.kind.value == "decimal":
+                    a["dec128Columns"] = a.get("dec128Columns", 0) + 1
+                    a["dec128Bytes"] = a.get("dec128Bytes", 0) \
+                        + c.data.size * c.data.dtype.itemsize
 
     def note_programs(self, hits: int, misses: int) -> None:
         """Of the keyed programs this operator's exec stated when it was

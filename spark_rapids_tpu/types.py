@@ -11,9 +11,11 @@ Design notes (TPU-first):
   plus a length vector — TPU vector units want rectangular data; cudf's
   offsets+chars layout (reference GpuColumnVector.java) would force dynamic
   shapes through XLA.
-- Decimals with precision <= 18 are scaled int64 (DECIMAL64); wider decimals
-  are deferred (tagged unsupported, CPU fallback — same policy the reference
-  applies via TypeSig.DECIMAL_128 gating).
+- Decimals with precision <= 18 are scaled int64 (DECIMAL64); wider ones
+  (DECIMAL128) are four 32-bit limbs in int64 lanes
+  (expressions/decimal128.py). Arithmetic result types follow Spark 3.3's
+  DecimalPrecision with allowPrecisionLoss=true (``decimal_add_type``,
+  ``decimal_multiply_type``).
 - Dates are days-since-epoch int32; timestamps are microseconds-since-epoch
   int64 (Spark's internal representation, which is also MXU/VPU friendly).
 """
@@ -147,8 +149,8 @@ NULL = SqlType(TypeKind.NULL)
 
 
 def decimal(precision: int, scale: int) -> SqlType:
-    # precision > 18 (DECIMAL128) has no device storage yet; TypeSig's
-    # max_decimal_precision gates it to CPU fallback at planning time.
+    # precision > 18 (DECIMAL128) stores as limbs; an operator's TypeSig
+    # (max_decimal_precision) says whether it has a kernel for them.
     return SqlType(TypeKind.DECIMAL, precision=precision, scale=scale)
 
 
@@ -192,7 +194,8 @@ _NUM_ORDER = [TypeKind.INT8, TypeKind.INT16, TypeKind.INT32, TypeKind.INT64,
 def common_numeric_type(a: SqlType, b: SqlType) -> SqlType:
     """Tightest common numeric type for binary arithmetic (Spark promotion)."""
     if a.kind is TypeKind.DECIMAL or b.kind is TypeKind.DECIMAL:
-        # Simplified decimal promotion; exact Spark rules in expressions/decimal.
+        # Spark's WIDER decimal type (comparisons, coalesce); arithmetic
+        # results take decimal_add_type / decimal_multiply_type below
         if a.kind is TypeKind.DECIMAL and b.kind is TypeKind.DECIMAL:
             scale = max(a.scale, b.scale)
             prec = max(a.precision - a.scale, b.precision - b.scale) + scale
@@ -203,15 +206,58 @@ def common_numeric_type(a: SqlType, b: SqlType) -> SqlType:
             return FLOAT64
         if other.kind not in _INTEGRALS:
             raise TypeError(f"no common numeric type for {a}, {b}")
-        # Spark DecimalType.forType: int8->3, int16->5, int32->10, int64->20 digits.
-        digits = {TypeKind.INT8: 3, TypeKind.INT16: 5,
-                  TypeKind.INT32: 10, TypeKind.INT64: 20}[other.kind]
+        digits = _INTEGRAL_DIGITS[other.kind]
         prec = max(dec.precision - dec.scale, digits) + dec.scale
         return decimal(min(prec, 38), dec.scale)
     if not (a.is_numeric and b.is_numeric):
         raise TypeError(f"no common numeric type for {a}, {b}")
     ia, ib = _NUM_ORDER.index(a.kind), _NUM_ORDER.index(b.kind)
     return SqlType(_NUM_ORDER[max(ia, ib)])
+
+
+# ---- decimal arithmetic result types (Spark 3.3 DecimalPrecision) ---
+MAX_DECIMAL_PRECISION = 38
+_MIN_ADJUSTED_SCALE = 6
+# Spark DecimalType.forType: the decimal an integral operand is cast to
+_INTEGRAL_DIGITS = {TypeKind.INT8: 3, TypeKind.INT16: 5,
+                    TypeKind.INT32: 10, TypeKind.INT64: 20}
+
+
+def as_decimal(t: SqlType) -> Optional[SqlType]:
+    """The decimal type ``t`` enters decimal arithmetic as: itself, or an
+    integral's ``DecimalType.forType``; None for anything else."""
+    if t.kind is TypeKind.DECIMAL:
+        return t
+    if t.kind in _INTEGRAL_DIGITS:
+        return decimal(_INTEGRAL_DIGITS[t.kind], 0)
+    return None
+
+
+def adjust_precision_scale(precision: int, scale: int) -> SqlType:
+    """DecimalType.adjustPrecisionScale (allowPrecisionLoss=true): past 38
+    digits the integral digits are kept and the scale is cut, to no less
+    than min(scale, 6)."""
+    if precision <= MAX_DECIMAL_PRECISION:
+        return decimal(precision, scale)
+    int_digits = precision - scale
+    min_scale = min(scale, _MIN_ADJUSTED_SCALE)
+    return decimal(MAX_DECIMAL_PRECISION,
+                   max(MAX_DECIMAL_PRECISION - int_digits, min_scale))
+
+
+def decimal_add_type(a: SqlType, b: SqlType) -> SqlType:
+    """Result of ``a + b`` / ``a - b``: scale max(s1, s2), precision
+    max(p1 - s1, p2 - s2) + scale + 1, then adjusted."""
+    scale = max(a.scale, b.scale)
+    return adjust_precision_scale(
+        max(a.precision - a.scale, b.precision - b.scale) + scale + 1, scale)
+
+
+def decimal_multiply_type(a: SqlType, b: SqlType) -> SqlType:
+    """Result of ``a * b``: precision p1 + p2 + 1, scale s1 + s2, then
+    adjusted."""
+    return adjust_precision_scale(a.precision + b.precision + 1,
+                                  a.scale + b.scale)
 
 
 # ---- host<->device conversion helpers -------------------------------

@@ -162,10 +162,29 @@ def test_dec128_key_through_exchange():
 
 
 def test_dec128_arithmetic_falls_back():
+    """A product whose Spark type cuts the scale (decimal(77,8) adjusted
+    to decimal(38,6)) rounds inside the arithmetic: no device kernel."""
     assert_tpu_fallback_collect(
         lambda: table(wide_table()).select(
-            (col("w") + col("w")).alias("twice")),
+            (col("w") * col("w")).alias("squared")),
         "Project")
+
+
+def test_dec128_addition_runs_on_device():
+    """decimal(38,4) + decimal(38,4) keeps its scale (decimal(38,4)): the
+    limb kernel adds it, a sum past 38 digits null (the gate is gone)."""
+    s = Session()
+    t = wide_table()
+    got = s.collect(table(t).select((col("w") + col("w")).alias("twice")))
+    assert not s.fell_back(), s.fell_back()
+    with d.localcontext() as cx:
+        cx.prec = 60
+        want = [None if v is None else v + v
+                for v in t.column("w").to_pylist()]
+    assert got.column("twice").to_pylist() == want
+    assert_tpu_and_cpu_are_equal_collect(
+        lambda: table(wide_table()).select(
+            (col("w") + col("w")).alias("twice")))
 
 
 def test_sum_overflow_nulls():

@@ -205,7 +205,81 @@ def smoke_programs(cap):
         return (lambda d: _pallas_match_packed(d, b"special requests", 64)), \
             [jnp.zeros((cap >> 1, 128), jnp.uint8)]
 
+    def q1dec_execs():
+        """TPC-H Q1 at decimal(15,2) (benchmarks/queries/tpchdec/q1.py):
+        two limb multiplies, four limb sums, three decimal averages."""
+        import decimal
+        import pyarrow as pa
+        n = 64
+        money = {c: pa.array([decimal.Decimal(v)] * n, pa.decimal128(15, 2))
+                 for c, v in (("l_quantity", "17.00"),
+                              ("l_extendedprice", "1234.56"),
+                              ("l_discount", "0.05"), ("l_tax", "0.02"))}
+        t = pa.table(dict(
+            money, l_returnflag=pa.array(["A"] * n),
+            l_linestatus=pa.array(["F"] * n),
+            l_shipdate=pa.array([10000] * n, pa.int32()).cast(pa.date32())))
+        b0 = batch_of(t)
+        b = at_capacity(b0, b0.capacity, cap)
+        scan = InMemoryScanExec(t.slice(0, 16))
+        one = lit(decimal.Decimal("1"))
+        disc_price = col("l_extendedprice") * (one - col("l_discount"))
+        proj = ProjectExec(
+            [col("l_returnflag"), col("l_linestatus"), col("l_quantity"),
+             col("l_extendedprice"), col("l_discount"),
+             disc_price.alias("disc_price"),
+             (disc_price * (one + col("l_tax"))).alias("charge")], scan)
+        aggs = [Sum(col("l_quantity")).alias("sum_qty"),
+                Sum(col("l_extendedprice")).alias("sum_base_price"),
+                Sum(col("disc_price")).alias("sum_disc_price"),
+                Sum(col("charge")).alias("sum_charge"),
+                Average(col("l_quantity")).alias("avg_qty"),
+                Average(col("l_extendedprice")).alias("avg_price"),
+                Average(col("l_discount")).alias("avg_disc"),
+                Count().alias("count_order")]
+        keys = [col("l_returnflag"), col("l_linestatus")]
+        partial = HashAggregateExec(keys, aggs, proj, AggregateMode.PARTIAL)
+        final = HashAggregateExec(keys, aggs, partial, AggregateMode.FINAL)
+        return b, proj, partial, final
+
+    def q1dec_project():
+        b, proj, *_ = q1dec_execs()
+        return proj._kernel, [b, jnp.uint32(0)]
+
+    def q1dec_update():
+        b, proj, partial, _ = q1dec_execs()
+        projected = jax.eval_shape(
+            lambda x: proj._kernel(x, jnp.uint32(0))[0], b)
+        return partial._update_kernel, [projected]
+
+    def q1dec_merge(final, factor, rows=None):
+        # ``factor`` partials of ``rows`` rows (default: uncut, ``cap``)
+        b, proj, partial, fin = q1dec_execs()
+        projected = jax.eval_shape(
+            lambda x: proj._kernel(x, jnp.uint32(0))[0], b)
+        buf = jax.eval_shape(partial._update_kernel, projected)
+        big = at_capacity(buf, cap, (rows or cap) * factor)
+        return (lambda x: fin._merge_kernel(x, final=final)), [big]
+
+    def dec_mul(left_limbs):
+        from spark_rapids_tpu.expressions.decimal128 import mul128
+        a = jnp.zeros((cap, 4) if left_limbs else (cap,), jnp.int64)
+        return (lambda x, y: mul128(x, y, 38)), [a, jnp.zeros(cap, jnp.int64)]
+
+    def dec_avg():
+        from spark_rapids_tpu.expressions.decimal128 import div_half_up
+        slots = 1 << 12      # the aggregate's small group-slot layout
+        return (lambda t, n: div_half_up(t, 4, n, 19)), \
+            [jnp.zeros((slots, 4), jnp.int64), jnp.ones(slots, jnp.int64)]
+
     return {
+        "q1dec.project": q1dec_project,
+        "q1dec.agg_update": q1dec_update,
+        "q1dec.agg_merge_4x": lambda: q1dec_merge(False, 4),
+        "q1dec.agg_final_cut": lambda: q1dec_merge(True, 8, 16),
+        "dec.mul_64x64": lambda: dec_mul(False),
+        "dec.mul_128x64": lambda: dec_mul(True),
+        "dec.avg_half_up": dec_avg,
         "q1.filter": filter_kernel,
         "q1.project": project_kernel,
         "q1.agg_update": lambda: agg_update(q1_execs, 2),
