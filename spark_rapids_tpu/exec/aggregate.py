@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 
 from .. import types as T
-from ..batch import ColumnarBatch, DeviceColumn, Field, Schema, bucket_capacity
+from ..batch import MIN_CAPACITY, ColumnarBatch, DeviceColumn, Field, \
+    Schema, bucket_capacity
 from ..expressions.aggregates import AggregateFunction
 from ..expressions.aggregates import _cumsum as prefix_sum
 from ..expressions.base import Alias, EvalContext, Expression
@@ -192,12 +193,12 @@ class HashAggregateExec(UnaryExec):
 
         # Limb buffers (a decimal sum past 18 digits: 32 bytes a row, six
         # f64 chunk lanes in the merge's stack) weigh several times what a
-        # double buffer does: a merge of four 2^20-row partials no longer
-        # fits the chip (22.7 GB of temporaries for TPC-H Q1's seven limb
-        # sums, tools/aot_compile.py's method). So such an exec, told by
-        # its buffer types alone, cuts each partial to its group count's
-        # bucket as soon as it is made (one host read of the count a
-        # batch) and merges a quarter of the rows at a time.
+        # double buffer does: a merge window of 2^22 rows does not fit the
+        # chip (22.7 GB of temporaries for TPC-H Q1's seven limb sums,
+        # tools/aot_compile.py's method). So such an exec, told by its
+        # buffer types alone, merges a quarter of the rows at a time, and
+        # its programs carry another name. (Every exec cuts its partials
+        # to their groups' bucket: do_execute_partition.)
         from ..expressions.decimal128 import is_dec128
         self._wide_buffers = any(is_dec128(f.dtype)
                                  for f in self.buffer_fields)
@@ -551,6 +552,7 @@ class HashAggregateExec(UnaryExec):
         # registrations and the merge passes run under the OOM retry loop
         # (no split: re-ordering partial merges would change float
         # accumulation order — spill-and-retry keeps results bit-for-bit)
+        from .. import trace as qtrace
         from ..memory import (SpillableBatch, device_budget,
                               register_with_retry)
         cat = device_budget()
@@ -575,37 +577,78 @@ class HashAggregateExec(UnaryExec):
                     raw, bucket_capacity(sum(b.capacity for b in raw)))
             yield self._eval_buffers_jit(self._update_jit(whole))
             return
-        for batch in self.child.execute_partition(p):
-            if self.mode in (AggregateMode.PARTIAL, AggregateMode.COMPLETE):
-                part = self._update_jit(batch)
-            else:
-                part = batch
-            if self._wide_buffers:
-                out_cap = bucket_capacity(max(int(part.num_rows), 1))
-                if out_cap < part.capacity:
-                    part = self._slice_compact(part, out_cap)
+
+        def register(part):
+            # a partial is merged at the capacity bucket of the groups it
+            # HOLDS, not of the batch it came from: padding rows carry no
+            # value, and _merge_and_emit concatenates, sorts and scans
+            # whatever capacity is recorded here. A partial whose bucket
+            # is its capacity (groups ~ rows) is left exactly as it is.
+            made = int(part.capacity)
+            part = self._cut_to_groups(part)
             # registered handles start unpinned (spillable)
             spillables.append((register_with_retry(part, buf_schema,
                                                    catalog=cat,
                                                    name=self.name),
                                int(part.capacity)))
-
-        finalize = self.mode in (AggregateMode.FINAL, AggregateMode.COMPLETE)
-        if not spillables:
-            if not self.key_fields and p == 0:
-                # global aggregate over empty input still yields one row
-                from ..batch import empty_batch
-                seed = empty_batch(Schema(self.key_fields + self.buffer_fields))
-                out = self._final_jit(seed) if finalize else self._merge_jit(seed)
-                yield out
-            return
+            qtrace.count(partialsCut=int(part.capacity < made),
+                         partialRowsMade=made,
+                         partialRowsKept=int(part.capacity))
 
         try:
+            self._each_partial(p, register)
+            finalize = self.mode in (AggregateMode.FINAL,
+                                     AggregateMode.COMPLETE)
+            if not spillables:
+                if not self.key_fields and p == 0:
+                    # global aggregate over empty input still yields one row
+                    from ..batch import empty_batch
+                    seed = empty_batch(buf_schema)
+                    yield self._final_jit(seed) if finalize \
+                        else self._merge_jit(seed)
+                return
             yield from self._merge_and_emit(spillables, finalize, cat,
                                             buf_schema)
         finally:
+            # (also what a failed registration leaves behind: the handles
+            # registered before it)
             for sb, _ in spillables:
                 sb.close()
+
+    def _each_partial(self, p: int, register) -> None:
+        """Hand ``register`` every partial of the partition, in batch
+        order, each ONE BATCH LATE: the group count that ``register``
+        reads waits for the update it belongs to, so batch k+1 is pulled
+        and its update dispatched before partial k is handed over, and
+        the device keeps one update in flight. Only the newest partial is
+        ever unregistered behind the one in flight; the last is flushed
+        after the loop."""
+        updating = self.mode in (AggregateMode.PARTIAL,
+                                 AggregateMode.COMPLETE)
+        newest = None
+        for batch in self.child.execute_partition(p):
+            part = self._update_jit(batch) if updating else batch
+            if newest is not None:
+                register(newest)
+            newest = part
+        if newest is not None:
+            register(newest)
+
+    def _held_rows(self, batch: ColumnarBatch) -> int:
+        """The rows a batch holds, on the host: waits for the program
+        that made the batch."""
+        return int(batch.num_rows)
+
+    def _cut_to_groups(self, batch: ColumnarBatch) -> ColumnarBatch:
+        """Slice a buffer-layout batch to the capacity bucket of the rows
+        it holds (rows are compact from 0: one group a row). A batch at
+        the smallest bucket is not even read."""
+        if batch.capacity <= MIN_CAPACITY:
+            return batch
+        out_cap = bucket_capacity(max(self._held_rows(batch), 1))
+        if out_cap < batch.capacity:
+            batch = self._slice_compact(batch, out_cap)
+        return batch
 
     def _merge_and_emit(self, entries, finalize, cat, buf_schema):
         """Merge spilled partials WITHOUT ever acquiring more than
@@ -670,12 +713,8 @@ class HashAggregateExec(UnaryExec):
 
                 def window_merge(grp=grp, cap_sum=cap_sum):
                     batches = _acquire_group(grp)
-                    merged = self._merge_jit(
-                        concat_batches(batches, bucket_capacity(cap_sum)))
-                    n = int(merged.num_rows)
-                    out_cap = bucket_capacity(max(n, 1))
-                    if out_cap < merged.capacity:
-                        merged = self._slice_compact(merged, out_cap)
+                    merged = self._cut_to_groups(self._merge_jit(
+                        concat_batches(batches, bucket_capacity(cap_sum))))
                     for sb, _ in grp:
                         sb.done_with()
                     return merged
@@ -706,7 +745,6 @@ class HashAggregateExec(UnaryExec):
         chunked merge tree, then bounded per-chunk merges. Only the boundary
         group can span chunks, so it is carried forward and every other
         group is emitted as soon as its chunk is merged."""
-        from ..batch import MIN_CAPACITY
         from ..expressions.base import BoundReference
         from .common import slice_batch
         from .ooc_sort import OutOfCoreSorter

@@ -403,6 +403,19 @@ def span(name: str, kind: str = "span", **attrs):
     return _SpanCm(st, name, kind, attrs)
 
 
+def count(**counters: int) -> None:
+    """Add to counters on the innermost span or pull frame of the calling
+    thread: inside an exec's ``do_execute_partition``, between its
+    children's pulls, that is the exec's own operator span. One
+    thread-local read with no active trace."""
+    st = getattr(_TLS, "st", None)
+    if st is None or not st.stack:
+        return
+    a = st.stack[-1].attrs
+    for name, n in counters.items():
+        a[name] = a.get(name, 0) + n
+
+
 class OperatorSpan:
     """One partition's iteration of one operator. The span runs from the
     first pull to exhaustion, so it also covers what the consumer does

@@ -86,14 +86,17 @@ def test_sorting_program_holds_one_sort(name, one_chip, no_persistent_cache):
 
 @pytest.mark.parametrize("name", ["dec.mul_64x64", "dec.mul_128x64",
                                   "dec.avg_half_up", "q1dec.project",
-                                  "q1dec.agg_final_cut"])
+                                  "q1dec.agg_final_cut",
+                                  "q1.agg_final_cut"])
 def test_decimal128_kernel_compiles_for_v5e(name, one_chip,
                                             no_persistent_cache):
     """The limb kernels lean on what the chip emulates: uint64 multiplies
     of 32-bit digits, 64-bit shifts and compares, a 32-step ``fori_loop``
     a digit in the HALF_UP division. TPC-H Q1's decimal project (two limb
     multiplies) and its final merge over partials cut to their groups are
-    the served path's own programs."""
+    the served path's own programs; the double Q1 merges at the same cut
+    (every aggregate cuts its partials: eight of 128 rows, not four of
+    2^20), which has to stay as small."""
     out = _compile(name, one_chip)
     assert out["temp_bytes"] < 1 << 30, out
 

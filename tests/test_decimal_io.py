@@ -134,12 +134,13 @@ def test_nested_decimal_elements_round_trip():
         t.column("a").to_pylist()
 
 
-def test_scan_span_and_dec128_counters(tmp_path):
+def test_scan_span_and_dec128_counters(tmp_path, capsys):
     """A traced scan of decimal columns opens ``scan.h2d.decimal`` inside
     ``scan.h2d`` (values, columns, bytes), and an operator that emits limb
     matrices counts them (``dec128Columns``, ``dec128Bytes``)."""
     import pyarrow.parquet as pq
     from spark_rapids_tpu.expressions import col, lit
+    from spark_rapids_tpu.expressions.aggregates import Sum
     from spark_rapids_tpu import trace as qtrace
     from spark_rapids_tpu.io.parquet import ParquetSource
     from spark_rapids_tpu.plan import Session
@@ -189,6 +190,24 @@ def test_scan_span_and_dec128_counters(tmp_path):
     name = proj[0]["name"]
     assert rows[name]["dec128Columns"] == 1
     assert rows[name]["dec128Bytes"] == cap * 4 * 8
+    # ... and an aggregate's partials: how many were cut to their groups'
+    # bucket, and the capacities before and after (4 groups of one 512-row
+    # batch: the partial aggregate cuts its partial to 128 rows, and the
+    # final one, handed those 128, has nothing to cut)
+    ses.collect(df.select((col("k") % lit(4)).alias("g"), col("p"))
+                .group_by("g").agg(Sum(col("p")).alias("s")))
+    profile = qtrace.flight_recorder().profiles(ses.last_query_id)[0]
+    rows = {r["name"]: r for r in viewer.self_time_table(profile)}
+    agg = rows["HashAggregateExec"]
+    assert (agg["spans"], agg["partialsCut"]) == (2, 1)
+    assert (agg["partialRowsMade"], agg["partialRowsKept"]) == \
+        (cap + 128, 128 + 128)
+    viewer.print_tables([profile])
+    header, *lines = capsys.readouterr().out.splitlines()[1:]
+    assert header.split()[-6:] == ["partials", "cut", "rows", "made",
+                                   "rows", "kept"]
+    line = [ln for ln in lines if ln.startswith("HashAggregateExec")][0]
+    assert line.split()[-3:] == ["1", str(cap + 128), "256"]
 
 
 def test_row_group_reader_keeps_the_codes_hand_off_beside_decimals(

@@ -287,3 +287,246 @@ def test_float_sum_small_group_after_large_magnitudes():
                              scan(t), AggregateMode.COMPLETE)
     got = {r[0]: r[1] for r in rows_of(collect(plan))}
     assert abs(got[1] - 2e-10) < 1e-16, got
+
+
+# ---------------------------------------------------------------------------
+# Partials are merged at the capacity bucket of the groups they hold
+# (ISSUE 32): chosen from each partial's observed row count and from nothing
+# else, in every mode and for every buffer type
+# ---------------------------------------------------------------------------
+
+_CUT_CAP = 4096     # capacity of every batch that reaches the aggregate
+
+#: case -> the group counts of its batches (None: every row its own group)
+_CUT_CASES = {
+    "few_groups": [4, 4, 4],
+    "groups_are_rows": [None, None, None],
+    "power_of_two": [256, 256, 256],
+    "power_of_two_plus_one": [257, 257, 257],
+    "empty_batch": [4, 0, 4],
+    "single_batch": [4],
+}
+
+
+def _cut_values(buffers, n):
+    import decimal
+    import numpy as np
+    import pyarrow as pa
+    q = (np.arange(n) * 7) % 1001 - 500         # exact in every type
+    if buffers == "double":
+        return pa.array(q * 0.25, pa.float64())
+    if buffers == "int64":
+        return pa.array(q, pa.int64())
+    return pa.array([decimal.Decimal(int(x)).scaleb(-2) for x in q],
+                    pa.decimal128(15, 2))
+
+
+def _cut_aggs(buffers):
+    if buffers == "int64":
+        return [Sum(col("v")).alias("s"), Min(col("v")).alias("mn"),
+                Max(col("v")).alias("mx"), Count().alias("c")]
+    return [Sum(col("v")).alias("s"), Count(col("v")).alias("c")]
+
+
+def _cut_tables(buffers, case):
+    """One Arrow table a batch: ``groups`` keys dealt round-robin over a
+    full batch of rows (an empty table for 0 groups)."""
+    import numpy as np
+    import pyarrow as pa
+    tables = []
+    for groups in _CUT_CASES[case]:
+        n = 0 if groups == 0 else _CUT_CAP
+        k = np.arange(n) % (groups or _CUT_CAP)
+        tables.append(pa.table({"k": pa.array(k, pa.int64()),
+                                "v": _cut_values(buffers, n)}))
+    return tables
+
+
+def _watch_merge(agg, monkeypatch):
+    """Record what ``agg`` hands its merge: the registered capacities, and
+    every call of a merge program."""
+    seen = {"capacities": None, "merges": 0}
+    real = agg._merge_and_emit
+
+    def spy(entries, *a, **k):
+        seen["capacities"] = [c for _, c in entries]
+        return real(entries, *a, **k)
+    monkeypatch.setattr(agg, "_merge_and_emit", spy)
+    for role in ("_merge_jit", "_final_jit"):
+        def counted(b, _real=getattr(agg, role)):
+            seen["merges"] += 1
+            return _real(b)
+        monkeypatch.setattr(agg, role, counted)
+    return seen
+
+
+@pytest.mark.parametrize("case", list(_CUT_CASES))
+@pytest.mark.parametrize("buffers", ["double", "int64", "decimal_limbs"])
+@pytest.mark.parametrize("mode", [AggregateMode.PARTIAL,
+                                  AggregateMode.COMPLETE,
+                                  AggregateMode.FINAL])
+def test_partials_are_merged_at_their_groups_bucket(mode, buffers, case,
+                                                    monkeypatch):
+    import pyarrow as pa
+    from spark_rapids_tpu.batch import (MIN_CAPACITY, bucket_capacity,
+                                        from_arrow, schema_from_arrow)
+    from spark_rapids_tpu.plan import Session, table
+    tables = _cut_tables(buffers, case)
+    schema = schema_from_arrow(tables[0].schema)
+    raw = [from_arrow(t, capacity=_CUT_CAP, schema=schema)[0]
+           for t in tables]
+    keys, aggs = [col("k")], _cut_aggs(buffers)
+    if mode is AggregateMode.COMPLETE:
+        plan = watched = HashAggregateExec(
+            keys, aggs, InMemoryScanExec(raw, schema), mode)
+    else:
+        partial = HashAggregateExec(keys, aggs,
+                                    InMemoryScanExec(raw, schema),
+                                    AggregateMode.PARTIAL)
+        if mode is AggregateMode.PARTIAL:
+            watched = partial
+            plan = HashAggregateExec(keys, aggs, partial,
+                                     AggregateMode.FINAL)
+        else:
+            # FINAL over buffer batches as whoever made them sized them:
+            # uncut, at the scan batch's capacity
+            bufs = [partial._update_jit(b) for b in raw]
+            assert all(b.capacity == _CUT_CAP for b in bufs)
+            bound = [a.alias(n)
+                     for a, n in zip(partial.aggs, partial.agg_names)]
+            plan = watched = HashAggregateExec(
+                keys, bound, InMemoryScanExec(bufs, partial.output_schema),
+                AggregateMode.FINAL)
+    seen = _watch_merge(watched, monkeypatch)
+    got = collect(plan)
+
+    want_caps = [bucket_capacity(_CUT_CAP if g is None else max(g, 1))
+                 for g in _CUT_CASES[case]]
+    assert seen["capacities"] == want_caps
+    if case == "groups_are_rows":
+        assert want_caps == [_CUT_CAP] * 3          # left as they were
+    if case == "empty_batch":
+        assert want_caps[1] == MIN_CAPACITY
+    # every case fits one window: ONE merge, no windowed pre-merge pass
+    assert sum(want_caps) <= watched.max_result_rows
+    assert seen["merges"] == 1
+
+    cpu = Session({"spark.rapids.tpu.sql.enabled": False})
+    want = cpu.collect(table(pa.concat_tables(tables)).group_by("k")
+                       .agg(*_cut_aggs(buffers)))
+    assert got.schema.types == want.schema.types
+    assert_rows_equal(rows_of(got), rows_of(want), ignore_order=True,
+                      approx_float=False)
+
+
+def test_group_count_is_read_one_batch_late(monkeypatch):
+    """The count of partial k is a host read that waits for its update, so
+    batch k+1's update is dispatched BEFORE it: the device keeps one update
+    in flight. The last partial is flushed after the loop, and partials
+    are registered in batch order."""
+    from spark_rapids_tpu.batch import from_arrow, schema_from_arrow
+    tables = _cut_tables("int64", "few_groups") + \
+        _cut_tables("int64", "single_batch")
+    schema = schema_from_arrow(tables[0].schema)
+    raw = [from_arrow(t, capacity=_CUT_CAP, schema=schema)[0]
+           for t in tables]
+    plan = HashAggregateExec([col("k")], _cut_aggs("int64"),
+                             InMemoryScanExec(raw, schema),
+                             AggregateMode.COMPLETE)
+    calls, made = [], []
+    real_update, real_read = plan._update_jit, plan._held_rows
+
+    def update(batch):
+        calls.append(("update", len(made)))
+        made.append(real_update(batch))
+        return made[-1]
+
+    def read(batch):
+        calls.extend(("read", i) for i, m in enumerate(made) if batch is m)
+        return real_read(batch)
+    monkeypatch.setattr(plan, "_update_jit", update)
+    monkeypatch.setattr(plan, "_held_rows", read)
+    got = rows_of(collect(plan))
+    assert calls == [("update", 0), ("update", 1), ("read", 0),
+                     ("update", 2), ("read", 1), ("update", 3),
+                     ("read", 2), ("read", 3)]
+    assert [r[-1] for r in got] == [_CUT_CAP] * 4    # rows a group
+
+
+@pytest.mark.parametrize("ooms", [1, 50])
+def test_oom_between_cut_and_registration_loses_no_partial(ooms):
+    """A partial that has been cut is registered under the retry loop: one
+    injected OOM at its reservation is retried and the partial is there,
+    result unchanged; an OOM that outlasts the retries fails the query and
+    leaves NOTHING of this exec in the catalog (the partials registered
+    before it are closed, the cut one was never registered)."""
+    from spark_rapids_tpu.batch import from_arrow, schema_from_arrow
+    from spark_rapids_tpu.memory import device_budget
+    from spark_rapids_tpu.memory.retry import oom_injection
+    tables = _cut_tables("int64", "few_groups")
+    schema = schema_from_arrow(tables[0].schema)
+    raw = [from_arrow(t, capacity=_CUT_CAP, schema=schema)[0]
+           for t in tables]
+    plan = HashAggregateExec([col("k")], _cut_aggs("int64"),
+                             InMemoryScanExec(raw, schema),
+                             AggregateMode.COMPLETE)
+    cat = device_budget()
+    before = cat.leak_check()
+    # the first reservation is partial 0's registration; the second,
+    # partial 1's, is the one that fails
+    with oom_injection("every-1", skip_count=1, oom_count=ooms) as inj:
+        if ooms == 1:
+            got = rows_of(collect(plan))
+            assert [r[-1] for r in got] == [3 * _CUT_CAP // 4] * 4
+        else:
+            with pytest.raises(MemoryError):
+                collect(plan)
+        assert inj.injected >= 1
+    assert cat.leak_check() == before
+
+
+@pytest.mark.parametrize("keys, cut", [(("f", "s"), True), (("k",), False)])
+def test_partial_counters_on_the_operator_span(keys, cut, tmp_path):
+    """A traced query's aggregate counts, on its operator span, the
+    partials it cut and their summed capacities before and after: a tiny
+    TPC-H Q1 of n batches cuts all n down to MIN_CAPACITY; an aggregate
+    whose groups are its rows cuts none."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    from spark_rapids_tpu import trace as qtrace
+    from spark_rapids_tpu.batch import MIN_CAPACITY, bucket_capacity
+    from spark_rapids_tpu.io.parquet import ParquetSource
+    from spark_rapids_tpu.plan import Session
+    from spark_rapids_tpu.plan.logical import DataFrame, LogicalScan
+    n, rows = 3, 300
+    paths = []
+    for i in range(n):
+        t = pa.table({
+            "f": pa.array(["A", "N", "R", "N"] * (rows // 4)),
+            "s": pa.array(["F", "O"] * (rows // 2)),
+            "q": pa.array([float(j) for j in range(rows)]),
+            "k": pa.array(list(range(i * rows, (i + 1) * rows)),
+                          pa.int64())})
+        paths.append(str(tmp_path / f"part-{i}.parquet"))
+        pq.write_table(t, paths[-1])
+    src = ParquetSource(paths)
+    df = DataFrame(LogicalScan((), source=src, _schema=src.schema()))
+    ses = Session({"spark.rapids.tpu.trace.enabled": "true",
+                   "spark.rapids.tpu.sql.incompatibleOps.enabled": "true"})
+    got = ses.collect(df.group_by(*keys).agg(Sum(col("q")).alias("sq"),
+                                             Count().alias("c")))
+    assert not ses.fell_back()
+    assert got.num_rows == (3 if cut else n * rows)
+    spans = qtrace.flight_recorder().profiles(ses.last_query_id)[0]["spans"]
+    aggs = [s for s in spans if s["name"] == "HashAggregateExec"]
+    # the partial aggregate is the final one's child
+    partial = [s for s in aggs
+               if s["parent"] in {a["id"] for a in aggs}][0]["attrs"]
+    cap = bucket_capacity(rows)
+    assert partial["partialRowsMade"] == n * cap
+    if cut:
+        assert partial["partialsCut"] == n
+        assert partial["partialRowsKept"] == n * MIN_CAPACITY
+    else:
+        assert partial["partialsCut"] == 0
+        assert partial["partialRowsKept"] == partial["partialRowsMade"]

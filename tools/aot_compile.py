@@ -136,13 +136,15 @@ def smoke_programs(cap):
         b, *rest = execs()
         return rest[which]._update_kernel, [b]
 
-    def agg_merge(execs, which, final, factor):
-        # partials keep the scan batch's capacity; the windowed pre-merge
-        # concatenates max_result_rows (4M) of them at a time
+    def agg_merge(execs, which, final, factor, rows=None):
+        # ``factor`` partials of ``rows`` rows: cut to their groups'
+        # bucket, or (default) as many groups as the scan batch has rows,
+        # ``cap``, which the windowed pre-merge concatenates
+        # max_result_rows (4M) at a time
         b, *rest = execs()
         partial = rest[-2]
         buf = jax.eval_shape(partial._update_kernel, b)
-        big = at_capacity(buf, cap, cap * factor)
+        big = at_capacity(buf, cap, (rows or cap) * factor)
         agg = rest[which]
         return (lambda x: agg._merge_kernel(x, final=final)), [big]
 
@@ -285,6 +287,7 @@ def smoke_programs(cap):
         "q1.agg_update": lambda: agg_update(q1_execs, 2),
         "q1.agg_merge_4x": lambda: agg_merge(q1_execs, 2, False, 4),
         "q1.agg_final": lambda: agg_merge(q1_execs, 3, True, 1),
+        "q1.agg_final_cut": lambda: agg_merge(q1_execs, 3, True, 8, 128),
         "q2.agg_update": lambda: agg_update(q2_execs, 0),
         "q2.agg_merge_4x": lambda: agg_merge(q2_execs, 0, False, 4),
         "q2.agg_final_4x": lambda: agg_merge(q2_execs, 1, True, 4),

@@ -119,6 +119,12 @@ def to_trace_events(profiles: Iterable[dict],
     return events
 
 
+#: span attributes that are counts: a name's row sums them over its spans
+_SUMMED = ("pulls", "lowerings", "programHits", "programMisses",
+           "dec128Columns", "dec128Bytes",
+           "partialsCut", "partialRowsMade", "partialRowsKept")
+
+
 def self_time_table(profile: dict) -> List[dict]:
     """One row per span name of one profile, largest own time first:
     spans, their time inside (an operator's pulls, any other span's
@@ -127,7 +133,10 @@ def self_time_table(profile: dict) -> List[dict]:
     the milliseconds of ``jit.*`` spans directly below), how many of
     their execs' keyed programs the program table already had, and the
     decimal128 limb columns they emitted with their device bytes
-    (``dec128Columns`` / ``dec128Bytes``)."""
+    (``dec128Columns`` / ``dec128Bytes``), and the aggregates' partials:
+    how many were cut to their groups' bucket and the summed capacities
+    before and after (``partialsCut`` / ``partialRowsMade`` /
+    ``partialRowsKept``)."""
     import os
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), os.pardir))
@@ -140,18 +149,12 @@ def self_time_table(profile: dict) -> List[dict]:
         attrs = s.get("attrs") or {}
         r = rows.setdefault(s["name"], {
             "name": s["name"], "spans": 0, "insideMs": 0.0, "selfMs": 0.0,
-            "pulls": 0, "lowerings": 0, "relowerMs": 0.0,
-            "programHits": 0, "programMisses": 0,
-            "dec128Columns": 0, "dec128Bytes": 0})
+            "relowerMs": 0.0, **dict.fromkeys(_SUMMED, 0)})
         r["spans"] += 1
         r["insideMs"] += inside_us(s) / 1000.0
         r["selfMs"] += own[s["id"]] / 1000.0
-        r["pulls"] += int(attrs.get("pulls", 0))
-        r["lowerings"] += int(attrs.get("lowerings", 0))
-        r["programHits"] += int(attrs.get("programHits", 0))
-        r["programMisses"] += int(attrs.get("programMisses", 0))
-        r["dec128Columns"] += int(attrs.get("dec128Columns", 0))
-        r["dec128Bytes"] += int(attrs.get("dec128Bytes", 0))
+        for k in _SUMMED:
+            r[k] += int(attrs.get(k, 0))
         parent = name_of.get(s.get("parent"))
         if s["name"].startswith("jit.") and parent in rows \
                 and not parent.startswith("jit."):
@@ -169,13 +172,16 @@ def print_tables(profiles: Iterable[dict],
               f"{(prof.get('durUs') or 0) / 1000.0:.1f} ms")
         print(f"{'span':<34}{'n':>4}{'inside ms':>12}{'self ms':>12}"
               f"{'pulls':>7}{'lowerings':>10}{'relower ms':>12}"
-              f"{'hit/miss':>10}{'dec128 cols':>12}{'dec128 bytes':>14}")
+              f"{'hit/miss':>10}{'dec128 cols':>12}{'dec128 bytes':>14}"
+              f"{'partials cut':>13}{'rows made':>11}{'rows kept':>11}")
         for r in self_time_table(prof):
             print(f"{r['name']:<34}{r['spans']:>4}{r['insideMs']:>12.1f}"
                   f"{r['selfMs']:>12.1f}{r['pulls']:>7}"
                   f"{r['lowerings']:>10}{r['relowerMs']:>12.1f}"
                   f"{str(r['programHits']) + '/' + str(r['programMisses']):>10}"
-                  f"{r['dec128Columns']:>12}{r['dec128Bytes']:>14}")
+                  f"{r['dec128Columns']:>12}{r['dec128Bytes']:>14}"
+                  f"{r['partialsCut']:>13}{r['partialRowsMade']:>11}"
+                  f"{r['partialRowsKept']:>11}")
 
 
 def main(argv=None) -> int:
