@@ -81,6 +81,17 @@ class Exec:
         child's; exchanges define their own."""
         return self.children[0].num_partitions if self.children else 1
 
+    @property
+    def planned_partitions(self) -> int:
+        """The partition count the PLAN states, for the planner to ask:
+        it reads plan facts only and runs nothing, where ``num_partitions``
+        (what executing parents call) may materialize an exchange to
+        answer. It is over 1 wherever ``num_partitions`` can be (run-time
+        coalescing only lowers a count, a stood-aside exchange has one).
+        An exec that overrides ``num_partitions`` states this rule beside
+        it."""
+        return self.children[0].planned_partitions if self.children else 1
+
     def do_execute(self) -> Iterator[ColumnarBatch]:
         """All partitions chained (single-stream consumers / collect)."""
         for p in range(self.num_partitions):

@@ -87,6 +87,8 @@ class InMemoryScanExec(LeafExec):
     def num_partitions(self) -> int:
         return self._num_slices
 
+    planned_partitions = num_partitions    # a plan fact
+
     def _upload_batches(self):
         from ..memory.retry import maybe_inject, with_retry_no_split
         from ..trace import span
@@ -290,6 +292,8 @@ class GlobalLimitExec(LocalLimitExec):
     def num_partitions(self) -> int:
         return 1
 
+    planned_partitions = num_partitions    # a plan fact
+
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
         remaining = self.limit
         for cp in range(self.child.num_partitions):
@@ -314,6 +318,10 @@ class UnionExec(Exec):
     @property
     def num_partitions(self) -> int:
         return sum(c.num_partitions for c in self.children)
+
+    @property
+    def planned_partitions(self) -> int:
+        return sum(c.planned_partitions for c in self.children)
 
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
         for c in self.children:

@@ -249,6 +249,8 @@ class FusedStageExec(LeafExec):
     def num_partitions(self) -> int:
         return 1
 
+    planned_partitions = num_partitions    # a plan fact
+
     def do_execute_partition(self, p: int):
         yield self.stage.run()
 

@@ -689,6 +689,18 @@ class HashJoinExec(BinaryExec):
             return 1
         return self.left.num_partitions
 
+    @property
+    def planned_partitions(self) -> int:
+        """The same rule from plan facts: a PLANNED broadcast build (the
+        run-time switch never takes RIGHT/FULL outer), the left child's
+        planned count, and the skew split, which can cut even a single
+        map-output partition into several reader partitions."""
+        if (self._planned_broadcast and
+                self.join_type in (JoinType.RIGHT_OUTER, JoinType.FULL_OUTER)):
+            return 1
+        n = self.left.planned_partitions
+        return max(n, 2) if self.skew_split_rows else n
+
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
         self._maybe_coordinate()
         if self.broadcast_build and not self._planned_broadcast:
@@ -981,6 +993,12 @@ class BroadcastNestedLoopJoinExec(BinaryExec):
         if self.join_type in (JoinType.RIGHT_OUTER, JoinType.FULL_OUTER):
             return 1
         return self.left.num_partitions
+
+    @property
+    def planned_partitions(self) -> int:
+        if self.join_type in (JoinType.RIGHT_OUTER, JoinType.FULL_OUTER):
+            return 1
+        return self.left.planned_partitions
 
     def _build_tiles(self, build: ColumnarBatch, stream_cap: int):
         """(offset, piece) tiles of the build side bounded so one

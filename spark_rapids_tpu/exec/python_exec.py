@@ -240,10 +240,6 @@ class CoGroupInPandasExec(Exec):
     def output_schema(self) -> Schema:
         return self._schema
 
-    @property
-    def num_partitions(self) -> int:
-        return self.children[0].num_partitions
-
     @staticmethod
     def _norm_key(k) -> Tuple:
         """Group keys as dict keys: NaN objects are identity-hashed in

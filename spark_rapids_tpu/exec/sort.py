@@ -96,6 +96,10 @@ class SortExec(UnaryExec):
     def num_partitions(self) -> int:
         return 1 if self.global_sort else self.child.num_partitions
 
+    @property
+    def planned_partitions(self) -> int:
+        return 1 if self.global_sort else self.child.planned_partitions
+
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
         if not self.global_sort:
             for b in self.child.execute_partition(p):
@@ -204,6 +208,8 @@ class TakeOrderedAndProjectExec(UnaryExec):
     @property
     def num_partitions(self) -> int:
         return 1
+
+    planned_partitions = num_partitions    # a plan fact
 
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
         best: Optional[ColumnarBatch] = None

@@ -78,6 +78,8 @@ class InMemoryRelationExec(LeafExec):
     def num_partitions(self) -> int:
         return self.cached.num_partitions
 
+    planned_partitions = num_partitions    # a plan fact
+
     def do_execute_partition(self, p: int):
         t = self.cached.read_partition(p)
         if t is None or t.num_rows == 0:

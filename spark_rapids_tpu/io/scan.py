@@ -68,6 +68,8 @@ class FileSourceScanExec(LeafExec):
     def num_partitions(self) -> int:
         return self._num_slices
 
+    planned_partitions = num_partitions    # a plan fact
+
     def _files_for(self, p: int) -> List[str]:
         return [f for i, f in enumerate(self.files)
                 if i % self._num_slices == p]

@@ -495,6 +495,8 @@ class MeshStageExec(LeafExec):
     def num_partitions(self) -> int:
         return self.lowering.n_dev
 
+    planned_partitions = num_partitions    # a plan fact
+
     # ------------------------------------------------------------------
 
     def _stack_input(self, e: Exec) -> ColumnarBatch:

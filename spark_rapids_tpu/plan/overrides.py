@@ -772,19 +772,25 @@ def insert_coalesce_transitions(plan: Exec, target_bytes: int,
         new_children = []
         for i, c in enumerate(node.children):
             c = rewrite(c)
-            # declaration-driven (each exec states its CoalesceGoal —
-            # the reference's GpuCoalesceBatches goal contract)
             goal = node.coalesce_goal_for_child(i)
-            if isinstance(goal, RequireSingleBatch) and \
-                    not c.produces_single_batch:
-                c = CoalesceBatchesExec(c, goal, max_rows=max_rows)
-            elif isinstance(goal, TargetSize) and \
-                    isinstance(c, fragmenting):
-                c = CoalesceBatchesExec(c, TargetSize(target_bytes),
-                                        max_rows=max_rows)
-            new_children.append(c)
+            if getattr(c, "aside", None) is not None:
+                # an exchange that may stand aside then hands this
+                # consumer what it would be given without the exchange
+                c.aside = meet(goal, c.child)
+            new_children.append(meet(goal, c))
         node.children = tuple(new_children)
         return node
+
+    def meet(goal, c: Exec) -> Exec:
+        # declaration-driven (each exec states its CoalesceGoal —
+        # the reference's GpuCoalesceBatches goal contract)
+        if isinstance(goal, RequireSingleBatch) and \
+                not c.produces_single_batch:
+            return CoalesceBatchesExec(c, goal, max_rows=max_rows)
+        if isinstance(goal, TargetSize) and isinstance(c, fragmenting):
+            return CoalesceBatchesExec(c, TargetSize(target_bytes),
+                                       max_rows=max_rows)
+        return c
 
     out = rewrite(plan)
     verify_coalesce_goals(out)   # the contract's 'verify' half
@@ -944,19 +950,29 @@ class Overrides:
 
     @staticmethod
     def _partitioned(child: Exec) -> bool:
-        """Whether ``child`` has more than one partition. An adaptive
-        exchange or a co-partitioned join below it can say only once its
-        map output exists, so this question EXECUTES that subtree, during
-        planning: span ``plan.materialize`` holds it, the operators that
-        ran under it, and microseconds where nothing had to run."""
+        """Whether the PLAN gives ``child`` more than one partition: a
+        question of plan facts (``Exec.planned_partitions``) that runs
+        nothing. Where an adaptive exchange or a co-partitioned join below
+        turns out at run time to have one after all, the exchange planted
+        on this answer stands aside (``_exchange_if_partitioned``). Span
+        ``plan.materialize`` stays around the question as the guard: it
+        reads microseconds, and an operator under it is a planner that
+        executes again."""
         from .. import trace as qtrace
         with qtrace.span("plan.materialize", kind="plan", exec=child.name):
-            return child.num_partitions > 1
+            return child.planned_partitions > 1
 
     def _exchange(self, partitioning, child: Exec) -> Exec:
         from ..shuffle.manager import get_shuffle_manager
         return get_shuffle_manager(self.conf).create_exchange(
             partitioning, child)
+
+    def _exchange_if_partitioned(self, partitioning, child: Exec) -> Exec:
+        """The exchange planted because ``_partitioned(child)`` said
+        "maybe more than one": it stands aside where the run says one."""
+        ex = self._exchange(partitioning, child)
+        ex.aside = child
+        return ex
 
     def _to_exec(self, n: L.LogicalPlan, ch: List[Exec]) -> Exec:
         if isinstance(n, L.LogicalScan):
@@ -1024,11 +1040,12 @@ class Overrides:
         if any(not getattr(a, "supports_partial", True) for a in raw_aggs):
             if self._partitioned(child):
                 if n.group_exprs:
-                    child = self._exchange(
+                    child = self._exchange_if_partitioned(
                         HashPartitioning(list(n.group_exprs),
                                          self._shuffle_partitions()), child)
                 else:
-                    child = self._exchange(SinglePartitioning(), child)
+                    child = self._exchange_if_partitioned(
+                        SinglePartitioning(), child)
             return HashAggregateExec(n.group_exprs, n.agg_exprs, child,
                                      AggregateMode.COMPLETE,
                                      max_result_rows=agg_rows)
@@ -1038,11 +1055,11 @@ class Overrides:
         if n.group_exprs and self._partitioned(child):
             from ..expressions.base import col
             key_cols = [col(f.name) for f in partial.key_fields]
-            ex = self._exchange(
+            ex = self._exchange_if_partitioned(
                 HashPartitioning(key_cols, self._shuffle_partitions()),
                 partial)
         elif self._partitioned(child):
-            ex = self._exchange(SinglePartitioning(), partial)
+            ex = self._exchange_if_partitioned(SinglePartitioning(), partial)
         else:
             ex = partial
         return HashAggregateExec(n.group_exprs, n.agg_exprs, ex,
@@ -1057,10 +1074,11 @@ class Overrides:
         w = first.child if isinstance(first, Alias) else first
         pkeys = list(w.spec.partition_keys)
         if pkeys and self._partitioned(child):
-            child = self._exchange(
+            child = self._exchange_if_partitioned(
                 HashPartitioning(pkeys, self._shuffle_partitions()), child)
         elif self._partitioned(child):
-            child = self._exchange(SinglePartitioning(), child)
+            child = self._exchange_if_partitioned(
+                SinglePartitioning(), child)
         if pkeys:
             # bound the window kernel's per-batch working set by
             # re-chunking into key-complete batches (reference:

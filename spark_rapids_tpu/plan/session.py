@@ -731,8 +731,9 @@ class Session:
     def explain(self, df: DataFrame,
                 mode: ExplainMode = ExplainMode.ALL) -> str:
         text = Overrides(self.conf).explain(df.plan, mode)
-        # explain() plans nothing (planning a join can materialize its build
-        # side); a give-way is a fact of the last collect, reported as such
+        # explain() tags and converts no exec (converting may still run a
+        # build side: dynamic partition pruning); a give-way is a fact of
+        # the last collect, reported as such
         if self.last_mesh_giveway is not None:
             text += ("\nlast collect: mesh lowering gave way to the "
                      f"host-mediated exchange: {self.last_mesh_giveway}")
@@ -819,6 +820,9 @@ class Session:
         names = []
 
         def walk(e):
+            if getattr(e, "stood_aside", False):
+                # an exchange is named only where it ran
+                return walk(e.aside)
             names.append(e.name)
             for c in e.children:
                 walk(c)

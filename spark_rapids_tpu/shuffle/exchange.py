@@ -53,7 +53,69 @@ def _coalesce_groups(counts: List[int], target_rows: int) -> List[List[int]]:
     return groups or [[0]]
 
 
-class ShuffleExchangeExec(UnaryExec):
+class PartitioningExchangeExec(UnaryExec):
+    """What every exchange the shuffle manager hands out shares: the
+    partition count its PLAN states, and standing aside.
+
+    The planner plants an exchange where the plan says its child MAY have
+    more than one partition (``Overrides._partitioned``) and lets it stand
+    aside (``aside``): if at the first pull the child turns out to have ONE
+    partition (an adaptive exchange below coalesced to one), the exchange
+    hands the child's batches through as its one partition, with no
+    partition ids, no slices, no registration and no operator span of its
+    own, and is not named among the execs that ran. The exchanges under a
+    shuffled join never stand aside: both sides must hash alike."""
+
+    #: what the consumer pulls when this exchange stands aside: its child,
+    #: or the child under the coalesce the transition pass would have put
+    #: between the two. None (a join's exchanges): never stands aside
+    aside: Optional[Exec] = None
+    #: whether the last execution stood aside (``executed_exec_names()``)
+    stood_aside = False
+    _standing: Optional[bool] = None     # this execution's, once asked
+
+    def __init__(self, partitioning: Partitioning, child: Exec,
+                 ctx: Optional[EvalContext] = None):
+        super().__init__(child, ctx)
+        self.partitioning = partitioning.bind(child.output_schema)
+
+    @property
+    def output_schema(self) -> Schema:
+        return self.child.output_schema
+
+    @property
+    def planned_partitions(self) -> int:
+        # (a shuffled join sets the reader layout of its two exchanges,
+        # skew split included: ``HashJoinExec.planned_partitions``)
+        return self.partitioning.num_partitions
+
+    def _stands_aside(self) -> bool:
+        if self.aside is None:
+            return False
+        if self._standing is None:
+            self._standing = self.stood_aside = \
+                self.child.num_partitions == 1
+        return self._standing
+
+    @property
+    def num_partitions(self) -> int:
+        return 1 if self._stands_aside() else self._reader_partitions()
+
+    def _reader_partitions(self) -> int:
+        """The partitions a reader sees when the exchange runs."""
+        return self.partitioning.num_partitions
+
+    def execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
+        if self._stands_aside():
+            return self.aside.execute_partition(p)
+        return super().execute_partition(p)
+
+    def close(self) -> None:
+        super().close()
+        self._standing = None     # a re-execution asks its child again
+
+
+class ShuffleExchangeExec(PartitioningExchangeExec):
     """All-to-all redistribution of rows by a partitioning.
 
     Spill discipline (reference: RapidsShuffleIterator/ShuffleBufferCatalog):
@@ -66,14 +128,15 @@ class ShuffleExchangeExec(UnaryExec):
 
     @property
     def produces_single_batch(self):
-        return True
+        # (one read-side concat a partition; what stands aside hands
+        # through whatever its child yields)
+        return self.aside is None or self.aside.produces_single_batch
 
     def __init__(self, partitioning: Partitioning, child: Exec,
                  ctx: Optional[EvalContext] = None, adaptive: bool = False,
                  target_rows: int = 1 << 20,
                  catalog: Optional[BufferCatalog] = None):
-        super().__init__(child, ctx)
-        self.partitioning = partitioning.bind(child.output_schema)
+        super().__init__(partitioning, child, ctx)
         self._materialized: Optional[
             List[List[Tuple[SpillableBatch, int]]]] = None
         # AQE (reference: GpuCustomShuffleReaderExec): after the stage
@@ -114,12 +177,7 @@ class ShuffleExchangeExec(UnaryExec):
             self._catalog = device_budget()
         return self._catalog
 
-    @property
-    def output_schema(self) -> Schema:
-        return self.child.output_schema
-
-    @property
-    def num_partitions(self) -> int:
+    def _reader_partitions(self) -> int:
         if self._specs is not None:
             return len(self._specs)
         if self.adaptive:
@@ -573,6 +631,8 @@ class BroadcastExchangeExec(UnaryExec):
     def num_partitions(self) -> int:
         return 1
 
+    planned_partitions = num_partitions    # a plan fact
+
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
         from ..memory.retry import acquire_with_retry, with_retry_no_split
         if self._sb is None:
@@ -619,7 +679,7 @@ class BroadcastExchangeExec(UnaryExec):
 _cached_shuffle_ids = itertools.count(1)
 
 
-class CachedShuffleExchangeExec(UnaryExec):
+class CachedShuffleExchangeExec(PartitioningExchangeExec):
     """Device-resident CACHED shuffle mode (reference: RapidsCachingWriter
     + ShuffleBufferCatalog, RapidsShuffleInternalManagerBase.scala:876):
     map outputs are registered as spillable DEVICE blocks in a
@@ -630,8 +690,7 @@ class CachedShuffleExchangeExec(UnaryExec):
 
     def __init__(self, partitioning: Partitioning, child: Exec,
                  ctx: Optional[EvalContext] = None, cache=None, conf=None):
-        super().__init__(child, ctx)
-        self.partitioning = partitioning.bind(child.output_schema)
+        super().__init__(partitioning, child, ctx)
         self._shuffle_id = next(_cached_shuffle_ids)
         self._cache = cache
         self._conf = conf
@@ -654,14 +713,6 @@ class CachedShuffleExchangeExec(UnaryExec):
             from .device_cache import shared_device_cache
             self._cache = shared_device_cache(getattr(self, "_conf", None))
         return self._cache
-
-    @property
-    def output_schema(self) -> Schema:
-        return self.child.output_schema
-
-    @property
-    def num_partitions(self) -> int:
-        return self.partitioning.num_partitions
 
     def _write(self) -> None:
         # double-checked under the lock: concurrent reduce-partition

@@ -20,9 +20,10 @@ from typing import Iterator, List, Optional
 import jax
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
-from ..exec.base import Exec, UnaryExec
+from ..exec.base import Exec
 from ..exec.common import KernelPrograms, compact, concat_batches
 from ..expressions.base import EvalContext
+from .exchange import PartitioningExchangeExec
 from .partitioning import Partitioning, RangePartitioning
 from .serializer import deserialize_batch, serialize_batch
 from .transport import BlockMissingError, PeerUnreachableError
@@ -49,7 +50,7 @@ class BytesInFlightLimiter:
             self._cv.notify_all()
 
 
-class MultithreadedShuffleExchangeExec(UnaryExec):
+class MultithreadedShuffleExchangeExec(PartitioningExchangeExec):
     """Shuffle through framed spill files with writer/reader thread pools."""
 
     def __init__(self, partitioning: Partitioning, child: Exec,
@@ -65,8 +66,7 @@ class MultithreadedShuffleExchangeExec(UnaryExec):
                  replicas: int = 0,
                  lineage_enabled: bool = True,
                  lineage_registry=None):
-        super().__init__(child, ctx)
-        self.partitioning = partitioning.bind(child.output_schema)
+        super().__init__(partitioning, child, ctx)
         self.shuffle_dir = shuffle_dir or os.path.join(
             "/tmp/rapids_tpu_shuffle", uuid.uuid4().hex)
         self.num_threads = num_threads
@@ -121,14 +121,6 @@ class MultithreadedShuffleExchangeExec(UnaryExec):
         self._pids_jit = KernelPrograms(self, ("partitioning",)).jit(
             "pids",
             lambda self, b: self.partitioning.partition_ids(b, self.ctx))
-
-    @property
-    def output_schema(self) -> Schema:
-        return self.child.output_schema
-
-    @property
-    def num_partitions(self) -> int:
-        return self.partitioning.num_partitions
 
     # ------------------------------------------------------------------
     # write side (map tasks)

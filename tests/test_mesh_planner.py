@@ -113,11 +113,8 @@ def test_mesh_join_overflow_retries():
 
 NO_BROADCAST = dict(ICI)
 NO_BROADCAST["spark.rapids.tpu.sql.autoBroadcastJoinThreshold"] = 0
-# ... and at run time: planning reads the join's partition count, which
-# materializes the 41-row build side and lets the adaptive switch turn the
-# join into a broadcast one, which has no mesh lowering (the session then
-# reports MeshGiveWay[broadcast join without broadcast exchange child])
-NO_BROADCAST["spark.rapids.tpu.sql.adaptive.broadcastJoin.enabled"] = False
+# (the adaptive run-time switch to a broadcast join stays ON, its default:
+# planning runs nothing, so the lowering sees the join as planned)
 
 
 def _shuffled_vs_cpu(df_fn, ignore_order=True, require_exchanges=0):
@@ -176,24 +173,24 @@ def test_planned_global_sort_on_mesh():
     assert "MeshStageExec" in ses.executed_exec_names()
 
 
+def _join_under_group_by():
+    return (table(FACT)
+            .join(table(DIM), ["k"], ["dk"], JoinType.INNER)
+            .group_by("g")
+            .agg(Sum(col("v")).alias("sv"), Count().alias("c")))
+
+
 def test_mesh_giveway_reason_is_visible():
     """ICI shuffle mode asked for the mesh data plane; when lowering gives
     way to the host-mediated exchange the session SAYS so, with the reason,
-    in executed_exec_names() and explain() — never a silent switch. Here
-    the adaptive runtime broadcast switch (left on) turns the 41-row build
-    side's join into a broadcast one, which has no mesh lowering."""
-    conf = dict(NO_BROADCAST)
-    conf["spark.rapids.tpu.sql.adaptive.broadcastJoin.enabled"] = True
-
+    in executed_exec_names() and explain() — never a silent switch. Here a
+    PLANNED broadcast build (the 41-row side) under FULL OUTER, which no
+    device of the mesh can finish alone."""
     def q():
-        # planning the group-by above the join reads the join's partition
-        # count — which is what materializes the build side and switches
-        return (table(FACT)
-                .join(table(DIM), ["k"], ["dk"], JoinType.INNER)
-                .group_by("g")
-                .agg(Sum(col("v")).alias("sv"), Count().alias("c")))
+        return table(FACT).join(table(DIM), ["k"], ["dk"],
+                                JoinType.FULL_OUTER)
     cpu = Session({"spark.rapids.tpu.sql.enabled": False})
-    tpu = Session(conf)
+    tpu = Session(ICI)
     # explain() plans nothing: the give-way is a fact of a collect
     assert "gave way" not in tpu.explain(q())
     assert tpu.last_mesh_giveway is None
@@ -201,13 +198,35 @@ def test_mesh_giveway_reason_is_visible():
     names = tpu.executed_exec_names()
     assert not any("MeshStage" in n for n in names), names
     assert tpu.last_mesh_giveway == \
-        "broadcast join without broadcast exchange child"
+        "JoinType.FULL_OUTER needs global matched-build state under a " \
+        "replicated build"
     assert f"MeshGiveWay[{tpu.last_mesh_giveway}]" in names
     assert "last collect: mesh lowering gave way to the host-mediated " \
-        "exchange: broadcast join" in tpu.explain(q())
+        "exchange: JoinType.FULL_OUTER needs" in tpu.explain(q())
     assert_tables_equal(actual, cpu.collect(q()), ignore_order=True)
     # a plan that does lower reports no give-way
-    pinned = Session(NO_BROADCAST)
-    pinned.collect(q())
-    assert pinned.last_mesh_giveway is None
-    assert "MeshStageExec" in pinned.executed_exec_names()
+    lowers = Session(NO_BROADCAST)
+    lowers.collect(_join_under_group_by())
+    assert lowers.last_mesh_giveway is None
+    assert "MeshStageExec" in lowers.executed_exec_names()
+
+
+def test_the_run_time_broadcast_switch_left_on_does_not_stop_the_lowering():
+    """Until ISSUE 33, planning the group-by above a shuffled join RAN the
+    join's 41-row build side to count partitions; the adaptive switch then
+    turned the join into a broadcast one before the lowering saw it, and
+    the session gave way (``MeshGiveWay[broadcast join without broadcast
+    exchange child]``) unless ``adaptive.broadcastJoin.enabled`` was
+    pinned off. Planning runs nothing now: with the switch at its default
+    (on) the plan lowers to one mesh stage."""
+    conf = dict(NO_BROADCAST)
+    assert "spark.rapids.tpu.sql.adaptive.broadcastJoin.enabled" not in conf
+    conf["spark.rapids.tpu.sql.adaptive.broadcastJoin.enabled"] = True
+    cpu = Session({"spark.rapids.tpu.sql.enabled": False})
+    tpu = Session(conf)
+    actual = tpu.collect(_join_under_group_by())
+    assert tpu.last_mesh_giveway is None
+    assert tpu.executed_exec_names() == ["MeshStageExec"]
+    assert tpu.last_plan.lowered.count("mesh_exchange(all_to_all)") >= 3
+    assert_tables_equal(actual, cpu.collect(_join_under_group_by()),
+                        ignore_order=True)

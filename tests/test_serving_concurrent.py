@@ -289,3 +289,116 @@ def test_query_admission_cancel_and_cap_unit():
         assert cat.device_used <= cat.device_limit
     assert cat.device_used == 0
     assert adm.in_flight == 0
+
+
+# ---------------------------------------------------------------------------
+# What covers a query's exchanges now that planning runs none (ISSUE 33):
+# admission, the unwind, the breaker's classification. The query is the
+# shuffled ``join_sort`` shape: until ISSUE 33 planning its group-by ran
+# both of the join's exchanges, before any of the three applied.
+# ---------------------------------------------------------------------------
+
+_K = "spark.rapids.tpu."
+_SHUFFLED_JOIN = {
+    _K + "sql.autoBroadcastJoinThreshold": 0,
+    _K + "server.resultCache.enabled": "false",
+    _K + "server.concurrentCollects": "1",
+}
+
+
+def _hold_the_slot(server, tabs, delay_ms):
+    """Start a query whose ``server.test.collectDelayMs`` keeps it in the
+    one collect slot, and return once it sits there."""
+    import time
+
+    def hold():
+        with PlanClient("127.0.0.1", server.port, conf={
+                _K + "server.test.collectDelayMs": str(delay_ms)}) as c:
+            c.collect(dict(_shapes(tabs))["hash_agg"](3))
+    th = threading.Thread(target=hold)
+    th.start()
+    deadline = time.monotonic() + 5
+    while server.serving_stats()["admission"]["inFlight"] < 1:
+        assert time.monotonic() < deadline
+        time.sleep(0.01)
+    return th
+
+
+def test_a_queued_querys_first_exchange_write_waits_for_its_admission(tabs):
+    server = PlanServer(conf=dict(
+        _SHUFFLED_JOIN, **{_K + "trace.enabled": "true"})).start()
+    try:
+        holder = _hold_the_slot(server, tabs, 500)
+        with PlanClient("127.0.0.1", server.port) as c:
+            got = c.collect(dict(_shapes(tabs))["join_sort"](11))
+            assert got.num_rows == 10
+            assert "ShuffleExchangeExec" in c.last_execs
+            spans = next(p["spans"] for p in c.last_trace()["profiles"]
+                         if p["component"] == "server")
+        holder.join(10)
+        wait = next(s for s in spans if s["name"] == "admission.wait")
+        writes = [s for s in spans
+                  if s["name"] == "ShuffleExchangeExec.write"]
+        assert wait["durUs"] > 100_000      # it did queue behind the slot
+        assert writes and min(s["tsUs"] for s in writes) >= \
+            wait["tsUs"] + wait["durUs"]
+    finally:
+        server.stop()
+
+
+def test_a_query_cancelled_waiting_for_admission_registered_nothing(tabs):
+    from spark_rapids_tpu.server.client import PlanServerError
+    server = PlanServer(conf=dict(_SHUFFLED_JOIN)).start()
+    cat = device_budget()
+    try:
+        holder = _hold_the_slot(server, tabs, 1200)
+        # (the holder sleeps in its slot: nothing else registers meanwhile)
+        found = (cat._next, len(cat._entries))
+        with PlanClient("127.0.0.1", server.port) as c:
+            with pytest.raises(PlanServerError) as ei:
+                c.collect(dict(_shapes(tabs))["join_sort"](12),
+                          timeout_ms=300)
+            assert ei.value.timeout and ei.value.retryable
+        assert (cat._next, len(cat._entries)) == found
+        holder.join(10)
+        import time
+        deadline = time.monotonic() + 5
+        while server.active_query_count and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert server.active_query_count == 0
+        assert len(cat._entries) == found[1]
+    finally:
+        server.stop()
+
+
+def test_a_final_oom_in_an_exchange_write_is_an_exec_phase_failure(tabs):
+    """... for the circuit breaker: only failures tagged where the plan ran
+    on the device reach its classification, and the exchange's write used
+    to run, and fail, under ``prepare`` as a bind error (with whatever
+    injection the previous collect had installed, not this session's)."""
+    from spark_rapids_tpu.server.client import PlanServerError
+    server = PlanServer(conf=dict(_SHUFFLED_JOIN)).start()
+    breaker = server._server.breaker
+    seen, record = [], breaker.record_failure
+    breaker.record_failure = lambda e: (seen.append(e), record(e))[1]
+    try:
+        # every allocation check after the two scans' uploads fails, more
+        # often than the retry loop tries: the first exchange write's
+        with PlanClient("127.0.0.1", server.port, conf={
+                _K + "test.injectOOM.mode": "every-1",
+                _K + "test.injectOOM.skipCount": "2",
+                _K + "test.injectOOM.oomCount": "100"}) as c:
+            with pytest.raises(PlanServerError, match="ShuffleExchangeExec: "
+                               "device OOM survived"):
+                c.collect(dict(_shapes(tabs))["join_sort"](13))
+        assert [type(e).__name__ for e in seen] == ["FinalOOMError"]
+        assert getattr(seen[0], "_rtpu_exec_phase", False)
+        # a final OOM fails the query and leaves the executor healthy
+        with PlanClient("127.0.0.1", server.port) as c:
+            assert c.collect(
+                dict(_shapes(tabs))["join_sort"](13)).num_rows == 10
+    finally:
+        from spark_rapids_tpu.memory.retry import oom_injection
+        with oom_injection(""):
+            pass
+        server.stop()

@@ -232,15 +232,16 @@ def test_decode_on_pool_threads_is_the_querys_under_its_scan(tmp_path):
                        and s["attrs"]["deviceBytes"] > 0 for s in h2d)
 
 
-def test_planning_that_runs_an_exchange_says_so():
+def test_planning_runs_no_exchange_and_its_span_says_so():
     from spark_rapids_tpu.plan import table
     t = pa.table({"k": np.arange(600, dtype=np.int64) % 13,
                   "v": np.arange(600, dtype=np.int64)})
     ses = Session(dict(TRACE_ON, **{
         "spark.rapids.tpu.sql.adaptive.enabled": "true"}))
-    # the outer aggregate's planning asks the inner one how many
-    # partitions it has; the adaptive exchange under it can say only
-    # once its map output exists
+    # the outer aggregate's planning asks whether the inner one can have
+    # more than one partition; the adaptive exchange under it could say
+    # how many it HAS only once its map output exists, so the planner asks
+    # what the plan states and runs nothing (ISSUE 33)
     df = (table(t, num_slices=3).group_by("k")
           .agg(Sum(col("v")).alias("s"))
           .group_by("s").agg(Sum(col("k")).alias("ks")))
@@ -256,13 +257,23 @@ def test_planning_that_runs_an_exchange_says_so():
         # the planner's span, never the exec layer's guess
         assert by_id[s["parent"]]["name"] == "plan.overrides"
         assert s["attrs"]["exec"]
-    ran = [s for s in asked
-           if any(c["parent"] == s["id"] and c["kind"] == "operator"
-                  for c in spans)]
-    # the exchange ran once, under the question that needed it
-    assert [s["attrs"]["exec"] for s in ran] == ["HashAggregateExec"]
-    assert any(_under(by_id, s, ran[0]["id"]) for s in spans
-               if s["name"] == "ShuffleExchangeExec.write")
+    prepare = next(s for s in spans if s["name"] == "plan.prepare")
+    # the guard: nothing the planner asked had to run, so no span of any
+    # kind lies under a question, and none of an operator, an exchange, a
+    # scan or a lowering anywhere under ``plan.prepare``
+    assert not any(_under(by_id, by_id.get(s["parent"]), a["id"])
+                   for a in asked for s in spans)
+    assert [s["name"] for s in spans if _under(by_id, s, prepare["id"])
+            and (s["kind"] in ("operator", "shuffle")
+                 or s["name"].startswith(("scan.", "jit.")))] == []
+    # the exchange ran once, under ``execute``
+    execute = next(s for s in spans if s["name"] == "execute")
+    writes = [s for s in spans if s["name"] == "ShuffleExchangeExec.write"]
+    assert writes and all(_under(by_id, s, execute["id"]) for s in writes)
+    # ... and the outer one, planted on "maybe", stood aside: one exchange
+    # ran (the one batch of the inner one), one is named
+    assert len(writes) == 1
+    assert ses.executed_exec_names().count("ShuffleExchangeExec") == 1
 
 
 def _under(by_id, s, ancestor_id):
