@@ -3,12 +3,13 @@
 Reference: GpuShuffleExchangeExecBase.scala:152,262 (prepareBatchShuffleDependency:
 partition-id eval → device slicing → serialized blocks),
 GpuBroadcastExchangeExec.scala:319. This module is the DEFAULT/host-mediated
-shuffle mode (SURVEY.md §2.10): per input batch, rows are sliced per target
-partition ON DEVICE (one fused kernel computing partition ids + cumsum
-compaction per target), and re-coalesced on the read side. The ICI
-device-collective mode lives in parallel/mesh.py; both sit behind the same
-exec surface the way the reference's three shuffle modes sit behind one
-shuffle manager.
+shuffle mode (SURVEY.md §2.10): per input batch, rows are split into their
+target partitions' pieces ON DEVICE (``PartitioningExchangeExec.split``: one
+program orders the row index by partition id and counts each partition, one
+host read brings the counts, one gather a non-empty piece at its row-count
+bucket), and re-coalesced on the read side. The ICI device-collective mode
+lives in parallel/mesh.py; both sit behind the same exec surface the way the
+reference's three shuffle modes sit behind one shuffle manager.
 """
 
 from __future__ import annotations
@@ -20,11 +21,13 @@ import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from .. import trace as qtrace
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..exec.base import Exec, UnaryExec
-from ..exec.common import KernelPrograms, compact, concat_batches, \
-    slice_batch
+from ..exec.common import KernelPrograms, concat_batches, gather, \
+    jit_named, lex_sort_permutation, slice_batch
 from ..expressions.base import EvalContext
 from ..memory.catalog import BufferCatalog, SpillableBatch
 from .partitioning import Partitioning, RangePartitioning, SinglePartitioning
@@ -55,7 +58,8 @@ def _coalesce_groups(counts: List[int], target_rows: int) -> List[List[int]]:
 
 class PartitioningExchangeExec(UnaryExec):
     """What every exchange the shuffle manager hands out shares: the
-    partition count its PLAN states, and standing aside.
+    partition count its PLAN states, standing aside, and the splitter
+    (``split``: a batch into its partitions' pieces).
 
     The planner plants an exchange where the plan says its child MAY have
     more than one partition (``Overrides._partitioned``) and lets it stand
@@ -79,9 +83,86 @@ class PartitioningExchangeExec(UnaryExec):
         super().__init__(child, ctx)
         self.partitioning = partitioning.bind(child.output_schema)
 
+        def split_kernel(self, b: ColumnarBatch):
+            n = self.partitioning.num_partitions
+            pids = self.partitioning.partition_ids(b, self.ctx)
+            # dead rows take the id n: they order last, past every piece
+            ids = jnp.where(b.row_mask(), pids, n)
+            perm = lex_sort_permutation([ids.astype(jnp.uint32)])
+            # (a compare and a sum a partition, no scatter-add: n is
+            # small and scatters are the slow primitive on this chip)
+            parts = jnp.arange(n, dtype=jnp.int32)[:, None]
+            return perm, jnp.sum(ids[None, :] == parts, axis=1,
+                                 dtype=jnp.int32)
+
+        def piece_kernel(self, b: ColumnarBatch, perm, off, rows,
+                         cap: int) -> ColumnarBatch:
+            # (padded, so that a slice near the end is not moved back)
+            idx = jax.lax.dynamic_slice(jnp.pad(perm, (0, cap)), (off,),
+                                        (cap,))
+            return gather(b, idx, rows,
+                          jnp.arange(cap, dtype=jnp.int32) < rows)
+
+        self._split_jit = KernelPrograms(self, ("partitioning",)).jit(
+            "split", split_kernel)
+        # (one program a distinct piece capacity, offset and rows traced)
+        self._piece_jit = KernelPrograms(self, ()).jit(
+            "piece", piece_kernel, static_argnums=4)
+        # one partition: its rows are the batch's first (the module's own
+        # program, shared with every other ``slice_batch`` site)
+        self._shrink_jit = jit_named("slice_batch", slice_batch,
+                                     static_argnums=3)
+
     @property
     def output_schema(self) -> Schema:
         return self.child.output_schema
+
+    def split(self, b: ColumnarBatch
+              ) -> Iterator[Tuple[int, ColumnarBatch, int]]:
+        """``b``'s non-empty pieces as (partition, piece, rows), in
+        partition order: each the partition's rows in ``b``'s order, at
+        ``bucket_capacity(rows)`` (padding at the input's capacity would
+        multiply device residency by the partition count), dictionaries
+        riding along. One stable ordering of the row index by partition
+        id, ONE host read (the counts of all partitions) and gathers that
+        sum to about one pass over ``b``, whatever the partition count;
+        ids come from the partitioning, capacities from the counts."""
+        n = self.partitioning.num_partitions
+        if n == 1:
+            perm, counts = None, [int(b.num_rows)]
+        else:
+            perm, counts = self._split_jit(b)
+            counts = counts.tolist()    # the batch's ONE host read
+        # the piece program moves row lanes only: a dictionary is taken
+        # off before it and put back, the same object, on every piece
+        dicts = [(c.dict_data, c.dict_lengths) if c.is_dict else None
+                 for c in b.columns]
+        lanes = ColumnarBatch(tuple(
+            c.replace(dict_data=None, dict_lengths=None) if d else c
+            for c, d in zip(b.columns, dicts)), b.num_rows)
+        off = pieces = gathered = 0
+        for p, rows in enumerate(counts):
+            if rows == 0:
+                continue      # an absent piece reads as nothing downstream
+            cap = min(bucket_capacity(rows), b.capacity)
+            if perm is None and cap == b.capacity:
+                piece = b
+            else:
+                piece = self._shrink_jit(lanes, np.int32(0), np.int32(rows),
+                                         cap) if perm is None else \
+                    self._piece_jit(lanes, perm, np.int32(off),
+                                    np.int32(rows), cap)
+                piece = ColumnarBatch(tuple(
+                    c.replace(dict_data=d[0], dict_lengths=d[1]) if d else c
+                    for c, d in zip(piece.columns, dicts)), piece.num_rows)
+            off += rows
+            pieces += 1
+            gathered += cap
+            yield p, piece, rows
+        if n > 1:
+            qtrace.count(splitBatches=1, splitPieces=pieces,
+                         splitRowsSorted=b.capacity,
+                         splitRowsGathered=gathered)
 
     @property
     def planned_partitions(self) -> int:
@@ -148,22 +229,6 @@ class ShuffleExchangeExec(PartitioningExchangeExec):
         self._use_left: Optional[Dict[Tuple[int, int], int]] = None
         self._catalog = catalog
 
-        def slice_kernel(self, batch: ColumnarBatch, pids,
-                         p: int) -> ColumnarBatch:
-            return compact(batch, pids == p)
-
-        # (the partition index is a static argument: one table entry, a
-        # program a partition inside it, built once a process)
-        programs = KernelPrograms(self, ())
-        self._slice_jit = programs.jit("slice", slice_kernel,
-                                       static_argnums=2)
-        self._shrink_jit = programs.jit(
-            "shrink",
-            lambda self, b, cap: slice_batch(b, 0, b.num_rows, cap),
-            static_argnums=1)
-        self._pids_jit = KernelPrograms(self, ("partitioning",)).jit(
-            "pids",
-            lambda self, b: self.partitioning.partition_ids(b, self.ctx))
         from ..exec.base import DEBUG, MODERATE, Metric
         # wire-path visibility: serializeTime = framing/compression,
         # overlapTime = D2H staging hidden behind it (pipeline.py)
@@ -266,23 +331,6 @@ class ShuffleExchangeExec(PartitioningExchangeExec):
                 "bounds", bounds_kernel)(allk)
         part.set_bounds(bound_cols, n - 1)
 
-    def _register(self, staged, p: int, piece: ColumnarBatch) -> None:
-        """Shrink a partition piece to its row-count bucket and hand it to
-        the spill catalog (padding at full input capacity would multiply
-        device residency by the partition count). Appends to ``staged``
-        so a failed write attempt can free its partial pieces before the
-        retry loop re-runs it."""
-        rows = int(piece.num_rows)
-        if rows == 0:
-            return
-        cap = bucket_capacity(rows)
-        if cap < piece.capacity:
-            piece = self._shrink_jit(piece, cap)
-        # registration leaves the entry unpinned → spillable under pressure
-        sb = SpillableBatch(self._cat(), piece,
-                            self.output_schema)  # retry-ok: only write_body (runs under with_retry) calls _register
-        staged.append((p, sb, rows))
-
     def _materialize(self) -> List[List[Tuple[SpillableBatch, int]]]:
         if self._materialized is not None:
             return self._materialized
@@ -303,26 +351,25 @@ class ShuffleExchangeExec(PartitioningExchangeExec):
                       for b in self.child.execute_partition(cp))
         cat = self._cat()
         spill0 = cat.spilled_to_host + cat.spilled_to_disk
-        from .. import trace as qtrace
         from ..memory.retry import (SpillableInput, split_input_halves,
                                     with_retry)
         in_schema = self.child.output_schema
 
         def write_body(item: SpillableInput):
-            """One write attempt over one (possibly split) input: slice
-            per target partition and register the pieces. Transactional —
-            an OOM mid-loop frees this attempt's pieces so the retry (or
-            the half-inputs after a split) starts clean."""
+            """One write attempt over one (possibly split) input: split it
+            and hand each piece to the spill catalog as it is made.
+            Transactional — an OOM mid-loop frees this attempt's pieces
+            so the retry (or the half-inputs after a split) starts
+            clean."""
             b = item.acquire()
             staged: List[Tuple[int, SpillableBatch, int]] = []
             try:
-                if n == 1:
-                    self._register(staged, 0, b)
-                else:
-                    pids = self._pids_jit(b)
-                    for p in range(n):
-                        self._register(staged, p,
-                                       self._slice_jit(b, pids, p))
+                for p, piece, rows in self.split(b):
+                    # registration leaves the entry unpinned → spillable
+                    # under pressure
+                    staged.append(
+                        (p, SpillableBatch(cat, piece, self.output_schema),
+                         rows))
             except BaseException:
                 for _p, sb, _r in staged:
                     sb.close()
@@ -696,17 +743,6 @@ class CachedShuffleExchangeExec(PartitioningExchangeExec):
         self._conf = conf
         self._written = False
         self._write_lock = threading.Lock()
-        programs = KernelPrograms(self, ())
-        self._slice_jit = programs.jit(
-            "slice", lambda self, b, pids, p: compact(b, pids == p),
-            static_argnums=2)
-        self._shrink_jit = programs.jit(
-            "shrink",
-            lambda self, b, cap: slice_batch(b, 0, b.num_rows, cap),
-            static_argnums=1)
-        self._pids_jit = KernelPrograms(self, ("partitioning",)).jit(
-            "pids",
-            lambda self, b: self.partitioning.partition_ids(b, self.ctx))
 
     def _get_cache(self):
         if self._cache is None:
@@ -731,17 +767,7 @@ class CachedShuffleExchangeExec(PartitioningExchangeExec):
         m = 0
         for cp in range(self.child.num_partitions):
             for batch in self.child.execute_partition(cp):
-                pids = self._pids_jit(batch)
-                for r in range(self.num_partitions):
-                    piece = self._slice_jit(batch, pids, r)
-                    rows = int(piece.num_rows)
-                    if rows == 0:
-                        continue   # absent blocks read as None downstream
-                    cap = bucket_capacity(rows)
-                    if cap < piece.capacity:
-                        # full-capacity slices would multiply residency by
-                        # the partition count (same policy as _register)
-                        piece = self._shrink_jit(piece, cap)
+                for r, piece, _rows in self.split(batch):
                     cache.add_batch(self._shuffle_id, m, r, piece, schema)
                 m += 1
         self._n_maps = m

@@ -21,7 +21,7 @@ import jax
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..exec.base import Exec
-from ..exec.common import KernelPrograms, compact, concat_batches
+from ..exec.common import concat_batches
 from ..expressions.base import EvalContext
 from .exchange import PartitioningExchangeExec
 from .partitioning import Partitioning, RangePartitioning
@@ -113,15 +113,6 @@ class MultithreadedShuffleExchangeExec(PartitioningExchangeExec):
         else:
             self._lineage = None
 
-        def slice_kernel(self, batch, pids, p: int):
-            return compact(batch, pids == p)
-
-        self._slice_jit = KernelPrograms(self, ()).jit(
-            "slice", slice_kernel, static_argnums=2)
-        self._pids_jit = KernelPrograms(self, ("partitioning",)).jit(
-            "pids",
-            lambda self, b: self.partitioning.partition_ids(b, self.ctx))
-
     # ------------------------------------------------------------------
     # write side (map tasks)
     # ------------------------------------------------------------------
@@ -131,7 +122,6 @@ class MultithreadedShuffleExchangeExec(PartitioningExchangeExec):
         with self._write_lock:
             if self._written:
                 return
-            n = self.num_partitions
             schema = self.output_schema
             from ..trace import name_thread
             pool = cf.ThreadPoolExecutor(self.num_threads,
@@ -165,11 +155,7 @@ class MultithreadedShuffleExchangeExec(PartitioningExchangeExec):
                                 self._make_recompute(cp, bi),
                                 input_digest=self._fragment_digest(
                                     cp, bi))
-                        pids = self._pids_jit(batch)
-                        for p in range(n):
-                            piece = self._slice_jit(batch, pids, p)
-                            if int(piece.num_rows) == 0:
-                                continue
+                        for p, piece, _rows in self.split(batch):
                             futures.append(pool.submit(
                                 call_attached, tok, self._write_piece,
                                 piece, schema, m, p))
@@ -207,13 +193,9 @@ class MultithreadedShuffleExchangeExec(PartitioningExchangeExec):
         def recompute(reduce_ids):
             for i, batch in enumerate(self.child.execute_partition(cp)):
                 if i == bi:
-                    pids = self._pids_jit(batch)
-                    out = {}
-                    for r in reduce_ids:
-                        piece = self._slice_jit(batch, pids, r)
-                        out[r] = None if int(piece.num_rows) == 0 else \
-                            serialize_batch(piece, schema, self.codec)
-                    return out
+                    pieces = {p: piece for p, piece, _ in self.split(batch)}
+                    return {r: serialize_batch(pieces[r], schema, self.codec)
+                            if r in pieces else None for r in reduce_ids}
             return {}
 
         return recompute

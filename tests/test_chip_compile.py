@@ -64,18 +64,20 @@ def test_pallas_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     assert out["custom_calls"] >= 1, out     # the Mosaic kernel is in there
 
 
-@pytest.mark.parametrize("name", ["q1.project", "q3.join_count"])
+@pytest.mark.parametrize("name", ["q1.project", "q3.join_count",
+                                  "exchange.piece"])
 def test_sortless_program_compiles_for_v5e(name, one_chip,
                                            no_persistent_cache):
-    """The join probe is a search and gathers; a sort creeping back in
+    """The join probe is a search and gathers, an exchange's piece a slice
+    of the split's permutation and gathers; a sort creeping back in
     (searchsorted's method="sort" costs ~1-2 min of compile) shows here."""
     out = _compile(name, one_chip)
     assert out["hlo_sorts"] == 0, out
 
 
-@pytest.mark.parametrize("name", ["q1.agg_update", "exchange.slice"])
+@pytest.mark.parametrize("name", ["q1.agg_update", "exchange.split"])
 def test_sorting_program_holds_one_sort(name, one_chip, no_persistent_cache):
-    """The aggregate update and the exchange's slice kernel order rows with
+    """The aggregate update and the exchange's split program order rows with
     exec/common.lex_sort_permutation: ONE two-operand sort in the whole
     program, however many key and payload columns there are — the TPU
     compiler's time follows the sorts, their operands and widths."""

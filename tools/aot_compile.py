@@ -189,11 +189,26 @@ def smoke_programs(cap):
         return (lambda b: sort_batch(b, sort.orders, sort.ctx)), \
             [at_capacity(out, cap, cap * 4)]
 
-    def exchange_slice():
-        from spark_rapids_tpu.exec.common import compact
-        b = batch_of(bench.lineitem_table(cap))
-        pids = jnp.zeros(cap, jnp.int32)
-        return (lambda batch, p: compact(batch, p == 3)), [b, pids]
+    def exchange_execs():
+        """Q3's stream side hashed eight ways on its join key, as the
+        planner's shuffled join does."""
+        from spark_rapids_tpu.shuffle import (HashPartitioning,
+                                              ShuffleExchangeExec)
+        stream, _ = bench.join_tables(cap, cap >> 1)
+        ex = ShuffleExchangeExec(HashPartitioning([col("l_orderkey")], 8),
+                                 InMemoryScanExec(stream.slice(0, 16)))
+        return batch_of(stream), ex
+
+    def exchange_split():
+        b, ex = exchange_execs()
+        return ex._split_jit, [b]
+
+    def exchange_piece():
+        # one of eight pieces, just over an eighth: the next bucket up
+        b, ex = exchange_execs()
+        perm = jnp.zeros(cap, jnp.int32)
+        return ex._piece_jit, \
+            [b, perm, jnp.int32(0), jnp.int32(0), cap >> 2], (4,)
 
     def pallas_murmur3():
         from spark_rapids_tpu.kernels.murmur3 import pallas_murmur3_int32
@@ -295,7 +310,8 @@ def smoke_programs(cap):
         "q3.join_count": join_count,
         "q3.join_expand": join_expand,
         "q3.sort_4x": sort_kernel,
-        "exchange.slice": exchange_slice,
+        "exchange.split": exchange_split,
+        "exchange.piece": exchange_piece,
         "pallas.murmur3": pallas_murmur3,
         "pallas.string_search": pallas_string_search,
     }

@@ -122,7 +122,9 @@ def to_trace_events(profiles: Iterable[dict],
 #: span attributes that are counts: a name's row sums them over its spans
 _SUMMED = ("pulls", "lowerings", "programHits", "programMisses",
            "dec128Columns", "dec128Bytes",
-           "partialsCut", "partialRowsMade", "partialRowsKept")
+           "partialsCut", "partialRowsMade", "partialRowsKept",
+           "splitBatches", "splitPieces", "splitRowsSorted",
+           "splitRowsGathered")
 
 
 def self_time_table(profile: dict) -> List[dict]:
@@ -136,7 +138,10 @@ def self_time_table(profile: dict) -> List[dict]:
     (``dec128Columns`` / ``dec128Bytes``), and the aggregates' partials:
     how many were cut to their groups' bucket and the summed capacities
     before and after (``partialsCut`` / ``partialRowsMade`` /
-    ``partialRowsKept``)."""
+    ``partialRowsKept``), and the exchanges' splits: batches split, pieces
+    made, slots ordered (a batch's capacity, once) and slots gathered (the
+    pieces' summed capacities) (``splitBatches`` / ``splitPieces`` /
+    ``splitRowsSorted`` / ``splitRowsGathered``)."""
     import os
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), os.pardir))
@@ -173,6 +178,8 @@ def print_tables(profiles: Iterable[dict],
         print(f"{'span':<34}{'n':>4}{'inside ms':>12}{'self ms':>12}"
               f"{'pulls':>7}{'lowerings':>10}{'relower ms':>12}"
               f"{'hit/miss':>10}{'dec128 cols':>12}{'dec128 bytes':>14}"
+              f"{'splits':>8}{'pieces':>8}{'slots sorted':>14}"
+              f"{'slots gathered':>16}"
               f"{'partials cut':>13}{'rows made':>11}{'rows kept':>11}")
         for r in self_time_table(prof):
             print(f"{r['name']:<34}{r['spans']:>4}{r['insideMs']:>12.1f}"
@@ -180,6 +187,9 @@ def print_tables(profiles: Iterable[dict],
                   f"{r['lowerings']:>10}{r['relowerMs']:>12.1f}"
                   f"{str(r['programHits']) + '/' + str(r['programMisses']):>10}"
                   f"{r['dec128Columns']:>12}{r['dec128Bytes']:>14}"
+                  f"{r['splitBatches']:>8}{r['splitPieces']:>8}"
+                  f"{r['splitRowsSorted']:>14}"
+                  f"{r['splitRowsGathered']:>16}"
                   f"{r['partialsCut']:>13}{r['partialRowsMade']:>11}"
                   f"{r['partialRowsKept']:>11}")
 
