@@ -24,6 +24,7 @@ from typing import Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import types as T
 from ..batch import MIN_CAPACITY, ColumnarBatch, DeviceColumn, Field, \
@@ -34,7 +35,8 @@ from ..expressions.base import Alias, EvalContext, Expression
 from .base import Exec, UnaryExec
 from .basic import bind_all, output_name
 from .common import _batched_takes, adjacent_equal, adjacent_equal_ops, \
-    KernelPrograms, compaction_indices, concat_batches, gather_column, \
+    KernelPrograms, compaction_indices, concat_batches, \
+    concat_batches_encoded, cut_to_rows, dec128_role, gather_column, \
     jit_named, lex_sort_permutation, sort_operands
 
 # dtypes whose device payload is a flat 1-D array (or, for a decimal past
@@ -85,6 +87,12 @@ def _unalias(e: Expression) -> Tuple[AggregateFunction, str]:
 
 
 class HashAggregateExec(UnaryExec):
+    #: the fields of the exec that its kernels read (common.KernelPrograms)
+    _PROGRAM_READS = (
+        "mode", "group_exprs", "aggs", "key_fields", "buffer_fields",
+        "sort_sensitive", "small_groups_bucket", "layout_tiers",
+        "_upd_value_exprs", "_upd_per_agg", "_fast_update", "_fast_merge")
+
     def coalesce_goal_for_child(self, i):
         from .coalesce import TargetSize
         return TargetSize()
@@ -205,11 +213,7 @@ class HashAggregateExec(UnaryExec):
 
         # everything the kernels below read of this exec: the programs'
         # key, and all their stand-in has (common.KernelPrograms)
-        programs = KernelPrograms(self, (
-            "mode", "group_exprs", "aggs", "key_fields", "buffer_fields",
-            "sort_sensitive", "small_groups_bucket", "layout_tiers",
-            "_upd_value_exprs", "_upd_per_agg", "_fast_update",
-            "_fast_merge"))
+        programs = KernelPrograms(self, self._PROGRAM_READS)
         cls = type(self)
         def role(r):
             return r + "Dec128" if self._wide_buffers else r
@@ -607,6 +611,15 @@ class HashAggregateExec(UnaryExec):
                     yield self._final_jit(seed) if finalize \
                         else self._merge_jit(seed)
                 return
+            if len(spillables) == 1 and self.mode is AggregateMode.PARTIAL:
+                # ONE update's partial holds each of its groups once already
+                # (and is cut to their bucket): merging it alone would sort
+                # all of it again to change nothing
+                from ..memory import acquire_with_retry
+                only = spillables[0][0]
+                yield acquire_with_retry(only, name=self.name)
+                only.done_with()
+                return
             yield from self._merge_and_emit(spillables, finalize, cat,
                                             buf_schema)
         finally:
@@ -645,10 +658,7 @@ class HashAggregateExec(UnaryExec):
         the smallest bucket is not even read."""
         if batch.capacity <= MIN_CAPACITY:
             return batch
-        out_cap = bucket_capacity(max(self._held_rows(batch), 1))
-        if out_cap < batch.capacity:
-            batch = self._slice_compact(batch, out_cap)
-        return batch
+        return cut_to_rows(batch, self._held_rows(batch))
 
     def _merge_and_emit(self, entries, finalize, cat, buf_schema):
         """Merge spilled partials WITHOUT ever acquiring more than
@@ -688,7 +698,7 @@ class HashAggregateExec(UnaryExec):
                 def final_merge():
                     batches = _acquire_group(entries)
                     merged = batches[0] if len(batches) == 1 else \
-                        concat_batches(batches, bucket_capacity(total))
+                        concat_batches_encoded(batches, bucket_capacity(total))
                     for sb, _ in entries:
                         sb.done_with()
                     return merged
@@ -714,7 +724,7 @@ class HashAggregateExec(UnaryExec):
                 def window_merge(grp=grp, cap_sum=cap_sum):
                     batches = _acquire_group(grp)
                     merged = self._cut_to_groups(self._merge_jit(
-                        concat_batches(batches, bucket_capacity(cap_sum))))
+                        concat_batches_encoded(batches, bucket_capacity(cap_sum))))
                     for sb, _ in grp:
                         sb.done_with()
                     return merged
@@ -734,11 +744,6 @@ class HashAggregateExec(UnaryExec):
                 yield from self._ooc_sorted_merge(entries, finalize, cat,
                                                   buf_schema)
                 return
-
-    def _slice_compact(self, batch: ColumnarBatch, cap: int) -> ColumnarBatch:
-        from .common import slice_batch
-        return jit_named("slice_batch", slice_batch, static_argnums=3)(
-            batch, jnp.int32(0), jnp.int32(cap), cap)
 
     def _ooc_sorted_merge(self, entries, finalize, cat, buf_schema):
         """Sort-based OOC aggregation: global key order via the spilled
@@ -786,3 +791,71 @@ class HashAggregateExec(UnaryExec):
             yield self._eval_buffers_jit(emit) if finalize else emit
         if carry is not None:
             yield self._eval_buffers_jit(carry) if finalize else carry
+
+
+class RollupExec(HashAggregateExec):
+    """The coarser levels of a rollup, made from the finest level's
+    partials instead of from the rows (the planner puts it between the
+    Partial and the Final stage where the aggregate's child is a
+    rollup-shaped Expand, which then emits its finest projection alone).
+
+    Level j of a rollup is level j-1 with more keys nulled and its literal
+    keys (``spark_grouping_id``) replaced, and every buffer merges
+    associatively, so merging level j-1's groups under level j's keys gives
+    exactly the partial that aggregating projection j of the rows would
+    have: a sum rolls up exactly. Each input batch (buffer layout, finest
+    level) is handed on, then each coarser level in turn, merged from the
+    level before it at the capacity bucket of the groups THAT level holds:
+    the rows are sorted once at their own size and the levels at theirs,
+    where an Expand sorts every row once a level. The Final stage merges
+    whatever partials it is given, so several input batches (or several
+    partitions) are fine.
+
+    ``levels``: for each coarser level, finest first, ``(nulled, literals)``:
+    the ordinals of the keys null at that level and ``{ordinal: int}`` for
+    the literal keys."""
+
+    def __init__(self, levels, group_exprs, agg_exprs,
+                 child: "HashAggregateExec", **kw):
+        super().__init__(group_exprs, agg_exprs, child,
+                         AggregateMode.PARTIAL_MERGE, **kw)
+        nk = len(self.key_fields)
+        self.literal_keys = tuple(sorted({i for _, lits in levels
+                                          for i in lits}))
+        self._levels = [
+            (np.asarray([i in nulled for i in range(nk)], bool),
+             np.asarray([lits.get(i, 0) for i in range(nk)], np.int64))
+            for nulled, lits in levels]
+
+        def level(self, batch: ColumnarBatch, nulled, lits) -> ColumnarBatch:
+            cols = list(batch.columns)
+            for i in range(len(self.key_fields)):
+                c = cols[i]
+                if i in self.literal_keys:
+                    cols[i] = c.replace(data=jnp.full_like(c.data, lits[i]))
+                    continue
+                keep = ~nulled[i]
+                cols[i] = c.replace(
+                    data=jnp.where(keep, c.data, jnp.zeros((), c.data.dtype)),
+                    validity=c.validity & keep,
+                    lengths=None if c.lengths is None
+                    else jnp.where(keep, c.lengths, 0))
+            return self._merge_kernel(
+                ColumnarBatch(tuple(cols), batch.num_rows), final=False)
+
+        self._level_jit = KernelPrograms(
+            self, self._PROGRAM_READS + ("literal_keys",)).jit(
+                dec128_role("level", (f.dtype for f in self.buffer_fields)),
+                level)
+
+    def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
+        from .. import trace as qtrace
+        for batch in self.child.execute_partition(p):
+            batch = self._cut_to_groups(batch)
+            yield batch
+            for nulled, lits in self._levels:
+                qtrace.count(rollupLevels=1,
+                             rollupSlotsMerged=int(batch.capacity))
+                batch = self._cut_to_groups(
+                    self._level_jit(batch, nulled, lits))
+                yield batch

@@ -21,7 +21,7 @@ import pyarrow as pa
 from .. import types as T
 from ..batch import (ColumnarBatch, DeviceColumn, Field, Schema,
                      bucket_capacity, from_arrow)
-from ..expressions.base import Alias, EvalContext, Expression
+from ..expressions.base import Alias, EvalContext, Expression, raw_eval
 from ..types import TypeKind
 from .base import Exec, LeafExec, UnaryExec
 from .common import KernelPrograms, compact, dec128_role, slice_batch
@@ -390,33 +390,51 @@ class SampleExec(UnaryExec):
 
 class ExpandExec(UnaryExec):
     """Reference: GpuExpandExec — one output batch per projection per input
-    batch (rollup/cube/grouping sets)."""
+    batch (rollup/cube/grouping sets), all of a batch's projections made by
+    ONE program, each at the input's capacity. A bare column reference
+    hands on the stored column, dictionary codes included, so a string key
+    stays one code lane for the aggregate above.
+
+    ``emit``: the projections this exec makes (default: all). Under a
+    rollup the planner asks for the finest alone and has the coarser levels
+    made from the aggregate's partials (``aggregate.RollupExec``); the
+    schema is that of all projections either way, so a key that a coarser
+    level nulls is nullable from here on."""
 
     def __init__(self, projections: Sequence[Sequence[Expression]],
-                 child: Exec, ctx: Optional[EvalContext] = None):
+                 child: Exec, ctx: Optional[EvalContext] = None,
+                 emit: Optional[Sequence[int]] = None):
         super().__init__(child, ctx)
         self.projections = [bind_all(p, child.output_schema)
                             for p in projections]
-        self._schema = schema_of(self.projections[0])
+        self.emit = tuple(range(len(self.projections))) if emit is None \
+            else tuple(emit)
+        first = schema_of(self.projections[0])
         # nullability is the union across projections
-        fields = []
-        for i, f in enumerate(self._schema):
-            nullable = any(p[i].nullable for p in self.projections)
-            fields.append(Field(f.name, f.dtype, nullable))
-        self._schema = Schema(fields)
+        self._schema = Schema([
+            Field(f.name, f.dtype,
+                  any(p[i].nullable for p in self.projections))
+            for i, f in enumerate(first)])
 
-        def kernel(self, batch: ColumnarBatch, pi: int) -> ColumnarBatch:
-            cols = tuple(e.eval(batch, self.ctx) for e in self.projections[pi])
-            return ColumnarBatch(cols, batch.num_rows)
+        def kernel(self, batch: ColumnarBatch):
+            return tuple(
+                ColumnarBatch(tuple(raw_eval(e, batch, self.ctx)
+                                    for e in self.projections[pi]),
+                              batch.num_rows)
+                for pi in self.emit)
 
-        self._kernel = KernelPrograms(self, ("projections",)).jit(
-            "expand", kernel, static_argnums=1)
+        self._kernel = KernelPrograms(self, ("projections", "emit")).jit(
+            "expand", kernel)
 
     @property
     def output_schema(self) -> Schema:
         return self._schema
 
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
+        from .. import trace as qtrace
+        qtrace.count(expandProjections=len(self.emit))
         for batch in self.child.execute_partition(p):
-            for pi in range(len(self.projections)):
-                yield self._kernel(batch, pi)
+            out = self._kernel(batch)
+            qtrace.count(expandBatchesOut=len(out),
+                         expandSlotsOut=len(out) * int(batch.capacity))
+            yield from out

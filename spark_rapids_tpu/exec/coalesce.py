@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from .base import Exec, UnaryExec
-from .common import concat_batches
+from .common import concat_batches_encoded
 
 
 @dataclass(frozen=True)
@@ -56,11 +56,9 @@ class CoalesceBatchesExec(UnaryExec):
         if len(pending) == 1:
             return pending[0]
         cap = bucket_capacity(sum(b.capacity for b in pending))
-        # eager boundary: unify per-batch string dictionaries (device
-        # code-remap) so the coalesce keeps the encoded form instead of
-        # decoding to padded bytes at the first concat
-        from ..dictenc import unify_dict_batches
-        return concat_batches(unify_dict_batches(pending), cap)
+        # eager boundary: the coalesce keeps string columns' dictionary
+        # codes instead of decoding to padded bytes at the first concat
+        return concat_batches_encoded(pending, cap)
 
     @property
     def produces_single_batch(self) -> bool:

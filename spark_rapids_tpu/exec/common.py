@@ -370,6 +370,18 @@ def concat_batches(batches: Sequence[ColumnarBatch], capacity: int) -> ColumnarB
     return ColumnarBatch(cols, jnp.asarray(total, jnp.int32))
 
 
+def concat_batches_encoded(batches: Sequence[ColumnarBatch],
+                           capacity: int) -> ColumnarBatch:
+    """``concat_batches`` at an EAGER boundary (a coalesce, an aggregate's
+    merge, a window's input): string columns that every piece carries as
+    dictionary codes are put over one dictionary first (equal
+    dictionaries, the usual case of columns from one build side, share
+    theirs), so the result keeps its codes and a sort above it pays one
+    lane a key instead of ``max_len/8 + 1``. Never under tracing."""
+    from ..dictenc import unify_dict_batches
+    return concat_batches(unify_dict_batches(batches), capacity)
+
+
 def slice_batch(batch: ColumnarBatch, start: jax.Array, count: jax.Array,
                 capacity: Optional[int] = None) -> ColumnarBatch:
     """Rows [start, start+count) as a new batch (cudf Table slice)."""
@@ -379,6 +391,18 @@ def slice_batch(batch: ColumnarBatch, start: jax.Array, count: jax.Array,
                     jnp.maximum(batch.num_rows - start, 0))
     live = jnp.arange(cap, dtype=jnp.int32) < n
     return gather(batch, idx, n, live)
+
+
+def cut_to_rows(batch: ColumnarBatch, rows: int) -> ColumnarBatch:
+    """``batch``, whose ``rows`` rows (a count the HOST holds) are compact
+    from 0, at the capacity bucket of those rows: what runs above it pays
+    for slots, not rows. As it is where that is its capacity already."""
+    from ..batch import bucket_capacity
+    cap = bucket_capacity(max(rows, 1))
+    if cap >= batch.capacity:
+        return batch
+    return jit_named("slice_batch", slice_batch, static_argnums=3)(
+        batch, jnp.int32(0), jnp.int32(rows), cap)
 
 
 # ---------------------------------------------------------------------------

@@ -4,17 +4,19 @@ Reference: GpuKeyBatchingIterator.scala (236 LoC) — the reference splits a
 stream of batches on group-key boundaries so per-key operators (windows)
 never see a key straddling two batches and never hold an unbounded batch.
 
-TPU-first shape: instead of the reference's iterator that carries leftover
-rows between cudf batches, the whole stream partition is sorted by the
-keys ONCE (one lax.sort — windows need that sort anyway) and the group
-boundary positions come back to the host, which picks cut points on whole
-groups closest to the row target. Each emitted batch is a static-shape
-slice, so downstream kernels compile once per bucket size.
+TPU-first shape: a stream partition that fits the row target goes on as ONE
+batch, unsorted: whole as it stands, it holds every group whole (the window
+above sorts on partition and order keys whatever order it is given). Only a
+partition over the target is sorted by the keys ONCE (one key sort, a
+dictionary-encoded string key on its codes) and the group boundary
+positions come back to the host, which picks cut points on whole groups
+closest to the row target. Each emitted batch is a static-shape slice, so
+downstream kernels compile once per bucket size. The concatenation is sized
+by the rows the batches hold (the one host read), not by their capacities.
 
 What this bounds: the DOWNSTREAM operator's per-batch working set (window
 scans allocate several columns per expression over the batch). The
-batching sort itself still materializes the partition once — same peak as
-the previous concat-whole-partition behavior, not worse; a spill-aware
+batching sort itself still materializes the partition once; a spill-aware
 chunked pre-sort (through OutOfCoreSorter) is the refinement if window
 inputs ever exceed HBM on their own.
 """
@@ -28,12 +30,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
-from ..expressions.base import EvalContext, Expression
+from ..expressions.base import EvalContext, Expression, raw_eval
 from .base import UnaryExec
 from .basic import bind_all
-from .common import (KernelPrograms, adjacent_equal, concat_batches,
-                     gather_column, lex_sort_permutation, slice_batch,
-                     sort_operands)
+from .common import (KernelPrograms, adjacent_equal, concat_batches_encoded,
+                     cut_to_rows, gather_column, lex_sort_permutation,
+                     slice_batch, sort_operands)
 
 
 class KeyBatchingExec(UnaryExec):
@@ -50,7 +52,10 @@ class KeyBatchingExec(UnaryExec):
         self.target_rows = target_rows
 
         def prep(self, batch: ColumnarBatch):
-            key_cols = [e.eval(batch, self.ctx) for e in self.keys]
+            # raw_eval: a dictionary-encoded string key sorts and compares
+            # on its codes (one lane; within one batch code order is string
+            # order)
+            key_cols = [raw_eval(e, batch, self.ctx) for e in self.keys]
             live = batch.row_mask()
             k = len(key_cols)
             from .common import may_skip_null_lane
@@ -83,21 +88,28 @@ class KeyBatchingExec(UnaryExec):
         return repr(list(self.keys))
 
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
+        from .. import trace as qtrace
         batches = list(self.child.execute_partition(p))
-        if not batches:
-            return
-        total = sum(int(b.num_rows) for b in batches)
+        # the one host read: the rows each batch holds
+        rows = [int(b.num_rows) for b in batches]
+        total = sum(rows)
         if total == 0:
             return
-        if len(batches) == 1:
-            merged = batches[0]
+        held = [b for b, r in zip(batches, rows) if r]
+        # sized by the rows, not by the capacities
+        if len(held) > 1:
+            merged = concat_batches_encoded(held, bucket_capacity(total))
         else:
-            cap = bucket_capacity(sum(b.capacity for b in batches))
-            merged = concat_batches(batches, cap)
-        srt, new_group = self._prep_jit(merged)
+            merged = cut_to_rows(held[0], total)
+        qtrace.count(keyBatchRowsIn=total)
         if total <= self.target_rows:
-            yield srt
+            # the whole partition in one batch holds every group whole as it
+            # stands: nothing to cut, so nothing to sort (the window above
+            # sorts on partition AND order keys whatever order it is given)
+            yield merged
             return
+        qtrace.count(keyBatchSlotsSorted=int(merged.capacity))
+        srt, new_group = self._prep_jit(merged)
         # group start positions -> host; cut on whole groups at the LAST
         # start that keeps the batch <= target_rows (a batch exceeds the
         # target only when one single group does — the same bound
@@ -112,6 +124,7 @@ class KeyBatchingExec(UnaryExec):
             prev = int(s)
         if cuts[-1] != n:
             cuts.append(n)
+        qtrace.count(keyBatchCuts=len(cuts) - 2)
         for lo, hi in zip(cuts, cuts[1:]):
             if hi > lo:
                 yield self._slice_jit(srt, lo, hi - lo,

@@ -21,11 +21,16 @@ import jax
 import jax.numpy as jnp
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
-from ..expressions.base import EvalContext, Expression
+from ..expressions.base import EvalContext, Expression, raw_eval
 from .base import Exec, UnaryExec
 from .basic import bind_all
-from .common import KernelPrograms, concat_batches, gather, gather_column, \
-    slice_batch, sort_permutation
+from .common import KernelPrograms, concat_batches, cut_to_rows, gather, \
+    gather_column, slice_batch, sort_permutation
+
+
+#: a batch of at most this many slots is sorted as it stands: its key
+#: passes cost less than the host read that would size it by its rows
+_SORT_AS_IS_SLOTS = 1 << 14
 
 
 @dataclass(frozen=True)
@@ -62,7 +67,9 @@ def sort_batch(batch: ColumnarBatch, orders: Sequence[SortOrder],
                ctx: EvalContext = EvalContext()) -> ColumnarBatch:
     """Stable in-core sort of one batch (jit-traceable)."""
     live = batch.row_mask()
-    key_cols = [o.child.eval(batch, ctx) for o in orders]
+    # raw_eval: a dictionary-encoded string key sorts on its codes, one
+    # lane (within one batch code order is string order)
+    key_cols = [raw_eval(o.child, batch, ctx) for o in orders]
     perm = sort_permutation(batch, key_cols,
                             [o.descending for o in orders],
                             [o.effective_nulls_first for o in orders])
@@ -127,8 +134,8 @@ class SortExec(UnaryExec):
             return
         try:
             if len(spillables) == 1:
-                yield self._sort_jit(acquire_with_retry(
-                    spillables[0], name=self.name))
+                yield self._sort_jit(self._cut_to_rows(acquire_with_retry(
+                    spillables[0], name=self.name)))
                 spillables[0].done_with()
                 return
 
@@ -155,11 +162,24 @@ class SortExec(UnaryExec):
                                          device_budget())
                 yield from sorter.sort(iter(caps))
                 return
-            merged = concat_batches(caps, bucket_capacity(total_cap))
+            # sized by the rows held, not by the capacities
+            rows = sum(int(b.num_rows) for b in caps)
+            merged = concat_batches(caps, bucket_capacity(max(rows, 1)))
             yield self._sort_jit(merged)
         finally:
             for sb in spillables:
                 sb.close()
+
+
+    @staticmethod
+    def _cut_to_rows(batch: ColumnarBatch) -> ColumnarBatch:
+        """A global sort waits for all of its input anyway: read the rows
+        the one batch holds and sort at THEIR capacity bucket. What is left
+        of a selective filter (a hundred rows in 2^20 slots) would
+        otherwise pay every key lane's pass at the filter's capacity."""
+        if batch.capacity <= _SORT_AS_IS_SLOTS:
+            return batch        # cheaper to sort than to wait for a count
+        return cut_to_rows(batch, int(batch.num_rows))
 
 
 class TakeOrderedAndProjectExec(UnaryExec):

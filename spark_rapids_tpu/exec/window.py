@@ -21,14 +21,14 @@ import jax.numpy as jnp
 from .. import types as T
 from ..batch import ColumnarBatch, DeviceColumn, Field, Schema, bucket_capacity
 from ..expressions.aggregates import _cumsum as prefix_sum
-from ..expressions.base import Alias, EvalContext, Expression
+from ..expressions.base import Alias, EvalContext, Expression, raw_eval
 from ..expressions.window import (LagLead, NTile, Rank, RowNumber,
                                   WindowAgg, WindowExpression, WindowFrame,
                                   segmented_scan)
 from ..types import TypeKind
 from .base import Exec, UnaryExec
-from .common import adjacent_equal, concat_batches, gather_column, \
-    jit_named, lex_sort_permutation, sort_operands
+from .common import KernelPrograms, adjacent_equal, concat_batches_encoded, \
+    gather_column, lex_sort_permutation, sort_operands
 
 
 def _unalias(e: Expression) -> Tuple[WindowExpression, str]:
@@ -74,8 +74,10 @@ class WindowExec(UnaryExec):
         for w, n in zip(self.exprs, self.names):
             fields.append(Field(n, w.dtype, w.nullable))
         self._schema = Schema(fields)
-        self._kernel = jit_named(f"{type(self).__name__}_window",
-                                 self._window_kernel)
+        # everything the kernel reads of this exec: the program's key, and
+        # all its stand-in has (common.KernelPrograms)
+        self._kernel = KernelPrograms(self, ("exprs", "spec")).jit(
+            "window", type(self)._window_kernel)
 
     @property
     def output_schema(self) -> Schema:
@@ -87,8 +89,11 @@ class WindowExec(UnaryExec):
         cap = batch.capacity
         spec = self.spec
         live = batch.row_mask()
-        pkeys = [e.eval(batch, self.ctx) for e in spec.partition_keys]
-        okeys = [o.child.eval(batch, self.ctx) for o in spec.orders]
+        # raw_eval: a dictionary-encoded string key sorts and compares on
+        # its codes, one lane (within one column of one batch code order is
+        # string order: dictenc.py invariant 2)
+        pkeys = [raw_eval(e, batch, self.ctx) for e in spec.partition_keys]
+        okeys = [raw_eval(o.child, batch, self.ctx) for o in spec.orders]
 
         ops = sort_operands(
             list(pkeys) + list(okeys),
@@ -101,10 +106,6 @@ class WindowExec(UnaryExec):
         s_pkeys = [gather_column(c, perm) for c in pkeys]
         s_okeys = [gather_column(c, perm) for c in okeys]
         sorted_live = iota < batch.num_rows
-        # trace-scoped context for value-bounded RANGE ranking (the merge
-        # rank re-evaluates the order column; XLA CSEs the duplicate)
-        self._range_batch = batch
-        self._range_perm = perm
 
         if s_pkeys:
             same_part = adjacent_equal(s_pkeys)
@@ -122,13 +123,13 @@ class WindowExec(UnaryExec):
             same_peer = same_part
         peer_head = sorted_live & ~same_peer
 
-        out_cols = []
-        for w in self.exprs:
-            col = self._eval_window(w, batch, perm, head, tail, peer_head,
-                                    sorted_live, cap)
-            # scatter back to original row order
-            inv = jnp.zeros(cap, jnp.int32).at[perm].set(iota)
-            out_cols.append(gather_column(col, inv, batch.row_mask()))
+        # back to the original row order: one inverse permutation for all
+        inv = jnp.zeros(cap, jnp.int32).at[perm].set(iota)
+        out_cols = [
+            gather_column(self._eval_window(w, batch, perm, head, tail,
+                                            peer_head, sorted_live, cap),
+                          inv, live)
+            for w in self.exprs]
         return ColumnarBatch(batch.columns + tuple(out_cols), batch.num_rows)
 
     # ------------------------------------------------------------------
@@ -177,7 +178,8 @@ class WindowExec(UnaryExec):
             src = fn.child.eval(batch, self.ctx)
             s = gather_column(src, perm)
             lo, hi = self._frame_bounds(w.spec.frame, head, tail,
-                                        peer_head, live, iota, cap)
+                                        peer_head, live, iota, cap,
+                                        (batch, perm))
             idx = lo + fn.n - 1
             ok = (idx <= hi) & (idx >= lo) & live
             v = gather_column(s, jnp.clip(idx, 0, cap - 1))
@@ -212,7 +214,9 @@ class WindowExec(UnaryExec):
                     validity = jnp.where(use_d, dcol.validity, validity)
                     return DeviceColumn(data, validity & live, lengths,
                                         fn.dtype)
-                data = jnp.where(use_d, dcol.data, data)
+                # (a decimal past 18 digits is a limb matrix)
+                data = jnp.where(use_d[:, None] if data.ndim > 1 else use_d,
+                                 dcol.data, data)
                 validity = jnp.where(use_d, dcol.validity, validity)
             return DeviceColumn(data, validity & live, sv.lengths, fn.dtype)
         if isinstance(fn, WindowAgg):
@@ -243,7 +247,7 @@ class WindowExec(UnaryExec):
             out_t = T.INT64
             v, valid = self._frame_reduce(x, jnp.add, jnp.int64(0), frame,
                                           head, tail, peer_head, live, iota,
-                                          cap)
+                                          cap, (batch, perm))
             return DeviceColumn(v, live, None, out_t)
         if isinstance(agg, (Sum, Average)):
             acc_t = jnp.float64 if isinstance(agg, Average) or \
@@ -252,10 +256,12 @@ class WindowExec(UnaryExec):
             ok = col.validity & live
             x = jnp.where(ok, col.data, 0).astype(acc_t)
             s, _ = self._frame_reduce(x, jnp.add, acc_t(0), frame, head,
-                                      tail, peer_head, live, iota, cap)
+                                      tail, peer_head, live, iota, cap,
+                                      (batch, perm))
             n, _ = self._frame_reduce(ok.astype(jnp.int64), jnp.add,
                                       jnp.int64(0), frame, head, tail,
-                                      peer_head, live, iota, cap)
+                                      peer_head, live, iota, cap,
+                                      (batch, perm))
             if isinstance(agg, Average):
                 v = s / jnp.maximum(n, 1).astype(jnp.float64)
                 return DeviceColumn(jnp.where(n > 0, v, 0.0),
@@ -281,18 +287,21 @@ class WindowExec(UnaryExec):
                 op = jnp.minimum if is_min else jnp.maximum
                 x = jnp.where(ok, col.data, fill)
             v, _ = self._frame_reduce(x, op, fill, frame, head, tail,
-                                      peer_head, live, iota, cap)
+                                      peer_head, live, iota, cap,
+                                      (batch, perm))
             n, _ = self._frame_reduce(ok.astype(jnp.int64), jnp.add,
                                       jnp.int64(0), frame, head, tail,
-                                      peer_head, live, iota, cap)
+                                      peer_head, live, iota, cap,
+                                      (batch, perm))
             valid = (n > 0) & live
             return DeviceColumn(jnp.where(valid, v, jnp.zeros_like(v)),
                                 valid, None, agg.dtype)
         raise NotImplementedError(type(agg).__name__)
 
     def _frame_reduce(self, x, op, identity, frame: WindowFrame, head, tail,
-                      peer_head, live, iota, cap):
-        """Reduce x over each row's frame; returns (values, None)."""
+                      peer_head, live, iota, cap, src):
+        """Reduce x over each row's frame; returns (values, None). ``src``:
+        the (batch, sort permutation) the sorted layout came from."""
         if frame.is_full_partition:
             # segment total broadcast back: forward running to tail, gather
             run = segmented_scan(x, head, op)
@@ -327,7 +336,7 @@ class WindowExec(UnaryExec):
         # prefix-difference (sums) or sparse-table (min/max) reduction
         # (reference: GpuWindowExec.scala:1846 double-pass machinery)
         lo, hi = self._frame_bounds(frame, head, tail, peer_head, live,
-                                    iota, cap)
+                                    iota, cap, src)
         return self._reduce_between(x, op, identity, lo, hi, head, cap), None
 
     # ------------------------------------------------------------------
@@ -342,7 +351,7 @@ class WindowExec(UnaryExec):
         return seg_start, seg_end
 
     def _frame_bounds(self, frame: WindowFrame, head, tail, peer_head,
-                      live, iota, cap):
+                      live, iota, cap, src):
         """Absolute sorted-layout [lo, hi] index bounds of each row's
         frame (hi < lo = empty). ROWS bounds are positional; RANGE bounds
         with nonzero offsets rank shifted ORDER VALUES into the sorted
@@ -368,18 +377,18 @@ class WindowExec(UnaryExec):
             lo = peer_start
         else:
             lo = self._range_rank(frame.start, True, head, peer_start,
-                                  peer_end, live, iota, cap)
+                                  peer_end, live, iota, cap, src)
         if frame.end is None:
             hi = seg_end
         elif frame.end == 0:
             hi = peer_end
         else:
             hi = self._range_rank(frame.end, False, head, peer_start,
-                                  peer_end, live, iota, cap)
+                                  peer_end, live, iota, cap, src)
         return lo, hi
 
     def _range_rank(self, delta: int, is_lo: bool, head, peer_start,
-                    peer_end, live, iota, cap):
+                    peer_end, live, iota, cap, src):
         """Rank each row's shifted order value among the partition's rows:
         lo = first index with value >= v+delta, hi = last index with
         value <= v+delta. One (pid, null-rank, word, tag, iota) merge sort
@@ -392,9 +401,8 @@ class WindowExec(UnaryExec):
         o = spec.orders[0]
         # evaluated + sorted order column (CSE'd with the kernel's own
         # sort by XLA — identical subgraphs)
-        batch = self._range_batch
-        col = o.child.eval(batch, self.ctx)
-        col = gather_column(col, self._range_perm)
+        batch, perm = src
+        col = gather_column(o.child.eval(batch, self.ctx), perm)
         data = col.data
 
         def one_word(d):
@@ -507,10 +515,14 @@ class WindowExec(UnaryExec):
         # child guarantees that with bounded batch sizes (reference:
         # GpuKeyBatchingIterator) — process batch-at-a-time; otherwise
         # concat the stream partition into one batch.
+        from .. import trace as qtrace
+        qtrace.count(windowExprs=len(self.exprs))
         guarantee = getattr(self.child, "key_complete_for", None)
         if guarantee is not None and \
                 guarantee == repr(list(self.spec.partition_keys)):
             for batch in self.child.execute_partition(p):
+                qtrace.count(windowBatches=1,
+                             windowSlots=int(batch.capacity))
                 yield self._kernel(batch)
             return
         # accumulated input batches ride the spill catalog across the
@@ -530,10 +542,15 @@ class WindowExec(UnaryExec):
             try:
                 for item in inputs:
                     got.append(item.acquire())
-                if len(got) == 1:
-                    return self._kernel(got[0])
-                cap = bucket_capacity(sum(b.capacity for b in got))
-                return self._kernel(concat_batches(got, cap))
+                whole = got[0]
+                if len(got) > 1:
+                    # sized by the rows held, not by the capacities
+                    rows = sum(int(b.num_rows) for b in got)
+                    whole = concat_batches_encoded(
+                        got, bucket_capacity(max(rows, 1)))
+                qtrace.count(windowBatches=1,
+                             windowSlots=int(whole.capacity))
+                return self._kernel(whole)
             finally:
                 for j in range(len(got)):
                     inputs[j].release()

@@ -286,11 +286,16 @@ class WindowAgg(WindowFunction):
     def device_unsupported_reason(self):
         # the frame reductions (exec/window.py) accumulate in int64 or
         # double: no limb sums, no decimal division
+        from .decimal128 import is_dec128
         name, t = type(self.agg).__name__, self.agg.dtype
         if t.kind is TypeKind.DECIMAL and (
                 name == "Average" or (name == "Sum" and t.precision > 18)):
             return (f"{name.lower()} over a window returning {t}: the "
                     f"window frames have no decimal128 kernel")
+        if name != "Count" and any(is_dec128(c.dtype)
+                                   for c in self.agg.children):
+            return (f"{name.lower()} over a window of {t}: the window "
+                    f"frames have no decimal128 kernel")
         return None
 
     @property

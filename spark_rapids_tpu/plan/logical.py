@@ -236,6 +236,12 @@ class DataFrame:
         keys = [col(k) if isinstance(k, str) else k for k in keys]
         return GroupedData(self.plan, keys)
 
+    def rollup(self, *keys) -> "RollupData":
+        """GROUP BY ROLLUP(keys): ``.agg(...)`` gives one row a group of
+        every prefix of ``keys``, the keys past the prefix null."""
+        keys = [col(k) if isinstance(k, str) else k for k in keys]
+        return RollupData(self.plan, keys)
+
     def agg(self, *aggs) -> "DataFrame":
         return GroupedData(self.plan, []).agg(*aggs)
 
@@ -296,6 +302,51 @@ class GroupedData:
 
     def agg(self, *aggs) -> DataFrame:
         return DataFrame(LogicalAggregate((self.plan,), self.keys, list(aggs)))
+
+
+GROUPING_ID = "spark_grouping_id"
+
+
+class RollupData:
+    """What Spark's analyzer plans for ``GROUP BY ROLLUP(k1..kn)``: an
+    Expand of n+1 projections, each the columns the aggregates read, the
+    keys with the trailing ones replaced by typed nulls, and
+    ``spark_grouping_id`` (bit n-1-i set where key i is nulled, so 0 for
+    the finest set and 2^n - 1 for the grand total), under ONE aggregate on
+    keys + id; the id is projected away."""
+
+    def __init__(self, plan: LogicalPlan, keys: List[Expression]):
+        self.plan = plan
+        self.keys = keys
+
+    def agg(self, *aggs) -> DataFrame:
+        from ..exec.basic import output_name
+        schema = self.plan.schema()
+        bound = [k.bind(schema) for k in self.keys]
+        names = [output_name(b, i) for i, b in enumerate(bound)]
+        n = len(names)
+        from ..io.parquet import _referenced_columns
+        read = list(dict.fromkeys(
+            c for a in aggs for c in _referenced_columns(a)))
+        clash = sorted(set(read) & set(names))
+        if clash:
+            # Spark gives the nulled keys new attributes; here a column is
+            # found by name, so one name cannot be both a key and an input
+            raise ValueError(f"rollup keys {clash} are also aggregated: "
+                             f"project the aggregate's input under another "
+                             f"name first")
+        projections = [
+            [col(r) for r in read]
+            + [Alias(k if i < kept else lit(None, b.dtype), name)
+               for i, (k, b, name) in enumerate(zip(self.keys, bound, names))]
+            + [Alias(lit((1 << (n - kept)) - 1), GROUPING_ID)]
+            for kept in range(n, -1, -1)]
+        expand = LogicalExpand((self.plan,), projections)
+        grouped = LogicalAggregate(
+            (expand,), [col(x) for x in names + [GROUPING_ID]], list(aggs))
+        out = grouped.schema().names
+        return DataFrame(LogicalProject(
+            (grouped,), [col(x) for x in out if x != GROUPING_ID]))
 
 
 def table(data: pa.Table, num_slices: int = 1,

@@ -679,7 +679,7 @@ EXEC_SIGS: Dict[str, TypeSig] = {
     "Range": TS.ALL_BASIC,
     "Expand": TS.ALL_BASIC + TS.ARRAY + TS.MAP + TS.STRUCT,
     "Sample": TS.ALL_BASIC + TS.ARRAY + TS.MAP + TS.STRUCT,
-    "Window": TS.ALL_BASIC + TS.STRUCT,
+    "Window": TS.ALL_BASIC + TS.STRUCT + TS.DECIMAL_128,
     "Generate": TS.ALL_BASIC + TS.ARRAY + TS.MAP,
 }
 
@@ -1049,9 +1049,19 @@ class Overrides:
             return HashAggregateExec(n.group_exprs, n.agg_exprs, child,
                                      AggregateMode.COMPLETE,
                                      max_result_rows=agg_rows)
+        levels = _rollup_levels(n, child)
+        if levels is not None:
+            # a rollup: the Expand makes the finest level alone, and the
+            # coarser ones are merged from its partials (RollupExec)
+            child = ExpandExec(n.children[0].projections, child.child,
+                               ctx=child.ctx, emit=(0,))
         partial = HashAggregateExec(n.group_exprs, n.agg_exprs, child,
                                     AggregateMode.PARTIAL,
                                     max_result_rows=agg_rows)
+        if levels is not None:
+            from ..exec.aggregate import RollupExec
+            partial = RollupExec(levels, n.group_exprs, n.agg_exprs, partial,
+                                 max_result_rows=agg_rows)
         if n.group_exprs and self._partitioned(child):
             from ..expressions.base import col
             key_cols = [col(f.name) for f in partial.key_fields]
@@ -1256,6 +1266,62 @@ class Overrides:
                      for i, f in enumerate(ch[1].output_schema.fields)]
             join = ProjectExec(refs, join)
         return join
+
+
+def _rollup_levels(n: L.LogicalAggregate, child: Exec):
+    """Where ``child`` is an Expand whose projections are the levels of a
+    rollup over ``n``'s grouping keys, finest first (projection j is
+    projection j-1 with further keys replaced by null literals and other
+    integer literals, ``spark_grouping_id``, on keys that are literals
+    throughout): for each coarser level ``(ordinals of the keys it nulls,
+    {ordinal: value} of its literal keys)``, else None. Grouping sets that
+    do not nest (a cube) and anything else this does not recognise keep the
+    plain Expand."""
+    from ..expressions.base import Literal, UnresolvedColumn
+    if not isinstance(child, ExpandExec) \
+            or not isinstance(n.children[0], L.LogicalExpand) \
+            or len(child.projections) < 2 or child.emit != tuple(range(len(child.projections))):
+        return None
+    names = child.output_schema.names
+    ordinal = {}                # Expand column -> position among the keys
+    for k, g in enumerate(n.group_exprs):
+        if not isinstance(g, UnresolvedColumn) or g.name not in names \
+                or names.index(g.name) in ordinal:
+            return None
+        ordinal[names.index(g.name)] = k
+    first = child.projections[0]
+
+    def bare(e):
+        while isinstance(e, Alias):
+            e = e.child
+        return e
+
+    def is_lit(e, null):
+        e = bare(e)
+        return isinstance(e, Literal) and (e.value is None) == null and (
+            null or (isinstance(e.value, int) and e.dtype.is_integral))
+
+    literal = {c for c in ordinal if is_lit(first[c], False)}
+    if any(is_lit(first[c], True) for c in ordinal):
+        return None
+    levels, before = [], set()
+    for proj in child.projections[1:]:
+        nulled = set()
+        for c, (e, e0) in enumerate(zip(proj, first)):
+            if c in literal:
+                if not is_lit(e, False):
+                    return None
+            elif c in ordinal and is_lit(e, True):
+                nulled.add(c)
+            elif repr(e) != repr(e0):
+                return None
+        if not nulled > before:
+            return None         # not nested: no level to merge it from
+        before = nulled
+        levels.append((frozenset(ordinal[c] for c in nulled),
+                       {ordinal[c]: int(bare(proj[c]).value)
+                        for c in literal}))
+    return levels
 
 
 def _expr_passthrough_name(expr, child_schema):

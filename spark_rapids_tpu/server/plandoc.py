@@ -97,6 +97,19 @@ def _plain_dataclasses() -> Dict[str, type]:
     return _PLAIN_DATACLASSES
 
 
+def _late_expression(name: str) -> Optional[type]:
+    """An expression class registers itself when its module is imported,
+    and a server imports a module only when a plan needs it: a window
+    function's first arrival finds ``expressions/window.py`` not yet
+    imported. Import the package's modules (once) and look again."""
+    import importlib
+    import pkgutil
+    from .. import expressions
+    for m in pkgutil.iter_modules(expressions.__path__):
+        importlib.import_module(f"{expressions.__name__}.{m.name}")
+    return Expression._registry.get(name)
+
+
 def _file_sources() -> Dict[str, type]:
     from ..io.avro import AvroSource
     from ..io.csv import CsvSource
@@ -186,7 +199,7 @@ def decode_value(v: Any, path: str = "$") -> Any:
                 "-inf": -math.inf}[payload]
     if tag == "$e":
         name, *args = payload
-        cls = Expression._registry.get(name)
+        cls = Expression._registry.get(name) or _late_expression(name)
         if cls is None:
             raise PlanDecodeError(f"unknown expression class {name}",
                                   path)

@@ -119,12 +119,20 @@ def to_trace_events(profiles: Iterable[dict],
     return events
 
 
+#: the counters of a rollup and its window: a table of their own, printed
+#: only where a span carries one
+_ROLLUP_WINDOW = ("expandProjections", "expandBatchesOut", "expandSlotsOut",
+                  "rollupLevels", "rollupSlotsMerged",
+                  "keyBatchRowsIn", "keyBatchSlotsSorted", "keyBatchCuts",
+                  "windowBatches", "windowSlots", "windowExprs")
+
+
 #: span attributes that are counts: a name's row sums them over its spans
 _SUMMED = ("pulls", "lowerings", "programHits", "programMisses",
            "dec128Columns", "dec128Bytes",
            "partialsCut", "partialRowsMade", "partialRowsKept",
            "splitBatches", "splitPieces", "splitRowsSorted",
-           "splitRowsGathered")
+           "splitRowsGathered") + _ROLLUP_WINDOW
 
 
 def self_time_table(profile: dict) -> List[dict]:
@@ -141,7 +149,16 @@ def self_time_table(profile: dict) -> List[dict]:
     ``partialRowsKept``), and the exchanges' splits: batches split, pieces
     made, slots ordered (a batch's capacity, once) and slots gathered (the
     pieces' summed capacities) (``splitBatches`` / ``splitPieces`` /
-    ``splitRowsSorted`` / ``splitRowsGathered``)."""
+    ``splitRowsSorted`` / ``splitRowsGathered``), and a rollup and its
+    window (``print_tables``' second table): the projections an Expand
+    makes, the batches and slots it hands on (``expandProjections`` /
+    ``expandBatchesOut`` / ``expandSlotsOut``), the levels a ``RollupExec``
+    merged from partials and the slots it merged them at (``rollupLevels``
+    / ``rollupSlotsMerged``), the rows a ``KeyBatchingExec`` took in, the
+    slots it had to sort and the cuts it made (``keyBatchRowsIn`` /
+    ``keyBatchSlotsSorted`` / ``keyBatchCuts``), and a ``WindowExec``'s
+    batches, their slots and its expressions (``windowBatches`` /
+    ``windowSlots`` / ``windowExprs``)."""
     import os
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), os.pardir))
@@ -181,7 +198,8 @@ def print_tables(profiles: Iterable[dict],
               f"{'splits':>8}{'pieces':>8}{'slots sorted':>14}"
               f"{'slots gathered':>16}"
               f"{'partials cut':>13}{'rows made':>11}{'rows kept':>11}")
-        for r in self_time_table(prof):
+        table = self_time_table(prof)
+        for r in table:
             print(f"{r['name']:<34}{r['spans']:>4}{r['insideMs']:>12.1f}"
                   f"{r['selfMs']:>12.1f}{r['pulls']:>7}"
                   f"{r['lowerings']:>10}{r['relowerMs']:>12.1f}"
@@ -192,6 +210,13 @@ def print_tables(profiles: Iterable[dict],
                   f"{r['splitRowsGathered']:>16}"
                   f"{r['partialsCut']:>13}{r['partialRowsMade']:>11}"
                   f"{r['partialRowsKept']:>11}")
+        rolled = [r for r in table if any(r[k] for k in _ROLLUP_WINDOW)]
+        if rolled:
+            print(f"{'span':<34}" + "".join(
+                f"{k:>{len(k) + 2}}" for k in _ROLLUP_WINDOW))
+            for r in rolled:
+                print(f"{r['name']:<34}" + "".join(
+                    f"{r[k]:>{len(k) + 2}}" for k in _ROLLUP_WINDOW))
 
 
 def main(argv=None) -> int:
