@@ -55,6 +55,11 @@ class JoinType(enum.Enum):
     CROSS = "Cross"
 
 
+#: the least slots of one part of a pair batch cut to a bound: a probe
+#: batch smaller than this still makes parts of this size, so that a hot
+#: key's fan-out is not paid in launches
+PAIR_PART_MIN_SLOTS = 1 << 18
+
 _PAIR_TYPES = (JoinType.INNER, JoinType.LEFT_OUTER, JoinType.RIGHT_OUTER,
                JoinType.FULL_OUTER)
 
@@ -211,6 +216,8 @@ class HashJoinExec(BinaryExec):
         self._count_jit = programs.jit("count", cls._count_kernel)
         self._expand_jit = programs.jit("expand", cls._expand_kernel,
                                         static_argnums=(4,))
+        self._expand_part_jit = programs.jit(
+            "expandPart", cls._expand_part_kernel, static_argnums=(4,))
         self._semi_jit = programs.jit("semi", cls._semi_kernel,
                                       static_argnums=(4,))
 
@@ -397,11 +404,17 @@ class HashJoinExec(BinaryExec):
         return {rk.ordinal: key_col_at_pairs.replace(
             validity=key_col_at_pairs.validity & pair_ok)}
 
-    def _gather_pairs(self, stream, build, lo, counts, offsets, out_cap):
+    def _gather_pairs(self, stream, build, lo, counts, offsets, out_cap,
+                      base=None):
         """Candidate pair gather + key verification (+ condition).
         ``build`` is the build-kernel's SORTED build batch, so candidate
-        positions index it directly (no perm indirection)."""
+        positions index it directly (no perm indirection). ``base`` (a
+        traced scalar) makes the batch candidates ``[base, base +
+        out_cap)`` of the probe batch's, in the same order: one part of a
+        pair batch cut to a bound (``_probe``)."""
         j = jnp.arange(out_cap, dtype=jnp.int32)
+        if base is not None:
+            j = j + base
         total = offsets[-1]
         probe_row = jnp.searchsorted(offsets, j, side="right",
                                      method="scan").astype(jnp.int32)
@@ -428,7 +441,9 @@ class HashJoinExec(BinaryExec):
         pair_ok = in_range if self._exact_probe \
             else in_range & _keys_equal(s_keys, b_keys)
         if self.condition is not None:
-            pair_batch = ColumnarBatch(tuple(s_cols + b_cols), total)
+            rows = total if base is None else \
+                jnp.clip(total - base, 0, out_cap).astype(jnp.int32)
+            pair_batch = ColumnarBatch(tuple(s_cols + b_cols), rows)
             c = self.condition.eval(pair_batch, self.ctx)
             pair_ok = pair_ok & c.data & c.validity
         return s_cols, b_cols, pair_ok, probe_row, build_row
@@ -511,9 +526,10 @@ class HashJoinExec(BinaryExec):
             batch.num_rows)
 
     def _expand_general(self, stream, build, lo,
-                        counts, offsets, matched_build_in, out_cap: int):
+                        counts, offsets, matched_build_in, out_cap: int,
+                        base=None):
         s_cols, b_cols, pair_ok, probe_row, build_row = self._gather_pairs(
-            stream, build, lo, counts, offsets, out_cap)
+            stream, build, lo, counts, offsets, out_cap, base)
 
         # compact verified pairs to the front
         pairs = compact(ColumnarBatch(tuple(s_cols + b_cols),
@@ -578,6 +594,14 @@ class HashJoinExec(BinaryExec):
             unique = jnp.max(counts) <= 1
             return jax.lax.cond(unique, unique_fn, general_fn)
         return general_fn()
+
+    def _expand_part_kernel(self, stream, build, lo_counts, base,
+                            out_cap: int):
+        """INNER join: candidates ``[base, base + out_cap)`` of a probe
+        batch as one pair batch, verified pairs compacted to the front."""
+        lo, counts, offsets = lo_counts
+        return self._expand_general(stream, build, lo, counts, offsets,
+                                    None, out_cap, base)[0]
 
     def _semi_kernel(self, stream, build, lo_counts, matched_build_in,
                      out_cap: int):
@@ -744,6 +768,7 @@ class HashJoinExec(BinaryExec):
         with_retry_no_split; each probe batch runs under with_retry with
         halving — a half-stream probes to the same pairs in the same
         stream-row order, so concatenated outputs are bit-for-bit."""
+        from .. import trace as qtrace
         from ..batch import empty_batch
         from ..memory import (SpillableInput, admit_all, device_budget,
                               split_input_halves, with_retry,
@@ -780,6 +805,9 @@ class HashJoinExec(BinaryExec):
 
         semi = self.join_type in (JoinType.LEFT_SEMI, JoinType.LEFT_ANTI,
                                   JoinType.EXISTENCE)
+        # the exact-probe semi join without a condition counts matches and
+        # makes no pair batch: its program takes no pair capacity
+        pairless = semi and self._exact_probe and self.condition is None
         stream_schema = self.left.output_schema
 
         def probe_body(item: SpillableInput):
@@ -793,12 +821,26 @@ class HashJoinExec(BinaryExec):
                         f"one probe batch exceeds the int32 offset range; "
                         f"reduce the batch size or pre-aggregate the "
                         f"build side")
-                out_cap = bucket_capacity(max(total_i, 1))
+                out_cap = 0 if pairless else bucket_capacity(max(total_i, 1))
+                part = max(b.capacity, PAIR_PART_MIN_SLOTS)
+                lo_counts = (lo, counts, offsets)
                 if semi:
-                    return self._semi_jit(b, sbuild, (lo, counts, offsets),
-                                          matched_build, out_cap), None
-                return self._expand_jit(b, sbuild, (lo, counts, offsets),
-                                        matched_build, out_cap)
+                    outs = [(self._semi_jit(b, sbuild, lo_counts,
+                                            matched_build, out_cap), None)]
+                elif self.join_type is JoinType.INNER and out_cap > part:
+                    # a join whose pairs are many times its probe batch:
+                    # pair batches of at most the probe batch's capacity,
+                    # consecutive ranges of the candidates, in their order
+                    outs = [(self._expand_part_jit(b, sbuild, lo_counts,
+                                                   jnp.int32(base), part),
+                             None) for base in range(0, total_i, part)]
+                    out_cap = part * len(outs)
+                else:
+                    outs = [self._expand_jit(b, sbuild, lo_counts,
+                                             matched_build, out_cap)]
+                qtrace.count(joinProbeBatches=1, joinCandidates=total_i,
+                             joinSlotsOut=out_cap)
+                return outs
             finally:
                 item.release()
 
@@ -809,13 +851,14 @@ class HashJoinExec(BinaryExec):
             # already measured over the skew row target pre-splits
             # through the same split-and-retry machinery instead of
             # OOMing its way down to size
-            for out, mb in with_retry(inp, probe_body,
-                                      split=split_input_halves,
-                                      catalog=cat, name=self.name,
-                                      presplit_rows=self.skew_split_rows):
-                if mb is not None:
-                    matched_build = mb
-                yield out
+            for outs in with_retry(inp, probe_body,
+                                   split=split_input_halves,
+                                   catalog=cat, name=self.name,
+                                   presplit_rows=self.skew_split_rows):
+                for out, mb in outs:
+                    if mb is not None:
+                        matched_build = mb
+                    yield out
 
         if self.join_type in (JoinType.RIGHT_OUTER, JoinType.FULL_OUTER):
             # matched state lives in SORTED build space; the tail reads
@@ -851,6 +894,8 @@ class HashJoinExec(BinaryExec):
         cat = device_budget()
         build_rows = sum(int(b.num_rows) for b in build_batches)
         n_buckets = -(-build_rows // self.max_build_rows)
+        from .. import trace as qtrace
+        qtrace.count(graceBuckets=n_buckets)
 
         programs = KernelPrograms(self, ("left_keys", "right_keys"),
                                   also=[n_buckets])

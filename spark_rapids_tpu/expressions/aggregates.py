@@ -1256,6 +1256,31 @@ class Last(First):
     _take_last = True
 
 
+class CountDistinct(AggregateFunction):
+    """count(DISTINCT x): bigint, never null, the non-null distinct values
+    of ``x`` in the group. No kernel of its own: the planner rewrites an
+    aggregate that holds it as Spark 3.3 plans one distinct column
+    (``AggUtils.planAggregateWithOneDistinct``): an aggregate on the
+    grouping keys and ``x`` that carries the other aggregates' partials,
+    then one on the grouping keys where each (keys, x) group counts 1 if
+    ``x`` is not null and the partials merge (``plan/overrides.py``,
+    ``_convert_distinct``). The row interpreter counts it directly."""
+
+    @property
+    def dtype(self):
+        return T.INT64
+
+    @property
+    def nullable(self):
+        return False
+
+    def buffer_types(self):
+        return [T.INT64]
+
+    def buffer_nullable(self):
+        return [False]
+
+
 # convenience constructors mirroring pyspark.sql.functions
 def sum_(e) -> Sum:            # noqa: A001
     return Sum(e)
@@ -1263,6 +1288,10 @@ def sum_(e) -> Sum:            # noqa: A001
 
 def count(e=None) -> Count:
     return Count(e)
+
+
+def count_distinct(e) -> CountDistinct:
+    return CountDistinct(e)
 
 
 def min_(e) -> Min:

@@ -1581,6 +1581,8 @@ class Interpreter:
         nn = [x for x in xs if x is not None]
         if name == "Count":
             return len(nn) if child is not None else len(grp_rows)
+        if name == "CountDistinct":
+            return len({RowEvaluator._ordkey(x) for x in nn})
         if name == "Sum":
             if not nn:
                 return None

@@ -178,6 +178,8 @@ def _expr_rules() -> Dict[str, ExprRule]:
     # is the only thing the segment count reads)
     r("Count", TS.ALL_BASIC + TS.DECIMAL_128 + TS.ARRAY + TS.MAP
       + TS.STRUCT)
+    # a grouping key of the first of the two aggregates it plans as
+    r("CountDistinct", TS.ALL_BASIC + TS.DECIMAL_128)
     for n in ("Min", "Max"):
         r(n, TS.ALL_BASIC + TS.DECIMAL_128)
     # first/last are pure gathers; any layout rides through
@@ -1037,6 +1039,8 @@ class Overrides:
         from ..expressions.base import Alias as _Alias
         raw_aggs = [e.child if isinstance(e, _Alias) else e
                     for e in n.agg_exprs]
+        if any(isinstance(a, AGG.CountDistinct) for a in raw_aggs):
+            return self._convert_distinct(n, child, agg_rows)
         if any(not getattr(a, "supports_partial", True) for a in raw_aggs):
             if self._partitioned(child):
                 if n.group_exprs:
@@ -1074,6 +1078,89 @@ class Overrides:
             ex = partial
         return HashAggregateExec(n.group_exprs, n.agg_exprs, ex,
                                  AggregateMode.FINAL,
+                                 max_result_rows=agg_rows)
+
+    def _convert_distinct(self, n: L.LogicalAggregate, child: Exec,
+                          agg_rows: int) -> Exec:
+        """``count(DISTINCT x)`` beside ordinary aggregates, as Spark 3.3
+        plans one distinct column (``AggUtils.planAggregateWithOneDistinct``)
+        from today's aggregates, no kernel of its own:
+
+        1. Partial on keys + ``x``, the other aggregates' partials;
+           exchange on keys + ``x``; PartialMerge there: one row a distinct
+           (keys, ``x``), its partials merged;
+        2. a projection that turns ``x`` into a count partial (1 where
+           ``x`` is not null, else 0) and passes the partials on;
+           PartialMerge on the keys, where that count and the partials
+           merge; exchange on the keys; Final.
+
+        A second distinct column set has no such plan: refused here."""
+        from .. import types as T
+        from ..expressions.comparison import IsNotNull
+        from ..expressions.conditional import If
+        named = [(e.child, e.name) if isinstance(e, Alias)
+                 else (e, type(e).__name__.lower()) for e in n.agg_exprs]
+        distinct = [(a, nm) for a, nm in named
+                    if isinstance(a, AGG.CountDistinct)]
+        if len({repr(a.child) for a, _ in distinct}) > 1:
+            raise NotImplementedError(
+                f"count(DISTINCT ...) over {len(distinct)} different "
+                f"columns in one aggregate is not supported: one distinct "
+                f"column set per aggregate (Spark plans more with an Expand)")
+        others = [(a, nm) for a, nm in named
+                  if not isinstance(a, AGG.CountDistinct)]
+        if any(not getattr(a, "supports_partial", True) for a, _ in others):
+            raise NotImplementedError(
+                "count(DISTINCT ...) beside an aggregate with no partial "
+                "form (percentile, collect) is not supported")
+        parts = self._shuffle_partitions()
+        nk = len(n.group_exprs)
+        counted_agg, name = distinct[0]
+        keys = list(n.group_exprs) + [Alias(counted_agg.child,
+                                            f"{name}#distinct")]
+        first = HashAggregateExec(keys, [Alias(a, nm) for a, nm in others],
+                                  child, AggregateMode.PARTIAL,
+                                  max_result_rows=agg_rows)
+        bound = [Alias(a, nm) for a, (_, nm) in zip(first.aggs, others)]
+        key_cols = [EB.col(f.name) for f in first.key_fields]
+        if self._partitioned(child):
+            first = self._exchange_if_partitioned(
+                HashPartitioning(key_cols, parts), first)
+        merged = HashAggregateExec(key_cols, bound, first,
+                                   AggregateMode.PARTIAL_MERGE,
+                                   max_result_rows=agg_rows)
+
+        fields = merged.output_schema.fields
+
+        def ref(i):
+            f = fields[i]
+            return EB.BoundReference(i, f.dtype, f.nullable, f.name)
+        x = ref(nk)
+        exprs = [ref(i) for i in range(nk)]
+        aggs, pos, of_first = [], nk + 1, iter(bound)
+        for a, nm in named:
+            if isinstance(a, AGG.CountDistinct):
+                exprs.append(Alias(If(IsNotNull(x), EB.lit(1, T.INT64),
+                                      EB.lit(0, T.INT64)), f"{nm}#0"))
+                aggs.append(Alias(AGG.Count(x), nm))
+                continue
+            b = next(of_first)
+            width = len(b.child.buffer_types())
+            exprs += [ref(i) for i in range(pos, pos + width)]
+            pos += width
+            aggs.append(b)
+        counted = ProjectExec(exprs, merged, ctx=self._ctx())
+        out_keys = [EB.col(f.name) for f in fields[:nk]]
+        partial = HashAggregateExec(out_keys, aggs, counted,
+                                    AggregateMode.PARTIAL_MERGE,
+                                    max_result_rows=agg_rows)
+        if out_keys and self._partitioned(counted):
+            partial = self._exchange_if_partitioned(
+                HashPartitioning(out_keys, parts), partial)
+        elif self._partitioned(counted):
+            partial = self._exchange_if_partitioned(SinglePartitioning(),
+                                                    partial)
+        return HashAggregateExec(out_keys, aggs, partial, AggregateMode.FINAL,
                                  max_result_rows=agg_rows)
 
     def _convert_window(self, n: L.LogicalWindow, child: Exec) -> Exec:

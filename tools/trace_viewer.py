@@ -130,13 +130,17 @@ _ROLLUP_WINDOW = ("expandProjections", "expandBatchesOut", "expandSlotsOut",
 #: table too
 _CONCAT = ("concatCalls", "concatPieces", "concatSlotsOut")
 
+#: a hash join's probes (``exec/join.HashJoinExec``): a fourth table
+_JOIN = ("joinProbeBatches", "joinCandidates", "joinSlotsOut",
+         "graceBuckets")
+
 
 #: span attributes that are counts: a name's row sums them over its spans
 _SUMMED = ("pulls", "lowerings", "programHits", "programMisses",
            "dec128Columns", "dec128Bytes",
            "partialsCut", "partialRowsMade", "partialRowsKept",
            "splitBatches", "splitPieces", "splitRowsSorted",
-           "splitRowsGathered") + _ROLLUP_WINDOW + _CONCAT
+           "splitRowsGathered") + _ROLLUP_WINDOW + _CONCAT + _JOIN
 
 
 def self_time_table(profile: dict) -> List[dict]:
@@ -164,7 +168,11 @@ def self_time_table(profile: dict) -> List[dict]:
     batches, their slots and its expressions (``windowBatches`` /
     ``windowSlots`` / ``windowExprs``), and the eager concatenations
     (a third table): calls, the pieces they wrote, their summed output
-    slots (``concatCalls`` / ``concatPieces`` / ``concatSlotsOut``)."""
+    slots (``concatCalls`` / ``concatPieces`` / ``concatSlotsOut``), and a
+    hash join's probes (a fourth table): probe batches, their summed
+    candidate pairs, the summed slots of the pair batches made for them,
+    and the buckets of a grace join (``joinProbeBatches`` /
+    ``joinCandidates`` / ``joinSlotsOut`` / ``graceBuckets``)."""
     import os
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), os.pardir))
@@ -216,7 +224,7 @@ def print_tables(profiles: Iterable[dict],
                   f"{r['splitRowsGathered']:>16}"
                   f"{r['partialsCut']:>13}{r['partialRowsMade']:>11}"
                   f"{r['partialRowsKept']:>11}")
-        for keys in (_ROLLUP_WINDOW, _CONCAT):
+        for keys in (_ROLLUP_WINDOW, _CONCAT, _JOIN):
             rolled = [r for r in table if any(r[k] for k in keys)]
             if rolled:
                 print(f"{'span':<34}" + "".join(
