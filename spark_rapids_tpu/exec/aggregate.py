@@ -32,6 +32,7 @@ from ..batch import MIN_CAPACITY, ColumnarBatch, DeviceColumn, Field, \
 from ..expressions.aggregates import AggregateFunction
 from ..expressions.aggregates import _cumsum as prefix_sum
 from ..expressions.base import Alias, EvalContext, Expression
+from ..trace import host_read
 from .base import Exec, UnaryExec
 from .basic import bind_all, output_name
 from .common import _batched_takes, adjacent_equal, adjacent_equal_ops, \
@@ -647,10 +648,13 @@ class HashAggregateExec(UnaryExec):
         if newest is not None:
             register(newest)
 
+    #: the name of ``_held_rows``' read (``trace.host_read``)
+    _GROUPS_READ = "aggGroups"
+
     def _held_rows(self, batch: ColumnarBatch) -> int:
         """The rows a batch holds, on the host: waits for the program
         that made the batch."""
-        return int(batch.num_rows)
+        return int(host_read(self._GROUPS_READ, batch.num_rows))
 
     def _cut_to_groups(self, batch: ColumnarBatch) -> ColumnarBatch:
         """Slice a buffer-layout batch to the capacity bucket of the rows
@@ -776,7 +780,7 @@ class HashAggregateExec(UnaryExec):
                 cap = bucket_capacity(carry.capacity + chunk.capacity)
                 chunk = concat_batches([carry, chunk], cap)
             merged = self._merge_jit(chunk)
-            n = int(merged.num_rows)
+            n = int(host_read("aggMerged", merged.num_rows))
             if n == 0:
                 carry = None
                 continue
@@ -814,6 +818,8 @@ class RollupExec(HashAggregateExec):
     ``levels``: for each coarser level, finest first, ``(nulled, literals)``:
     the ordinals of the keys null at that level and ``{ordinal: int}`` for
     the literal keys."""
+
+    _GROUPS_READ = "rollupGroups"
 
     def __init__(self, levels, group_exprs, agg_exprs,
                  child: "HashAggregateExec", **kw):

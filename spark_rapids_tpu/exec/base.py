@@ -249,7 +249,8 @@ def iter_subplan_tables(plan: Exec):
     from .. import trace as qtrace
     schema = plan.output_schema
     for b in plan.execute():
-        # the query's one forced wait for the device
+        # the result batch to the host; the operators' own waits for a
+        # device value are their trace.host_read calls
         with qtrace.span("result.d2h", kind="transfer") as sp:
             t = to_arrow(b, schema)
             if sp is not None:

@@ -22,6 +22,7 @@ from .. import types as T
 from ..batch import (ColumnarBatch, DeviceColumn, Field, Schema,
                      bucket_capacity, from_arrow)
 from ..expressions.base import Alias, EvalContext, Expression, raw_eval
+from ..trace import host_read
 from ..types import TypeKind
 from .base import Exec, LeafExec, UnaryExec
 from .common import KernelPrograms, compact, dec128_role, slice_batch
@@ -213,6 +214,14 @@ def _sum_errors(ctx) -> dict:
 
 
 def _raise_ansi(errs: dict) -> None:
+    """Raise for the first kind of error a batch's rows hit; ``errs``
+    holds the device's counts, read here (none to read where the
+    projection checks nothing)."""
+    if errs:
+        _raise_counted(host_read("ansiErrors", errs))
+
+
+def _raise_counted(errs: dict) -> None:
     from ..batch import CapacityError
     for kind, count in errs.items():
         if int(count) > 0:
@@ -280,7 +289,8 @@ class LocalLimitExec(UnaryExec):
             if remaining <= 0:
                 break
             out = self._kernel(batch, jnp.int32(remaining))
-            remaining -= int(out.num_rows)  # host sync: limits are control flow
+            # host sync: limits are control flow
+            remaining -= int(host_read("limitRows", out.num_rows))
             yield out
 
 
@@ -301,7 +311,7 @@ class GlobalLimitExec(LocalLimitExec):
                 if remaining <= 0:
                     return
                 out = self._kernel(batch, jnp.int32(remaining))
-                remaining -= int(out.num_rows)
+                remaining -= int(host_read("limitRows", out.num_rows))
                 yield out
 
 

@@ -25,9 +25,10 @@ import jax.numpy as jnp
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..expressions.base import EvalContext
+from ..trace import host_read
 from .base import Exec, LeafExec
 from .basic import (FilterExec, InMemoryScanExec, LocalLimitExec,
-                    ProjectExec, _raise_ansi)
+                    ProjectExec, _raise_counted)
 from .common import compact, jit_named, slice_batch
 from .join import HashJoinExec, JoinType
 from .sort import SortExec, TakeOrderedAndProjectExec, sort_batch
@@ -206,12 +207,12 @@ class FusedStage:
         stage = self
         for _ in range(max_retries):
             out, errs, over, needs = stage._program(*stage.inputs)
-            ev = [int(x) for x in errs]
+            ev = [int(x) for x in host_read("fusedErrors", list(errs))]
             if stage._err_kinds and any(ev):
-                _raise_ansi(dict(zip(stage._err_kinds, ev)))
-            if int(jnp.max(over)) == 0:
+                _raise_counted(dict(zip(stage._err_kinds, ev)))
+            if int(host_read("fusedOverflow", jnp.max(over))) == 0:
                 return out
-            grow = int(jnp.max(needs))
+            grow = int(host_read("fusedGrow", jnp.max(needs)))
             factor = max(stage.expand_factor * max(grow, 2),
                          stage.expand_factor * 2)
             stage = FusedStage(self.plan, factor)

@@ -40,6 +40,7 @@ from ..batch import ColumnarBatch, DeviceColumn, Field, Schema, bucket_capacity
 from ..expressions.aggregates import _cumsum as prefix_sum
 from ..expressions.base import EvalContext, Expression
 from ..expressions.hashing import murmur3_batch
+from ..trace import host_read
 from ..types import TypeKind
 from .base import BinaryExec, Exec
 from .basic import bind_all
@@ -823,7 +824,8 @@ class HashJoinExec(BinaryExec):
         stream_iter = (b for sp in stream_parts
                        for b in self.left.execute_partition(sp))
 
-        build_rows = sum(int(b.num_rows) for b in build_batches)
+        build_rows = sum(int(n) for n in host_read(
+            "buildRows", [b.num_rows for b in build_batches]))
         if build_rows > self.max_build_rows:
             yield from self._grace_join(build_batches, stream_iter)
         else:
@@ -892,10 +894,10 @@ class HashJoinExec(BinaryExec):
             b = item.acquire()
             try:
                 lo, counts, offsets, total = self._count_jit(b, table)
-                total_i = int(total)
+                total_i = int(host_read("joinTotal", total))
                 if search_rounds is None:
-                    dense, rounds = jax.device_get((table.dense,
-                                                    table.rounds))
+                    dense, rounds = host_read("joinRounds", (table.dense,
+                                                             table.rounds))
                     search_rounds = 0 if self._exact_probe and dense \
                         else int(rounds)
                 if total_i > (1 << 31) - 1:
@@ -977,7 +979,8 @@ class HashJoinExec(BinaryExec):
         from ..memory import (SpillableBatch, acquire_with_retry,
                               device_budget, register_with_retry)
         cat = device_budget()
-        build_rows = sum(int(b.num_rows) for b in build_batches)
+        build_rows = sum(int(n) for n in host_read(
+            "buildRows", [b.num_rows for b in build_batches]))
         n_buckets = -(-build_rows // self.max_build_rows)
         from .. import trace as qtrace
         qtrace.count(graceBuckets=n_buckets)
@@ -999,7 +1002,7 @@ class HashJoinExec(BinaryExec):
         for b in build_batches:
             for s in range(n_buckets):
                 piece = split_build(b, s)
-                if int(piece.num_rows) > 0:
+                if host_read("gracePieceRows", piece.num_rows) > 0:
                     sub_builds[s].append(piece)
 
         sub_stream: List[List[SpillableBatch]] = \
@@ -1008,7 +1011,7 @@ class HashJoinExec(BinaryExec):
         for batch in stream_iter:
             for s in range(n_buckets):
                 piece = split_stream(batch, s)
-                if int(piece.num_rows) > 0:
+                if host_read("gracePieceRows", piece.num_rows) > 0:
                     sub_stream[s].append(register_with_retry(
                         piece, stream_schema, catalog=cat,
                         name=f"{self.name}.grace"))
@@ -1138,7 +1141,7 @@ class BroadcastNestedLoopJoinExec(BinaryExec):
             return
         tile = max(self.max_tile_rows // stream_cap, 1)
         tile_cap = bucket_capacity(tile)
-        n_build = int(build.num_rows)
+        n_build = int(host_read("buildRows", build.num_rows))
         for off in range(0, max(n_build, 1), tile_cap):
             yield off, _slice_tile(build, jnp.int32(off),
                                    jnp.int32(tile_cap), tile_cap)

@@ -31,6 +31,7 @@ import numpy as np
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..expressions.base import EvalContext, Expression, raw_eval
+from ..trace import host_read
 from .base import UnaryExec
 from .basic import bind_all
 from .common import (KernelPrograms, adjacent_equal, concat_batches_encoded,
@@ -90,8 +91,9 @@ class KeyBatchingExec(UnaryExec):
     def do_execute_partition(self, p: int) -> Iterator[ColumnarBatch]:
         from .. import trace as qtrace
         batches = list(self.child.execute_partition(p))
-        # the one host read: the rows each batch holds
-        rows = [int(b.num_rows) for b in batches]
+        # a host read: the rows each batch holds
+        rows = [int(n) for n in host_read("keyRows",
+                                          [b.num_rows for b in batches])]
         total = sum(rows)
         if total == 0:
             return
@@ -114,8 +116,9 @@ class KeyBatchingExec(UnaryExec):
         # start that keeps the batch <= target_rows (a batch exceeds the
         # target only when one single group does — the same bound
         # GpuKeyBatchingIterator guarantees)
-        starts = np.flatnonzero(np.asarray(new_group))
-        n = int(srt.num_rows)
+        new_group, n = host_read("keyGroups", (new_group, srt.num_rows))
+        starts = np.flatnonzero(new_group)
+        n = int(n)
         cuts: List[int] = [0]
         prev = 0
         for s in list(starts[1:]) + [n]:

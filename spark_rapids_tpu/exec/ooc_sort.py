@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..memory import (BufferCatalog, SpillableBatch, acquire_with_retry,
                       register_with_retry)
+from ..trace import host_read
 from .common import KernelPrograms, compact, concat_batches, jit_named, \
     slice_batch, sort_operands
 from .sort import SortOrder, sort_batch
@@ -103,12 +104,12 @@ class OutOfCoreSorter:
         if batch.capacity <= cap:
             run.append(batch)
             return
-        n = int(batch.num_rows)
+        n = int(host_read("oocRows", batch.num_rows))
         off = 0
         while off < max(n, 1):
             piece = self._slice_jit(batch, jnp.int32(off),
                                     jnp.int32(cap), cap)
-            if int(piece.num_rows) > 0 or n == 0:
+            if host_read("oocRows", piece.num_rows) > 0 or n == 0:
                 run.append(piece)
             off += cap
             if n == 0:
@@ -170,10 +171,10 @@ class OutOfCoreSorter:
             for w in live_bounds[1:]:
                 bound = _lex_min(bound, w)
             emit, keep = self._split_jit(merged, bound, merged.capacity)
-            if int(emit.num_rows) > 0:
+            if host_read("oocRows", emit.num_rows) > 0:
                 self._append_chunked(out, emit)
-            buf = keep if int(keep.num_rows) > 0 else None
-        if buf is not None and int(buf.num_rows) > 0:
+            buf = keep if host_read("oocRows", keep.num_rows) > 0 else None
+        if buf is not None and host_read("oocRows", buf.num_rows) > 0:
             self._append_chunked(out, buf)
         a.close()
         b.close()

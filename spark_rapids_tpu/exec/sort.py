@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from ..batch import ColumnarBatch, Schema, bucket_capacity
 from ..expressions.base import EvalContext, Expression, raw_eval
+from ..trace import host_read
 from .base import Exec, UnaryExec
 from .basic import bind_all
 from .common import KernelPrograms, concat_batches, cut_to_rows, gather, \
@@ -163,7 +164,8 @@ class SortExec(UnaryExec):
                 yield from sorter.sort(iter(caps))
                 return
             # sized by the rows held, not by the capacities
-            rows = sum(int(b.num_rows) for b in caps)
+            rows = sum(int(n) for n in host_read(
+                "sortRows", [b.num_rows for b in caps]))
             merged = concat_batches(caps, bucket_capacity(max(rows, 1)))
             yield self._sort_jit(merged)
         finally:
@@ -179,7 +181,8 @@ class SortExec(UnaryExec):
         otherwise pay every key lane's pass at the filter's capacity."""
         if batch.capacity <= _SORT_AS_IS_SLOTS:
             return batch        # cheaper to sort than to wait for a count
-        return cut_to_rows(batch, int(batch.num_rows))
+        return cut_to_rows(batch, int(host_read("sortRows",
+                                                batch.num_rows)))
 
 
 class TakeOrderedAndProjectExec(UnaryExec):

@@ -25,6 +25,7 @@ from ..expressions.base import Alias, EvalContext, Expression, raw_eval
 from ..expressions.window import (LagLead, NTile, Rank, RowNumber,
                                   WindowAgg, WindowExpression, WindowFrame,
                                   segmented_scan)
+from ..trace import host_read
 from ..types import TypeKind
 from .base import Exec, UnaryExec
 from .common import KernelPrograms, adjacent_equal, concat_batches_encoded, \
@@ -545,7 +546,8 @@ class WindowExec(UnaryExec):
                 whole = got[0]
                 if len(got) > 1:
                     # sized by the rows held, not by the capacities
-                    rows = sum(int(b.num_rows) for b in got)
+                    rows = sum(int(n) for n in host_read(
+                        "windowRows", [b.num_rows for b in got]))
                     whole = concat_batches_encoded(
                         got, bucket_capacity(max(rows, 1)))
                 qtrace.count(windowBatches=1,

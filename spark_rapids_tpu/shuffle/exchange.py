@@ -129,10 +129,12 @@ class PartitioningExchangeExec(UnaryExec):
         ids come from the partitioning, capacities from the counts."""
         n = self.partitioning.num_partitions
         if n == 1:
-            perm, counts = None, [int(b.num_rows)]
+            perm, counts = None, [int(qtrace.host_read("splitCounts",
+                                                       b.num_rows))]
         else:
             perm, counts = self._split_jit(b)
-            counts = counts.tolist()    # the batch's ONE host read
+            # the batch's ONE host read
+            counts = qtrace.host_read("splitCounts", counts).tolist()
         # the piece program moves row lanes only: a dictionary is taken
         # off before it and put back, the same object, on every piece
         dicts = [(c.dict_data, c.dict_lengths) if c.is_dict else None

@@ -416,6 +416,39 @@ def count(**counters: int) -> None:
         a[name] = a.get(name, 0) + n
 
 
+def host_read(what: str, value):
+    """``jax.device_get(value)``: the engine's one door for a read of a
+    device value by the host. Such a read waits for everything queued
+    before it, and the device then idles until the host dispatches again.
+    With no active trace that costs one thread-local read on top of the
+    read itself. In a traced query the read is the profiler's event
+    ``sync.<what>`` on this thread's line, and the innermost span or pull
+    frame counts it (``hostReads``) and its microseconds
+    (``hostReadUs``): counters, so the span cap never drops one."""
+    get = _DEVICE_GET or _join_device_get()
+    st = getattr(_TLS, "st", None)
+    if st is None:
+        return get(value)
+    t0 = time.perf_counter_ns()
+    ann = _annotate("sync." + what)
+    try:
+        return get(value)
+    finally:
+        ann.__exit__(None, None, None)
+        count(hostReads=1,
+              hostReadUs=(time.perf_counter_ns() - t0) // 1000)
+
+
+_DEVICE_GET = None      # jax.device_get, on the first read
+
+
+def _join_device_get():
+    global _DEVICE_GET
+    from jax import device_get
+    _DEVICE_GET = device_get
+    return device_get
+
+
 class OperatorSpan:
     """One partition's iteration of one operator. The span runs from the
     first pull to exhaustion, so it also covers what the consumer does

@@ -135,6 +135,10 @@ _JOIN = ("joinProbeBatches", "joinCandidates", "joinSlotsOut",
          "graceBuckets", "joinSearchRounds", "joinSearchRoundsPlain")
 
 
+#: a read of a device value by the host (``trace.host_read``): a fifth table
+_HOST_READS = ("hostReads", "hostReadUs")
+
+
 #: span attributes that are counts: a name's row sums them over its spans
 _SUMMED = ("pulls", "lowerings", "programHits", "programMisses",
            "dec128Columns", "dec128Bytes",
@@ -175,7 +179,9 @@ def self_time_table(profile: dict) -> List[dict]:
     over probe batches: those the build's directory asked for (0 on a
     dense build) and those a search of the whole build would have taken
     (``joinProbeBatches`` / ``joinCandidates`` / ``joinSlotsOut`` /
-    ``graceBuckets`` / ``joinSearchRounds`` / ``joinSearchRoundsPlain``)."""
+    ``graceBuckets`` / ``joinSearchRounds`` / ``joinSearchRoundsPlain``).
+    The host's reads of device values are a fifth table
+    (``host_read_table``)."""
     import os
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.abspath(__file__)), os.pardir))
@@ -199,6 +205,27 @@ def self_time_table(profile: dict) -> List[dict]:
                 and not parent.startswith("jit."):
             rows[parent]["relowerMs"] += (s.get("durUs") or 0) / 1000.0
     return sorted(rows.values(), key=lambda r: -r["selfMs"])
+
+
+def host_read_table(profile: dict) -> List[dict]:
+    """One row per span name under which the host read a device value,
+    most microseconds first: the reads and their microseconds
+    (``hostReads`` / ``hostReadUs``, counted on the innermost span or pull
+    frame: an operator, or a span such as an exchange's ``<Exec>.write``)
+    and their share of the time inside those spans (``trace.inside_us``:
+    an operator's ``pullUs``)."""
+    from spark_rapids_tpu.trace import inside_us
+    rows: Dict[str, dict] = {}
+    for s in profile.get("spans", []):
+        attrs = s.get("attrs") or {}
+        if not attrs.get("hostReads"):
+            continue
+        r = rows.setdefault(s["name"], {"name": s["name"], "hostReads": 0,
+                                        "hostReadUs": 0, "insideUs": 0})
+        for k in _HOST_READS:
+            r[k] += int(attrs.get(k, 0))
+        r["insideUs"] += inside_us(s)
+    return sorted(rows.values(), key=lambda r: -r["hostReadUs"])
 
 
 def print_tables(profiles: Iterable[dict],
@@ -235,6 +262,15 @@ def print_tables(profiles: Iterable[dict],
                 for r in rolled:
                     print(f"{r['name']:<34}" + "".join(
                         f"{r[k]:>{len(k) + 2}}" for k in keys))
+        reads = host_read_table(prof)
+        if reads:
+            print(f"{'span':<34}{'hostReads':>11}{'hostReadUs':>12}"
+                  f"{'of inside':>11}")
+            for r in reads:
+                share = f"{100.0 * r['hostReadUs'] / r['insideUs']:.1f} %" \
+                    if r["insideUs"] else "-"
+                print(f"{r['name']:<34}{r['hostReads']:>11}"
+                      f"{r['hostReadUs']:>12}{share:>11}")
 
 
 def main(argv=None) -> int:
