@@ -37,7 +37,7 @@ import jax.numpy as jnp
 
 from .. import types as T
 from ..batch import ColumnarBatch, DeviceColumn, Field, Schema, bucket_capacity
-from ..expressions.aggregates import _cumsum as prefix_sum
+from ..expressions.aggregates import _cumsum as prefix_sum, _running_max
 from ..expressions.base import EvalContext, Expression
 from ..expressions.hashing import murmur3_batch
 from ..trace import host_read
@@ -211,6 +211,55 @@ def _table_search(t: ProbeTable, h: jnp.ndarray):
                              jnp.zeros(h.shape, bool)))
     span = jnp.take(t.spans, r, axis=0, mode="clip")
     return span[:, 0], jnp.where(hit, span[:, 1] - span[:, 0], 0)
+
+
+def _search_slot_rows(offsets: jnp.ndarray, j: jnp.ndarray) -> jnp.ndarray:
+    """Each pair slot's probe row, the first whose inclusive offset passes
+    it, by a binary search of the offsets: ``ceil(log2(n + 1))`` rounds of
+    a gather a slot (``n`` probe rows)."""
+    return jnp.searchsorted(offsets, j, side="right",
+                            method="scan").astype(jnp.int32)
+
+
+def _map_slot_rows(offsets: jnp.ndarray, counts: jnp.ndarray, base,
+                   out_cap: int) -> jnp.ndarray:
+    """Each of the slots ``[base, base + out_cap)``'s probe row in one pass:
+    every probe row whose candidates start inside the part marks its first
+    slot with its index, the row holding ``base`` (a search of that one
+    slot) marks slot 0, and a running maximum carries each mark over its
+    row's slots. No two marks share a place, the other rows each going to
+    one of its own past the end, so the scatter is of unique indices (the
+    fastest form on the chip: docs/tpu_compat.md). Slots from the total on
+    read the last row with candidates (the search reads ``n``): the caller
+    masks them."""
+    n = offsets.shape[0]
+    first = offsets - counts - base
+    rows = jnp.arange(n, dtype=jnp.int32)
+    inside = (counts > 0) & (first >= 0) & (first < out_cap)
+    marks = jnp.zeros(out_cap, jnp.int32).at[
+        jnp.where(inside, first, out_cap + rows)].set(
+            rows, mode="drop", unique_indices=True)
+    head = jnp.minimum(_search_slot_rows(offsets, base), n - 1)
+    return _running_max(marks.at[0].max(head))
+
+
+# The map costs a scatter of the probe rows and a ladder over the slots
+# (about 2 / 3.4 / 5.7 ms at 2^18 / 2^19 / 2^20 probe rows), the search a
+# gather a slot a round (27-33 ns a slot-round below 2^17 slots). On one
+# TPU v5 lite the map wins from 2^12 slots of 2^18 rows (19 rounds: 0.30
+# slot-rounds a row; 1.98 against 2.59 ms) and 2^14 of 2^20 (0.33; 5.70
+# against 8.82), the search at 256 of 2^18 (0.019; 0.68 against 2.05):
+# the map where the slot-rounds reach a quarter of the probe rows
+# (docs/tpu_compat.md, "The pair slots' probe rows").
+MAP_MIN_SLOT_ROUNDS_PER_ROW = 0.25
+
+
+def _maps_slots(probe_cap: int, out_cap: int) -> bool:
+    """Whether a pair batch of ``out_cap`` slots from a probe batch of
+    ``probe_cap`` rows takes its slots' probe rows from the one-pass map
+    (else from the search): both capacities are static in the program."""
+    return out_cap * probe_cap.bit_length() >= \
+        MAP_MIN_SLOT_ROUNDS_PER_ROW * probe_cap
 
 
 from functools import partial  # noqa: E402
@@ -489,14 +538,20 @@ class HashJoinExec(BinaryExec):
         if base is not None:
             j = j + base
         total = offsets[-1]
-        probe_row = jnp.searchsorted(offsets, j, side="right",
-                                     method="scan").astype(jnp.int32)
-        probe_row = jnp.clip(probe_row, 0, stream.capacity - 1)
+        in_range = j < total
+        # slots past the total read the last row, as the clipped search does
+        if _maps_slots(stream.capacity, out_cap):
+            probe_row = jnp.where(
+                in_range, _map_slot_rows(offsets, counts,
+                                         0 if base is None else base,
+                                         out_cap), stream.capacity - 1)
+        else:
+            probe_row = jnp.clip(_search_slot_rows(offsets, j), 0,
+                                 stream.capacity - 1)
         start = jnp.take(offsets, probe_row) - jnp.take(counts, probe_row)
         ordinal = j - start
         build_row = jnp.take(lo, probe_row) + ordinal
         build_row = jnp.clip(build_row, 0, build.capacity - 1).astype(jnp.int32)
-        in_range = j < total
 
         # exact-probe candidates already matched on the full key word, so
         # no key re-gather or equality verification is needed; the hash
@@ -907,6 +962,9 @@ class HashJoinExec(BinaryExec):
                         f"reduce the batch size or pre-aggregate the "
                         f"build side")
                 out_cap = 0 if pairless else bucket_capacity(max(total_i, 1))
+                # the slots of one pair-batch program, whose shapes choose
+                # how its slots find their probe rows (``_maps_slots``)
+                pair_cap = out_cap
                 part = max(b.capacity, PAIR_PART_MIN_SLOTS)
                 lo_counts = (lo, counts, offsets)
                 if semi:
@@ -920,11 +978,14 @@ class HashJoinExec(BinaryExec):
                                                    jnp.int32(base), part),
                              None) for base in range(0, total_i, part)]
                     out_cap = part * len(outs)
+                    pair_cap = part
                 else:
                     outs = [self._expand_jit(b, sbuild, lo_counts,
                                              matched_build, out_cap)]
+                mapped = out_cap if out_cap and \
+                    _maps_slots(b.capacity, pair_cap) else 0
                 qtrace.count(joinProbeBatches=1, joinCandidates=total_i,
-                             joinSlotsOut=out_cap,
+                             joinSlotsOut=out_cap, joinMappedSlots=mapped,
                              joinSearchRounds=search_rounds,
                              joinSearchRoundsPlain=plain_rounds)
                 return outs

@@ -207,14 +207,34 @@ class FastLanes:
 _SCAN_BLOCK = 512
 
 
-def _prefix_ladder_flat(m: jax.Array) -> jax.Array:
+def _prefix_ladder_flat(m: jax.Array, op=jnp.add) -> jax.Array:
     n = m.shape[0]
     d = 1
     while d < n:
         pad = jnp.zeros((d,) + m.shape[1:], m.dtype)
-        m = m + jnp.concatenate([pad, m[:-d]], axis=0)
+        m = op(m, jnp.concatenate([pad, m[:-d]], axis=0))
         d <<= 1
     return m
+
+
+def _running_max(x: jax.Array) -> jax.Array:
+    """Inclusive running maximum of a non-negative 1-D lane: the prefix
+    sum's two-level ladder with max for + (its zero pads are max's
+    identity on such a lane)."""
+    n = x.shape[0]
+    C = _SCAN_BLOCK
+    if n <= C or n % C != 0:
+        return _prefix_ladder_flat(x, jnp.maximum)
+    R = n // C
+    acc = x.reshape(R, C)
+    d = 1
+    while d < C:
+        z = jnp.zeros((R, d), x.dtype)
+        acc = jnp.maximum(acc, jnp.concatenate([z, acc[:, :-d]], axis=1))
+        d <<= 1
+    heads = _prefix_ladder_flat(acc[:, -1], jnp.maximum)
+    carry = jnp.concatenate([jnp.zeros(1, x.dtype), heads[:-1]])
+    return jnp.maximum(acc, carry[:, None]).reshape(n)
 
 
 def _prefix_ladder(m: jax.Array) -> jax.Array:

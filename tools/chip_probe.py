@@ -10,9 +10,11 @@ is non-zero if any did.
 The ``run ...`` probes time, at run time, each form the execs are built on
 (``exec/common.lex_sort_permutation``, the prefix ladder, ``searchsorted``
 by binary search, f64 key words) against the form it replaced for compile
-seconds, and the join probe's bucket search (``exec/join._table_search``)
-against the whole-build search it replaced, on the same data, and check
-that both give the same answer.
+seconds, the join probe's bucket search (``exec/join._table_search``)
+against the whole-build search it replaced, and the pair slots' probe rows
+by a one-pass map (``exec/join._map_slot_rows``) against the search it
+replaced and the other forms of the map, on the same data, and check that
+all give the same answer.
 """
 
 import json
@@ -159,9 +161,11 @@ def main() -> int:
     from spark_rapids_tpu.batch import DeviceColumn
     from spark_rapids_tpu.exec.common import (lex_sort_permutation,
                                               orderable_words)
-    from spark_rapids_tpu.exec.join import (ProbeTable, _probe_table,
+    from spark_rapids_tpu.exec.join import (ProbeTable, _map_slot_rows,
+                                            _probe_table, _search_slot_rows,
                                             _table_search)
     from spark_rapids_tpu.expressions.aggregates import _cumsum as ladder
+    from spark_rapids_tpu.expressions.aggregates import _running_max
 
     n = 1 << (int(sys.argv[1]) if len(sys.argv) > 1 else 20)
     rng = np.random.default_rng(7)
@@ -233,6 +237,108 @@ def main() -> int:
         return join_build(days[(days // 30) % 12 == 10][:6_000], 1 << 17,
                           rng.integers(2_450_816, 2_452_642, 1 << 20), 31)
 
+    def pair_slots(rows, counts, out_cap, part):
+        """A probe batch of ``rows`` slots whose live rows have ``counts``
+        candidates (dead rows none), and part ``part`` of its pair slots
+        at ``out_cap`` a part: the offsets, the counts and the part's
+        first slot."""
+        c = np.zeros(rows, np.int32)
+        c[:len(counts)] = counts
+        offsets = np.cumsum(c, dtype=np.int64)
+        assert offsets[-1] > part * out_cap and offsets[-1] < 1 << 31
+        return [offsets.astype(np.int32), c, np.int32(part * out_cap)]
+
+    def slot_forms(out_cap, sort_form=True):
+        """Each pair slot's probe row five ways, slots past the total read
+        the last row: the search (``_gather_pairs``' before the map), the
+        map marked by a scatter of max marks (a row that starts before the
+        part marks slot 0), by a scatter of ascending marks, by a scatter
+        of unique marks (``exec/join._map_slot_rows``, the form in use),
+        and by two sorts (the starts merged with the slots by one
+        two-operand sort, a stable sort on the tag to bring the slots back
+        into slot order)."""
+        def tail(offsets, base, rows):
+            j = jnp.arange(out_cap, dtype=jnp.int32) + base
+            return jnp.where(j < offsets[-1], rows, offsets.shape[0] - 1)
+
+        def search(offsets, counts, base):
+            j = jnp.arange(out_cap, dtype=jnp.int32) + base
+            return jnp.clip(_search_slot_rows(offsets, j), 0,
+                            offsets.shape[0] - 1)
+
+        def starts(offsets, counts, base):
+            # each row with candidates at its first slot, one that starts
+            # before the part at slot 0, the rest past the end
+            return jnp.where(counts > 0,
+                             jnp.maximum(offsets - counts - base, 0), out_cap)
+
+        def scatter_max(offsets, counts, base):
+            rows = jnp.arange(offsets.shape[0], dtype=jnp.int32)
+            marks = jnp.zeros(out_cap, jnp.int32).at[
+                starts(offsets, counts, base)].max(rows, mode="drop")
+            return tail(offsets, base, _running_max(marks))
+
+        def scatter_sorted(offsets, counts, base):
+            # every row marks where its candidates would start, the empty
+            # ones too: the marks' positions ascend with the rows, and an
+            # empty row's mark is outbid by the next row's (but at the
+            # total, which the tail masks)
+            at = jnp.clip(offsets - counts - base, 0, out_cap)
+            rows = jnp.arange(offsets.shape[0], dtype=jnp.int32)
+            marks = jnp.zeros(out_cap, jnp.int32).at[at].max(
+                rows, mode="drop", indices_are_sorted=True)
+            return tail(offsets, base, _running_max(marks))
+
+        def scatter_unique(offsets, counts, base):
+            return tail(offsets, base,
+                        _map_slot_rows(offsets, counts, base, out_cap))
+
+        def sort(offsets, counts, base):
+            n = offsets.shape[0]
+            keys = jnp.concatenate([starts(offsets, counts, base),
+                                    jnp.arange(out_cap, dtype=jnp.int32)])
+            vals = jnp.concatenate([jnp.arange(n, dtype=jnp.int32),
+                                    jnp.full(out_cap, -1, jnp.int32)])
+            _, v = lax.sort((keys, vals), num_keys=1, is_stable=True)
+            tag = (v < 0).astype(jnp.int32)
+            _, rows = lax.sort((tag, _running_max(jnp.maximum(v, 0))),
+                               num_keys=1, is_stable=True)
+            return tail(offsets, base, rows[n:])
+        forms = [("old searchsorted", search),
+                 ("map, scatter of max marks", scatter_max),
+                 ("map, scatter of ascending marks", scatter_sorted),
+                 ("map, scatter of unique marks (in use)", scatter_unique)]
+        return forms + [("map, two sorts", sort)] if sort_form else forms
+
+    # the cells' shapes: Q95's self-join parts (orders of 8-16 lines, 4 %
+    # null keys), the returns join's expansion, DS Q3's date and item joins
+    def self_join(part):
+        live = 390_000
+        c = rng.integers(8, 17, live) * (rng.random(live) >= 0.04)
+        return pair_slots(1 << 19, c, 1 << 19, part)
+
+    def lookups(rows, matched):     # counts of 0 and 1, ``matched`` ones
+        return lambda: pair_slots(rows, rng.random(rows) < matched / rows,
+                                  0, 0)
+
+    slot_shapes = [
+        ("2^19 rows -> 2^19 slots, part 0 (Q95's expandPart)", 1 << 19,
+         lambda: self_join(0), True),
+        ("2^19 rows -> 2^19 slots, part 5, from mid-row (Q95's expandPart)",
+         1 << 19, lambda: self_join(5), True),
+        ("2^19 rows -> 2^20 slots (Q95's returns join)", 1 << 20,
+         lambda: pair_slots(1 << 19, rng.poisson(3, 314_573), 1 << 20, 0),
+         True),
+        ("2^20 rows -> 2^18 slots (DS Q3's date join)", 1 << 18,
+         lookups(1 << 20, 209_715), True),
+        ("2^18 rows -> 256 slots (DS Q3's item join)", 1 << 8,
+         lookups(1 << 18, 200), True),
+        ("2^18 rows -> 2^12 slots", 1 << 12, lookups(1 << 18, 3_000), False),
+        ("2^18 rows -> 2^14 slots", 1 << 14, lookups(1 << 18, 12_000), False),
+        ("2^20 rows -> 2^14 slots", 1 << 14, lookups(1 << 20, 12_000), False),
+        ("2^20 rows -> 2^16 slots", 1 << 16, lookups(1 << 20, 50_000), False),
+    ]
+
     probe("f64_roundtrip", f64_roundtrip)
     probe("f64_arith", f64_arith)
     probe("f64_sum_precision", f64_sum_precision)
@@ -270,6 +376,9 @@ def main() -> int:
               ("old whole-build searchsorted", whole_build_search),
               ("new directory bucket search", directory_search)],
               date_dim_join))
+    for label, out_cap, make, sort_form in slot_shapes:
+        probe(f"run pair slots' probe rows, {label}",
+              run_time(slot_forms(out_cap, sort_form), make))
     probe(f"run f64 key words (_double_bits_words), {n} rows", run_time([
         ("new", f64_words)], lambda: [rng.uniform(-1e6, 1e6, n)]))
     # last: the old form's compile is the longest of all (minutes)

@@ -132,7 +132,8 @@ _CONCAT = ("concatCalls", "concatPieces", "concatSlotsOut")
 
 #: a hash join's probes (``exec/join.HashJoinExec``): a fourth table
 _JOIN = ("joinProbeBatches", "joinCandidates", "joinSlotsOut",
-         "graceBuckets", "joinSearchRounds", "joinSearchRoundsPlain")
+         "joinMappedSlots", "graceBuckets", "joinSearchRounds",
+         "joinSearchRoundsPlain")
 
 
 #: a read of a device value by the host (``trace.host_read``): a fifth table
@@ -174,12 +175,14 @@ def self_time_table(profile: dict) -> List[dict]:
     (a third table): calls, the pieces they wrote, their summed output
     slots (``concatCalls`` / ``concatPieces`` / ``concatSlotsOut``), and a
     hash join's probes (a fourth table): probe batches, their summed
-    candidate pairs, the summed slots of the pair batches made for them,
-    the buckets of a grace join, and the probes' search rounds, summed
-    over probe batches: those the build's directory asked for (0 on a
-    dense build) and those a search of the whole build would have taken
-    (``joinProbeBatches`` / ``joinCandidates`` / ``joinSlotsOut`` /
-    ``graceBuckets`` / ``joinSearchRounds`` / ``joinSearchRoundsPlain``).
+    candidate pairs, the summed slots of the pair batches made for them
+    and those of them whose probe rows came from the one-pass map rather
+    than a search, the buckets of a grace join, and the probes' search
+    rounds, summed over probe batches: those the build's directory asked
+    for (0 on a dense build) and those a search of the whole build would
+    have taken (``joinProbeBatches`` / ``joinCandidates`` /
+    ``joinSlotsOut`` / ``joinMappedSlots`` / ``graceBuckets`` /
+    ``joinSearchRounds`` / ``joinSearchRoundsPlain``).
     The host's reads of device values are a fifth table
     (``host_read_table``)."""
     import os
