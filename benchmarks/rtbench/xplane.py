@@ -92,7 +92,9 @@ def reduce(trace):
     profiler start to stop, so every operation counted lies inside it;
     ``busy_s`` is the union of the device's operation intervals, averaged
     over the devices; gaps are the idle stretches between them on device
-    0."""
+    0; ``idle_by_host`` is every idle stretch of device 0 put down to what
+    the host was doing (``rtbench/idle.attribute``)."""
+    from rtbench import idle        # idle reads this module's helpers
     devices = trace["devices"]
     if not devices or not any(d["ops"] for d in devices):
         return None
@@ -117,6 +119,7 @@ def reduce(trace):
         "device_ops": [[n, s / 1e9] for n, s in top_ops],
         "idle_gaps": [[_covering(trace["host"], s, e), g / 1e9]
                       for g, s, e in gaps],
+        "idle_by_host": idle.attribute(trace),
     }
 
 

@@ -48,3 +48,16 @@ def run_cli(script, args, cwd=REPO, timeout=600):
     except ValueError:
         last = None
     return p.returncode, last, p.stdout, p.stderr
+
+
+REHEARSAL_SEED = 2 ** 31 + 33
+
+
+def rehearsal_args(cell, work_dir):
+    """A cell's untraced rehearsal on a CPU server, as ``run.py`` takes it.
+    The clean run (``test_rehearsal.py``) and each run with its timed path
+    broken underneath (``test_fault_*.py``) take these same arguments, so
+    the clean run is the broken ones' twin."""
+    return [str(a) for a in (
+        "--workload", cell, "--seed", REHEARSAL_SEED, "--seconds", 2,
+        "--trace", 0, "--rehearsal", "--work-dir", work_dir)]

@@ -75,9 +75,14 @@ def test_a_failed_warm_up_ends_the_run_and_no_client_hangs():
         made.append(FakeClient([], fail_at=1 if not made else None))
         return made[-1]
 
+    before = set(threading.enumerate())
     with pytest.raises(RuntimeError, match="warm-up"):
         window.run(connect, ["a"], [1], 3, 0.2, seed=1)
-    assert threading.active_count() < 10
+    # the window's own threads, not those other tests of the process left
+    started = [t for t in threading.enumerate() if t not in before]
+    for t in started:
+        t.join(timeout=5)
+    assert not [t.name for t in started if t.is_alive()]
 
 
 def test_a_failed_timed_submission_is_a_record_on_a_new_connection():

@@ -2,8 +2,8 @@
 ``scan_decimal_ms`` over ``scan.h2d.decimal`` and ``dec128_device_bytes``
 over the operator spans' ``dec128Bytes``, each on a canned record whose
 answer is known and on the record of a program that lacks what it reads,
-where it answers nothing and not 0. ``BENCHMARK.json`` does not list them
-yet (PERF.md, Open questions): ``loader.metric`` finds a reader by name."""
+where it answers nothing and not 0. ``loader.metric`` finds a reader by
+the name ``BENCHMARK.json`` lists it under."""
 
 import types
 

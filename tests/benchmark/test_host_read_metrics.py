@@ -4,9 +4,8 @@ reads: ``host_reads_per_query`` and ``host_read_wait_ms`` over the spans'
 ``hostReads`` / ``hostReadUs``, ``idle_after_read_pct`` over the reduced
 trace's ``idle_by_host``. Each on hand-made input whose answer is known, on
 a trace recorded on a TPU v5e, and on the record of a program that lacks
-what it reads, where it answers nothing and not 0. ``BENCHMARK.json`` does
-not list the readers yet (PERF.md, Open questions): ``loader.metric`` finds
-a reader by name."""
+what it reads, where it answers nothing and not 0. ``loader.metric`` finds
+a reader by the name ``BENCHMARK.json`` lists it under."""
 
 import gzip
 import json
@@ -122,14 +121,17 @@ def recorded(tmp_path_factory):
 def test_on_a_chip_trace_the_stretches_sum_to_its_idle_time(recorded):
     trace, want = recorded
     r = xplane.reduce(trace)
-    # the reduction reads what it did before, and nothing more
+    # the reduction reads what it did before, and the charge of every idle
+    # stretch beside it
     assert set(r) == {"busy_s", "window_s", "device_count",
-                      "program_executions", "device_ops", "idle_gaps"}
+                      "program_executions", "device_ops", "idle_gaps",
+                      "idle_by_host"}
     assert r["busy_s"] == pytest.approx(want["busy_s"])
     assert r["program_executions"] == want["program_executions"]
     assert r["idle_gaps"] == [[n, pytest.approx(s)]
                               for n, s in want["idle_gaps"]]
     by_host = idle.attribute(trace)
+    assert r["idle_by_host"] == by_host
     assert sum(by_host.values()) == pytest.approx(
         r["window_s"] - r["busy_s"], rel=1e-6)
     # recorded before the door: no stretch follows a read, and each of the
@@ -209,6 +211,9 @@ def test_idle_after_read_is_the_share_of_the_stretches_after_a_read():
         == pytest.approx(100.0 * 0.030 / 0.065)
     assert read(_run(by_host={"sync.joinTotal": 3.0, "scan.decode": 1.0})) \
         == pytest.approx(75.0)
+    # as run.py hands it the reduced trace
+    assert read({"trace": xplane.reduce(_trace())}) \
+        == pytest.approx(100.0 * 0.030 / 0.065)
 
 
 def test_idle_after_read_reads_nothing_without_reads_or_the_reduction():

@@ -3,8 +3,8 @@ the operator spans' ``joinCandidates`` and ``join_slots_out`` over their
 ``joinSlotsOut``, each on a canned record whose answer is known and on the
 record of a program that lacks what it reads, where it answers nothing and
 not 0; and the least bytes of an expansion that a roofline share of the
-expand program will divide. ``BENCHMARK.json`` does not list the readers
-yet (PERF.md, Open questions): ``loader.metric`` finds a reader by name."""
+expand program will divide. ``loader.metric`` finds a reader by the name
+``BENCHMARK.json`` lists it under."""
 
 import types
 

@@ -12,7 +12,7 @@ import time
 
 import pytest
 
-from benchlib import REPO, run_cli
+from benchlib import REPO, rehearsal_args, run_cli
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
     BENCH = json.load(_f)
@@ -27,9 +27,8 @@ def _metrics_of(kind, cell):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_rehearses_end_to_end(cell, tmp_path):
-    rc, last, out, err = run_cli(RUN, [
-        "--workload", cell, "--seed", 2 ** 31 + 7, "--seconds", 2,
-        "--trace", 0, "--rehearsal", "--work-dir", tmp_path])
+    # the twin of test_fault_*.py's broken runs: the same seed and arguments
+    rc, last, out, err = run_cli(RUN, rehearsal_args(cell, tmp_path))
     assert rc == 0, err[-3000:]
     # rc 0 also says the client initialised no JAX backend: run.py checks
     assert set(last) >= {"correct", "attempted", "failed", "metrics",
@@ -65,7 +64,7 @@ def test_traced_rehearsal_reports_the_per_layer_metrics(cell, seconds,
     # readers of the device's trace and of shares of the chip return
     # nothing, never 0
     off_chip = {"hbm_roofline_pct", "device_idle_pct", "peak_hbm_bytes",
-                "programs_per_query"}
+                "programs_per_query", "idle_after_read_pct"}
     assert set(last["metrics"]) == _metrics_of("per_layer", cell) - off_chip
     assert "busy_s" not in last["device"] and "breakdown" not in last
     assert last["metrics"]["cpu_fallback_ops"]["value"] == 0

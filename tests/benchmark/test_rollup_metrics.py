@@ -3,8 +3,8 @@
 ``WindowExec`` operator spans and ``expand_slots_out`` over the operator
 spans' ``expandSlotsOut``, each on a canned record whose answer is known and
 on the record of a program that lacks what it reads, where it answers
-nothing and not 0. ``BENCHMARK.json`` does not list them yet (PERF.md, Open
-questions): ``loader.metric`` finds a reader by name."""
+nothing and not 0. ``loader.metric`` finds a reader by the name
+``BENCHMARK.json`` lists it under."""
 
 import types
 

@@ -149,7 +149,8 @@ FIRST_EXECUTION = textwrap.dedent('''
 def test_a_traced_rehearsal_prints_all_eight(tmp_path):
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert [m["name"] for m in bench["per_layer"]][-8:] == NEW
+    # found by name, wherever in the list later entries leave them
+    assert set(NEW) <= {m["name"] for m in bench["per_layer"]}
     driver = tmp_path / "driver.py"
     driver.write_text(FIRST_EXECUTION.format(bench=BENCH, args=[
         "--workload", "tpcds_sf1.q3", "--seed", str(2 ** 31 + 28),
